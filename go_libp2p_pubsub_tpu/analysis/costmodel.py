@@ -276,9 +276,9 @@ def _leaf_cost(eqn) -> dict:
 
 def _closed_jaxprs(v) -> list:
     """ClosedJaxpr values inside one eqn param (scalars pass through)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, ClosedJaxpr):
         return [v]
     if isinstance(v, (list, tuple)):
         out = []
@@ -289,7 +289,7 @@ def _closed_jaxprs(v) -> list:
 
 
 def cost_jaxpr(jaxpr) -> dict:
-    """Walk one ``jax.core.Jaxpr`` and return the metric totals.
+    """Walk one ``jax.extend.core.Jaxpr`` and return the metric totals.
     Control flow: ``scan`` multiplies its body by the static trip
     count, ``while`` charges cond+body ONCE (trip count is dynamic —
     the engines carry no unbounded whiles; the window's loop is a

@@ -822,10 +822,9 @@ def _collapse_pair(iv):
 
 
 def _read(env, atom):
-    import jax
-    import numpy as np
+    from jax.extend.core import Literal
 
-    if isinstance(atom, jax.core.Literal):
+    if isinstance(atom, Literal):
         return _const_ival(atom.val, atom.aval)
     iv = env.get(atom)
     if iv is None:
@@ -843,7 +842,7 @@ def _shape_fix(iv, aval):
 
 
 def interp_jaxpr(jaxpr, consts, in_ivals, rec, path=""):
-    """Walk one ``jax.core.Jaxpr`` propagating intervals; returns the
+    """Walk one ``jax.extend.core.Jaxpr`` propagating intervals; returns the
     output intervals. ``rec=None`` walks silently (scan pre-pass)."""
     env = {}
     for v, c in zip(jaxpr.constvars, consts):

@@ -1,38 +1,38 @@
-"""Shared persistent-XLA-compile-cache policy (tests/conftest.py and
-perf/regress.py both apply it).
+"""Shared persistent-XLA-compile-cache policy: every entry point (tests,
+gates, bench, sweep, profile, chip_smoke.py, the serve child) calls
+:func:`enable_persistent_cache` before its first jit.
 
-The cache halves a warm full-tier run — but on jax 0.4.x CPU, LOADING a
-persistent-cache entry segfaults the process inside the deserialized
-executable (reproduced on 0.4.37 with a cache written by the same
-jaxlib: the first populate-run passes, every warm run crashes). Enable
-only on jax >= 0.5, where rounds 2-5 ran it without incident.
-JAX_NO_TEST_CACHE=1 opts out everywhere (e.g. when bisecting a
-suspected stale-cache issue).
+Where the cache lives is decided OUTSIDE the code when
+``JAX_COMPILATION_CACHE_DIR`` is set (jax reads the variable itself; this
+module then sets no directory). Otherwise it is the fixed
+``<checkout>/.jax_cache`` — fixed because the path is part of the cache
+key's environment: a directory that moves (temporary name, pid, time)
+never hits. JAX_NO_TEST_CACHE=1 opts out everywhere (e.g. when bisecting
+a suspected stale-cache issue).
 """
 
 from __future__ import annotations
 
 import os
-import re
+
+#: the fixed fallback directory, git-ignored
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
 
-def cache_supported() -> bool:
-    import jax
-
-    m = re.match(r"(\d+)\.(\d+)", jax.__version__)
-    if m is None:  # pragma: no cover — exotic version strings
-        return False
-    return (int(m.group(1)), int(m.group(2))) >= (0, 5)
-
-
-def enable_persistent_cache(cache_dir: str) -> bool:
-    """Point jax at the repo-local cache when this jaxlib supports it and
-    the env hasn't opted out; returns whether the cache was enabled."""
-    if os.environ.get("JAX_NO_TEST_CACHE", "") == "1" or not cache_supported():
+def enable_persistent_cache(cache_dir: str | None = None) -> bool:
+    """Turn the persistent compile cache on unless the env opted out;
+    returns whether it was enabled. ``cache_dir`` (default
+    :data:`DEFAULT_CACHE_DIR`) is used only when
+    ``JAX_COMPILATION_CACHE_DIR`` is unset."""
+    if os.environ.get("JAX_NO_TEST_CACHE", "") == "1":
         return False
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          cache_dir or DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return True

@@ -28,23 +28,31 @@ from ..state import Delivery, MsgTable, Net
 from ..trace.events import EV
 
 # opt-in fused Pallas delivery kernel for banded topologies (exact parity
-# with the XLA path — tests/test_pallas.py). Off by default: the current
-# libtpu's Mosaic pass rejects the packed-word shape casts on real TPU
-# (see ops/pallas_delivery.py docstring), so the opt-in runs the kernel in
-# interpret mode (set PUBSUB_PALLAS_COMPILE=1 to attempt a real compile on
-# a future libtpu). The XLA path stays the production default.
+# with the XLA path — tests/test_pallas.py). Off by default: Mosaic
+# refuses the packed-word shape casts (ops/pallas_delivery.py docstring
+# has the compiler's message; tests/test_chip_compile.py pins it). Like
+# every Pallas switch here it runs interpreted off-TPU and COMPILED on a
+# TPU backend (_pallas_interpret), and a kernel that cannot be used for
+# the build raises — the XLA path is never taken in its place.
 USE_PALLAS = os.environ.get("PUBSUB_PALLAS", "") == "1"
 
 # opt-in fused Pallas kernels for the flat-[E] CSR plane (round 21,
 # ops/pallas_csr.py — exact parity with the fused composite,
-# tests/test_pallas_csr.py). Same Mosaic caveat and interpret-mode
-# gating as PUBSUB_PALLAS; requires a `fused=True` Net (the composite
-# and the kernel share the capacity-bounded scan contract).
+# tests/test_pallas_csr.py). Same rules as PUBSUB_PALLAS; refused by
+# the TPU lowering today (docstring there); requires a `fused=True` Net
+# (the composite and the kernel share the capacity-bounded scan contract).
 USE_PALLAS_CSR = os.environ.get("PUBSUB_PALLAS_CSR", "") == "1"
 
 
 def _pallas_block() -> int:
     return int(os.environ.get("PUBSUB_PALLAS_BLOCK", "2000"))
+
+
+def _pallas_interpret() -> bool:
+    """Interpret mode is a property of the backend, never a switch (the
+    rule models/gossipsub.py applies to ops/fused_round.py): a TPU runs
+    the compiled kernel or the compile error, nothing interpreted."""
+    return jax.default_backend() != "tpu"
 
 
 @struct.dataclass
@@ -207,12 +215,16 @@ def delivery_round(
         from ..ops.pallas_delivery import pallas_supported
 
         block = min(_pallas_block(), n)
-        if pallas_supported(net.band_off, n, block):
-            interpret = os.environ.get("PUBSUB_PALLAS_COMPILE", "") != "1"
-            return _delivery_round_pallas(
-                net, msgs, dlv, edge_mask, tick, block=block,
-                interpret=interpret, count_events=count_events,
-            )
+        if not pallas_supported(net.band_off, n, block):
+            raise ValueError(
+                f"PUBSUB_PALLAS=1 but the banded kernel cannot tile this "
+                f"net: block {block} must divide n_peers={n} and cover the "
+                f"widest band offset, with at most 127 bands "
+                f"({len(net.band_off)} here) — set PUBSUB_PALLAS_BLOCK")
+        return _delivery_round_pallas(
+            net, msgs, dlv, edge_mask, tick, block=block,
+            interpret=_pallas_interpret(), count_events=count_events,
+        )
 
     not_mine = ~origin_msg_words(net, msgs)  # [N, W]
     if msgs.wire_block is not None:
@@ -237,12 +249,10 @@ def delivery_round(
         flat_resident = dlv.fe_words.ndim == 2
         if (flat_resident and net.fused and USE_PALLAS_CSR
                 and val_delay == 0 and queue_cap == 0):
-            got = _delivery_round_pallas_csr(
+            return _delivery_round_pallas_csr(
                 net, msgs, dlv, edge_mask, not_mine, tick,
                 forward_mask=forward_mask, count_events=count_events,
             )
-            if got is not None:
-                return got
         fwd_e = net.peer_gather_flat(dlv.fwd)                    # [E, W]
         echo_e = net.edge_gather_flat(
             dlv.fe_words if flat_resident
@@ -543,20 +553,25 @@ def _delivery_round_pallas_csr(net, msgs, dlv, edge_mask, not_mine, tick,
     (ops/pallas_csr.csr_delivery — the three-call form of the flat
     gather/scan/commit chain). Bit-identical to the composite path
     below (tests/test_pallas_csr.py); opt-in via PUBSUB_PALLAS_CSR=1 on
-    a fused Net. Returns None when the static block preconditions don't
-    hold (the caller falls through to the composite)."""
+    a fused Net. Raises when the static block preconditions don't hold
+    (the composite is never taken in the kernel's place)."""
     from ..ops import edges as _edges
     from ..ops import pallas_csr as pcsr
 
     e = net.n_edges
     cap = net.max_degree
     want = _pallas_block()
-    block = _pick_div(e, cap, want)
+    # at least two edge blocks: each grid step reads the previous one
+    block = _pick_div(e, cap, min(want, e // 2))
     block_rows = _pick_div(net.n_peers, 1, want)
     if (block is None or block_rows is None
             or not pcsr.pallas_csr_supported(e, block, cap)):
-        return None
-    interpret = os.environ.get("PUBSUB_PALLAS_COMPILE", "") != "1"
+        raise ValueError(
+            f"PUBSUB_PALLAS_CSR=1 but the CSR kernels cannot tile this "
+            f"net: no block <= {want} (PUBSUB_PALLAS_BLOCK) divides "
+            f"n_edges={e} with block >= max_degree={cap} and at least two "
+            f"blocks, and n_peers={net.n_peers} in blocks")
+    interpret = _pallas_interpret()
     m = msgs.capacity
     mask_e = net.pack_edges(edge_mask)
     valid_words = bitset.pack(msgs.valid)

@@ -2350,10 +2350,10 @@ def make_gossipsub_step(
     # fused Pallas data plane (ops/fused_round.py): the whole edge-crossing
     # exchange + delivery as two kernels on banded topologies. Opt-in via
     # PUBSUB_FUSED=1 (bit-identical to the XLA path — tests/
-    # test_fused_round.py): measured on the current libtpu the kernels
-    # lose to XLA's fusion pipeline (per-grid-step and strided-DMA
-    # overheads dominate the halo reads at these shapes), so the XLA path
-    # stays the production default. The async-validation pipeline always
+    # test_fused_round.py, and on the v5e at N=100k, PR 23): the kernels
+    # lose to XLA's fusion pipeline there (1.8154 s vs 0.1703 s per 64
+    # ticks, my chip run, PR 23), so the XLA path stays the production
+    # default. The async-validation pipeline always
     # keeps the XLA path (pending stages live outside the kernel).
     from .common import USE_PALLAS as _old_pallas
 

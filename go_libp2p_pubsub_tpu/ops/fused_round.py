@@ -25,6 +25,11 @@ iwant_responses + merge_extra_tx + the merged wire gather in
 models/gossipsub._round); tests/test_fused_round.py drives both paths
 through full simulations and compares state trees exactly.
 
+Status on the v5e compiler (jax 0.9.0 / libtpu 0.0.34): both kernels
+COMPILE at the bench shape (N=100,000, block = pick_block = 400, one
+`tpu_custom_call` each — tests/test_chip_compile.py, against a described
+`v5e:2x2` device).
+
 Reference semantics covered (citations as in the XLA path):
   mesh push + fanout + flood edges     gossipsub.go:943-1013, 973-978
   flood-publish (sender-side fold)     gossipsub.go:957-963

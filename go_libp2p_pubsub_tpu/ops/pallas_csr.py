@@ -38,12 +38,34 @@ Bit-exactness: each kernel is proven equal to its XLA composite twin in
 interpret mode on ragged, banded and power-law topologies, chaos masks
 on and off (tests/test_pallas_csr.py).
 
-Status on real TPU: same Mosaic caveat as pallas_delivery.py — the
-packed-word bit casts and the unstructured VMEM gathers are rejected by
-the current libtpu's infer-vector-layout pass, so these kernels compile
-only in interpret mode today and the restructured XLA composite
-(``cfg.fused``, ops/select + ops/csr) is what runs on hardware. The
-composite is the form `make cost-audit`'s fusion contract prices.
+Status on the v5e compiler (jax 0.9.0 / libtpu 0.0.34, ``csr_delivery``
+asked with a described `v5e:2x2` device at the E = 1,600,000 of a CSR
+bench net, N=100,000 — tests/test_chip_compile.py pins the first two):
+REFUSED, by the Pallas TPU lowering before Mosaic is reached, three
+times over:
+
+  1. at the blocks models/common.py picks (2000): "The Pallas TPU
+     lowering currently requires that rank 1 block shapes, either 1) the
+     first (and only) dimension of the block shape is equal to the first
+     (and only) dimension of the array shape, or 2) ... is a multiple of
+     the tiling size (128 ...)" — the [B] col/eperm/row/seg_start index
+     blocks. An aligned edge block exists (2560 | E) but N=100,000 has no
+     128-multiple divisor, so the row phase cannot be blocked at all;
+  2. past that: "Loads are only allowed on VMEM and SMEM references. ANY
+     memory space can only be accessed using async_copy." — the
+     whole-array gather sources (``pl.ANY``) are read with ``ref[:]``;
+  3. with those in VMEM instead (12.8 MB for the [E, W] plane alone):
+     "Shape mismatch in input, indices and output" — the unstructured
+     ``fwd[col]`` row gather has no TPU lowering.
+
+None is a local repair: 2 and 3 are the kernel's design (unstructured
+gathers from whole arrays). The kernels run in interpret mode only, i.e.
+never on a TPU (models/common.py derives interpret mode from the
+backend); the restructured XLA composite (``cfg.fused``, ops/select +
+ops/csr) is what runs on hardware and is the form `make cost-audit`'s
+fusion contract prices. ``select_topk_pallas`` is called by no engine
+(tests only) and fails on refusal 1 as well. ROADMAP queue 3 item 3 has
+this as its evidence for deletion.
 """
 
 from __future__ import annotations
@@ -199,7 +221,7 @@ def csr_delivery(
     deny = link_ok_e is not None
 
     full2 = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim,
-                                   memory_space=pltpu.ANY)
+                                   memory_space=pl.ANY)
     eb = lambda cols: pl.BlockSpec((block, cols), lambda i: (i, 0),
                                    memory_space=pltpu.VMEM)
     eb1 = pl.BlockSpec((block,), lambda i: (i,), memory_space=pltpu.VMEM)

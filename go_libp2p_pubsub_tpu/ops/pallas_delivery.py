@@ -16,15 +16,21 @@ unpacked in VMEM registers. The kernel is exact — bit-identical to the
 XLA path (tests/test_pallas.py proves it in interpret mode and the banded
 parity suite covers the surrounding step).
 
-Status on real TPU: the current libtpu's Mosaic pass (infer-vector-layout)
-rejects the word<->bit shape casts this packed layout needs
-(`vector<BxWx32xi32> -> vector<BxMxi32>` is an "unsupported shape cast"),
-so the kernel compiles only in interpret mode today; the XLA path stays
-the default. Measured on this chip the XLA fusion pipeline already runs
-the delivery round within ~1-2 ms at N=100k, so the fused kernel's upside
-is bounded and not worth contorting the layout (e.g. one-column packs)
-around the Mosaic restriction. Revisit when Mosaic grows lane<->sublane
-reshapes for int vectors.
+Status on the v5e compiler (jax 0.9.0 / libtpu 0.0.34, asked with a
+described `v5e:2x2` device at the bench shape N=100,000 block=2000 —
+tests/test_chip_compile.py pins it): REFUSED. The message:
+
+    MosaicError: INTERNAL: Mosaic failed to compile TPU kernel:
+    infer-vector-layout: unsupported shape cast
+    %160 = "tpu.reshape"(%159) :
+        (vector<2000x64xi32>) -> vector<2000x2x32xi32>
+
+That cast is `_pack_bits` / `_unpack_words` — the word<->bit reshape the
+packed layout is built on — so there is no local repair (block size,
+alignment, VMEM budget do not touch it); the kernel runs in interpret
+mode only, i.e. never on a TPU (models/common.py derives interpret mode
+from the backend). The XLA path is the default. ROADMAP queue 3 item 3
+has this as its evidence for deletion.
 """
 
 from __future__ import annotations

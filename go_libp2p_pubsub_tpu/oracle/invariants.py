@@ -45,6 +45,7 @@ referenced by a seeded-violation negative test in tests/.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -575,8 +576,14 @@ def _backoff_wf(ctx) -> jax.Array:
         "(clearBackoff cadence, gossipsub.go:1585-1604)")
 def _backoff_clears(ctx) -> jax.Array:
     gs, cfg = ctx.gs, ctx.cfg
+    # the lazy clear runs inside the heartbeat and fires when
+    # tick % backoff_clear_ticks == 0, so with heartbeat_every > 1 the
+    # two cadences coincide once per lcm (8 and 15: every 120 rounds —
+    # the reference's "every 15th heartbeat", gossipsub.go:1587); that,
+    # not backoff_clear_ticks alone, is one full lazy-clear period
+    period = math.lcm(cfg.backoff_clear_ticks, cfg.heartbeat_every)
     bound = (gs.backoff_expire + cfg.backoff_slack_ticks
-             + cfg.backoff_clear_ticks + cfg.heartbeat_every + 1)
+             + period + cfg.heartbeat_every + 1)
     return ~jnp.any(gs.backoff_present & (ctx.tick > bound))
 
 

@@ -1,4 +1,5 @@
 from .sharding import (  # noqa: F401
+    collective_ops,
     collective_profile,
     make_mesh,
     make_mesh_2d,

@@ -18,6 +18,8 @@ shardings, let XLA do the rest.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -127,19 +129,39 @@ def shard_state(state, mesh: Mesh, n_peers: int,
         state, state_shardings(state, mesh, n_peers, n_edges=n_edges))
 
 
-def collective_profile(hlo_text: str) -> dict:
-    """Count collective ops in compiled (partitioned) HLO — including the
-    async start forms, which is how XLA often emits them. Used by the
-    scaling report (scripts/scaling_cpu_mesh.py) and the CI regression
-    guard (tests/test_collectives.py) to pin the GSPMD lowering of the
-    cross-peer neighbor gathers (halo collective-permutes, never
-    peer-sized all-gathers)."""
-    import re
+_COLLECTIVES = ("collective-permute", "all-gather", "all-reduce",
+                "all-to-all", "reduce-scatter")
+_COLLECTIVE_RE = re.compile(
+    r" = (.*?) (" + "|".join(_COLLECTIVES) + r")(?:-start)?\(")
+_ARRAY_RE = re.compile(r"[a-z0-9]+\[([0-9,]*)\]")
 
-    prof = {}
-    for op in ("collective-permute", "all-gather", "all-reduce",
-               "all-to-all", "reduce-scatter"):
-        n = len(re.findall(rf"= \S+ {op}\(", hlo_text))
-        n += len(re.findall(rf"= \S+ {op}-start\(", hlo_text))
-        prof[op] = n
+
+def collective_ops(hlo_text: str) -> list:
+    """``(op, shape)`` of every collective in compiled (partitioned) HLO,
+    ``shape`` being the first array of its result type. The async start
+    forms count as their op: XLA:CPU prints them with an array type, the
+    TPU compiler with a TUPLE type ``(operand, result, ...)`` whose
+    layouts carry parentheses of their own, so the type is matched
+    non-greedily up to the op name rather than as one token."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _COLLECTIVE_RE.search(line)
+        if m is None:
+            continue
+        dims = _ARRAY_RE.search(m.group(1)).group(1)
+        out.append((m.group(2),
+                    tuple(int(d) for d in dims.split(",") if d)))
+    return out
+
+
+def collective_profile(hlo_text: str) -> dict:
+    """Count collective ops in compiled (partitioned) HLO
+    (:func:`collective_ops`). Used by the scaling report
+    (scripts/scaling_cpu_mesh.py), the CI regression guard
+    (tests/test_collectives.py) and chip_smoke.py to pin the GSPMD
+    lowering of the cross-peer neighbor gathers (halo transfers, never
+    peer-sized all-gathers)."""
+    prof = dict.fromkeys(_COLLECTIVES, 0)
+    for op, _shape in collective_ops(hlo_text):
+        prof[op] += 1
     return prof

@@ -6,7 +6,7 @@ ad-hoc scripts + markdown arithmetic:
 
   * :mod:`.artifacts`  — the versioned, self-describing bench-JSON schema
     (v2: config fingerprint incl. score-weight elision flags) + readers
-    that still parse the in-tree ``BENCH_r01–r05.json`` wrapper files;
+    that still parse the pre-schema driver wrapper files of rounds 1-5;
   * :mod:`.profile`    — library-ified per-op profiler: runs the
     per-round or phase engine at arbitrary ``(N, r, config)`` shapes and
     returns an attributed op table (the BASELINE.md round-5-style table);

@@ -497,9 +497,13 @@ def profile_workload(
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    from .sweep import PUBS_PER_ROUND, build_bench, workload_fingerprint
+    from .sweep import (
+        bench_schedule,
+        build_bench,
+        make_bench_scan,
+        workload_fingerprint,
+    )
 
     r = max(int(rounds_per_phase), 1)
     he = heartbeat_every if heartbeat_every is not None else (r if r > 1 else 1)
@@ -509,24 +513,11 @@ def profile_workload(
         rounds_per_phase=r,
     )
 
-    rng = np.random.default_rng(0)
-    if honest is not None:
-        po = honest[
-            rng.integers(0, len(honest), size=(rounds, PUBS_PER_ROUND))
-        ].astype(np.int32)
-    else:
-        po = rng.integers(0, n_peers, size=(rounds, PUBS_PER_ROUND)).astype(np.int32)
-    po = jnp.asarray(po)
-    pt = jnp.asarray(rng.integers(
-        0, n_topics, size=(rounds, PUBS_PER_ROUND)).astype(np.int32))
-    pv = jnp.asarray(np.ones((rounds, PUBS_PER_ROUND), bool))
-
-    from ..driver import make_scan
-
-    u = unroll if unroll is not None else (2 * r if r > 1 else 4)
-    scan = make_scan(step, heartbeat_every=he, rounds_per_phase=r,
-                     static_heartbeat=he > 1 or r > 1,
-                     unroll=max(1, u // max(r, 1)))
+    po, pt, pv = (
+        jnp.asarray(a)
+        for a in bench_schedule(n_peers, n_topics, honest, rounds)
+    )
+    scan, u = make_bench_scan(step, he, r, unroll)
     st = scan(st, po, pt, pv)  # compile + warmup
     jax.block_until_ready(st)
 
@@ -592,14 +583,18 @@ def main(argv=None):
 
     import jax
 
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
+    from ..compile_cache import enable_persistent_cache
+    from .sweep import select_platform
+
+    stamp = select_platform(args.platform)
     prng = os.environ.get("BENCH_PRNG", "unsafe_rbg")
     if prng:
         jax.config.update("jax_default_prng_impl", prng)
+    enable_persistent_cache()
 
     table = profile_workload(args.n, args.rounds, config=args.config,
                              rounds_per_phase=args.r, unroll=args.unroll)
+    print(json.dumps({"device": stamp}))
     print(format_table(table, top=args.top))
 
 

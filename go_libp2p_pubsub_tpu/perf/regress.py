@@ -115,12 +115,11 @@ def check_projection(root: str) -> list[str]:
     """The committed round-5 projection must reproduce from code."""
     from .projection import project_from_artifacts
 
-    bench = os.path.join(root, "BENCH_r05.json")
     multi = os.path.join(root, "MULTICHIP_r05.json")
-    if not (os.path.exists(bench) and os.path.exists(multi)):
+    if not os.path.exists(multi):
         return []  # nothing committed to check against (fresh clone subset)
     try:
-        proj = project_from_artifacts(bench, multi)
+        proj = project_from_artifacts(None, multi)
     except Exception as e:  # noqa: BLE001
         return [f"projection from round-5 artifacts failed: {e}"]
     frac = proj.central / 10_000.0
@@ -222,8 +221,6 @@ def run_mini_bench(emit=None) -> dict:
     for mode, rr in (("per_round", 1), ("phase", r)):
         rec = measure_record("default", n, 64, rr if rr > 1 else 1, rr,
                              rounds, reps=2)
-        if rec is None:
-            raise RuntimeError(f"mini-bench {mode} failed to run at N={n}")
         out[mode] = rec.value
         out["records"].append(rec)
         if emit is not None:
@@ -301,8 +298,8 @@ def main(argv=None) -> int:
     # the same thing) on any dev box / CI runner, TPU present or not
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_default_prng_impl", "unsafe_rbg")
-    # same persistent compile cache (and jax-version safety gate) the
-    # test tier uses — ../compile_cache.py: the mini-bench is
+    # same persistent compile cache the test tier uses —
+    # ../compile_cache.py: the mini-bench is
     # compile-dominated cold (~2 min) and ~25 s warm
     from ..compile_cache import enable_persistent_cache
 

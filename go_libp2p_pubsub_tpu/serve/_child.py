@@ -162,19 +162,22 @@ def main(argv=None) -> int:
 
     import jax
 
+    # A CPU-ONLY test cell, by construction: its launchers
+    # (tests/test_serve.py, scripts/service_smoke.py) start it from a
+    # parent that has already initialised jax, and an accelerator
+    # belongs to one process at a time — a child that needed the chip
+    # its parent holds would fail or hang. Both sides pin the CPU, so
+    # the pair is correct; never point this cell at a chip (the served
+    # path on the chip is chip_smoke.py's in-process phase).
     jax.config.update("jax_platforms", "cpu")
     # the parent decides the PRNG impl (service_smoke pins the gate
     # PRNG so its in-process legs share the children's key shapes)
     impl = os.environ.get("SERVE_CHILD_PRNG")
     if impl:
         jax.config.update("jax_default_prng_impl", impl)
-    cache = os.environ.get("SERVE_CHILD_CACHE")
-    if cache:
-        from go_libp2p_pubsub_tpu.compile_cache import (
-            enable_persistent_cache,
-        )
+    from go_libp2p_pubsub_tpu.compile_cache import enable_persistent_cache
 
-        enable_persistent_cache(cache)
+    enable_persistent_cache()
 
     from go_libp2p_pubsub_tpu.serve import ServiceHalted, state_digest
 

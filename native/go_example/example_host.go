@@ -15,7 +15,7 @@
 //	./example_host_go PLUGIN.so MODULE.mlirpb OPTIONS.pb [name:type:value ...]
 //
 // The module/options inputs are produced exactly as for the C host (see
-// tests/test_pjrt_bridge.py: jax.jit(...).lower(...) -> StableHLO bytes
+// tests/test_chip_compile.py: jax.jit(...).lower(...) -> StableHLO bytes
 // + compile-options proto), so a Go service can execute the full
 // vectorized router step with zero Python in the loop.
 package main

@@ -24,7 +24,7 @@ oracle) and asserts the round-14 whole-run-compilation contract:
      SCAN_SMOKE.json floor (both rates and the implied
      per-dispatch-overhead are recorded in the artifact).
   4. **projection refresh** — the v5e-8 projection recomputed from the
-     committed BENCH_r05 shard rates with the new
+     committed round-5 shard rates with the new
      ``dispatch_overhead_ms`` term parameterized on the overhead this
      run measured, gated on the 2-D (sims × peers) multichip dryrun
      artifact (MULTICHIP_r06.json — scripts/mesh2d_dryrun.py).
@@ -238,12 +238,12 @@ def refresh_projection(root: str, res: dict) -> dict:
     per-dispatch (1/r) execution shapes."""
     from go_libp2p_pubsub_tpu.perf.projection import project_from_artifacts
 
-    bench = os.path.join(root, "BENCH_r05.json")
+    bench = None  # the round-5 headline cell (projection.ROUND5_SHARD_RATES_R16)
     multi2d = os.path.join(root, MULTICHIP_2D_NAME)
     if not os.path.exists(multi2d):
         multi2d = os.path.join(root, "MULTICHIP_r05.json")
-    if not (os.path.exists(bench) and os.path.exists(multi2d)):
-        return {"skipped": "no committed bench/multichip artifacts"}
+    if not os.path.exists(multi2d):
+        return {"skipped": "no committed multichip artifact"}
     ov = res["dispatch_overhead_ms"]
     try:
         scanned = project_from_artifacts(

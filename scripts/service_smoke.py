@@ -83,10 +83,11 @@ def child_cmd(root: str, *extra: str) -> list:
 
 
 def run_child(repo_root: str, root: str, *extra: str):
+    # the child pins the CPU itself (serve/_child.py main): this parent
+    # has already initialised jax, and a chip belongs to one process
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               SERVE_CHILD_PRNG="unsafe_rbg",
-               SERVE_CHILD_CACHE=os.path.join(repo_root, ".jax_cache"))
+               SERVE_CHILD_PRNG="unsafe_rbg")
     return subprocess.run(
         child_cmd(root, *extra), cwd=repo_root, env=env,
         capture_output=True, text=True, timeout=CHILD_TIMEOUT)

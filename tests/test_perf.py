@@ -24,13 +24,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # artifacts: schema v2 + legacy readers
 
 
-def test_v2_readers_parse_all_committed_bench_artifacts():
-    """Every in-tree BENCH_r0*.json (v1 driver wrappers rounds 1-5,
-    schema-v3 scanned-window lines from round 15 on) must normalize
-    through the reader — the artifact trajectory is the regression
-    gate's ground truth."""
-    paths = sorted(glob.glob(os.path.join(ROOT, "BENCH_r*.json")))
-    assert len(paths) >= 5, paths
+def test_v2_readers_parse_all_committed_bench_artifacts(bench_r01_r05):
+    """Every BENCH_r0*.json (the v1 driver wrappers of rounds 1-5, from
+    the recorded literal; the in-tree schema-v3 scanned-window lines
+    from round 15 on) must normalize through the reader — the artifact
+    trajectory is the regression gate's ground truth."""
+    committed = sorted(glob.glob(os.path.join(ROOT, "BENCH_r*.json")))
+    assert len(committed) >= 3, committed
+    paths = bench_r01_r05 + committed
     recs = [artifacts.load_bench_artifact(p) for p in paths]
     for rec in recs:
         assert rec.value > 0
@@ -145,7 +146,7 @@ def test_fingerprint_records_engine_gating():
 # projection engine
 
 
-def test_projection_reproduces_round5_number():
+def test_projection_reproduces_round5_number(bench_r01_r05):
     """The committed round-5 projection — "~3,700-5,200 rounds/s,
     central ~4,500 ≈ 45% of the 10k north star" (BASELINE.md round-5
     addendum) — must come out of the code given the round-5 artifacts:
@@ -153,9 +154,12 @@ def test_projection_reproduces_round5_number():
     measured in) and MULTICHIP_r05 (the collective audit whose permute
     counts the ICI term is built from)."""
     proj = projection.project_from_artifacts(
-        os.path.join(ROOT, "BENCH_r05.json"),
+        bench_r01_r05[4],
         os.path.join(ROOT, "MULTICHIP_r05.json"),
     )
+    # the file-less form regress / scan-smoke use is the same projection
+    assert proj.summary() == projection.project_from_artifacts(
+        None, os.path.join(ROOT, "MULTICHIP_r05.json")).summary()
     lo, central, hi = proj.rounds_per_sec
     assert 0.44 <= central / 10_000.0 <= 0.455, proj.summary()
     assert 3_600 <= lo <= 3_800, proj.summary()
@@ -165,13 +169,13 @@ def test_projection_reproduces_round5_number():
     assert proj.ici_ms[2] == pytest.approx(0.10)
 
 
-def test_projection_refuses_failed_multichip(tmp_path):
+def test_projection_refuses_failed_multichip(bench_r01_r05):
     """A projection built on a failed collective audit would be fiction;
     the round-1 MULTICHIP artifact (libtpu mismatch, ok=false) must be
     rejected."""
     with pytest.raises(ValueError, match="not ok"):
         projection.project_from_artifacts(
-            os.path.join(ROOT, "BENCH_r01.json"),
+            bench_r01_r05[0],
             os.path.join(ROOT, "MULTICHIP_r01.json"),
             shard_rate=5_823.0,
         )
@@ -201,18 +205,17 @@ def test_permute_model_measured_sets():
         projection.permutes_per_round(16, 8)  # fewer sets than sub-rounds
 
 
-def test_projection_uses_fingerprint_permute_sets(tmp_path):
+def test_projection_uses_fingerprint_permute_sets(tmp_path, bench_r01_r05):
     """A v2 artifact carrying the measured count must project strictly
     higher than the same artifact without it (legacy fallback), with the
     dryrun gate behavior intact — and the control-set count translates
     across cadences (artifact r=8, projection r=16)."""
     import json as _json
 
-    with open(os.path.join(ROOT, "BENCH_r05.json")) as f:
+    with open(bench_r01_r05[4]) as f:
         wrapper = _json.load(f)
     multi = os.path.join(ROOT, "MULTICHIP_r05.json")
-    legacy = projection.project_from_artifacts(
-        os.path.join(ROOT, "BENCH_r05.json"), multi)
+    legacy = projection.project_from_artifacts(bench_r01_r05[4], multi)
 
     wrapper["parsed"]["schema"] = 2
     wrapper["parsed"]["fingerprint"] = {
@@ -233,8 +236,7 @@ def test_projection_uses_fingerprint_permute_sets(tmp_path):
     rec = artifacts.load_bench_artifact(str(p))
     assert rec.wire_coalesced is True
     assert rec.permute_sets_per_phase == 9
-    legacy_rec = artifacts.load_bench_artifact(
-        os.path.join(ROOT, "BENCH_r05.json"))
+    legacy_rec = artifacts.load_bench_artifact(bench_r01_r05[4])
     assert legacy_rec.wire_coalesced is None
     assert legacy_rec.permute_sets_per_phase is None
 
@@ -301,7 +303,7 @@ ENTRY %main (i: u32[8]) -> u32[8] {
     assert census["total"] == 4
 
 
-def test_projection_input_validation():
+def test_projection_input_validation(bench_r01_r05):
     with pytest.raises(ValueError):
         projection.project(0.0, 16)
     with pytest.raises(ValueError):
@@ -310,7 +312,7 @@ def test_projection_input_validation():
     # without its own shard rate must refuse, not silently project r=16
     with pytest.raises(ValueError, match="rounds_per_phase=16"):
         projection.project_from_artifacts(
-            os.path.join(ROOT, "BENCH_r05.json"),
+            bench_r01_r05[4],
             os.path.join(ROOT, "MULTICHIP_r05.json"),
             rounds_per_phase=8,
         )

@@ -398,8 +398,9 @@ def test_invariant_cadence_must_divide_segment(cell, tmp_path):
 
 
 def _run_child(root, *extra, timeout=240):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               SERVE_CHILD_CACHE=os.path.join(_REPO, ".jax_cache"))
+    # a CPU-only cell: this parent holds jax already, and the child pins
+    # the CPU itself (serve/_child.py main) — one process per chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     cmd = [sys.executable, "-m", "go_libp2p_pubsub_tpu.serve._child",
            "--root", str(root), "--n", str(N), "--rounds", str(ROUNDS),
            "--segment", str(SEG), "--probes", *extra]

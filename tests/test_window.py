@@ -503,18 +503,14 @@ def test_projection_dispatch_term():
         project(0.4, 16, dispatch_overhead_ms=-1.0)
 
 
-def test_projection_round5_reproduces_with_dispatch_term_off():
+def test_projection_round5_reproduces_with_dispatch_term_off(bench_r01_r05):
     import os
 
     from go_libp2p_pubsub_tpu.perf.artifacts import _repo_root
     from go_libp2p_pubsub_tpu.perf.projection import project_from_artifacts
 
-    root = _repo_root()
-    bench = os.path.join(root, "BENCH_r05.json")
-    multi = os.path.join(root, "MULTICHIP_r05.json")
-    if not (os.path.exists(bench) and os.path.exists(multi)):
-        pytest.skip("committed round-5 artifacts not present")
-    proj = project_from_artifacts(bench, multi)
+    multi = os.path.join(_repo_root(), "MULTICHIP_r05.json")
+    proj = project_from_artifacts(bench_r01_r05[4], multi)
     assert 0.44 <= proj.central / 10_000.0 <= 0.455
     assert proj.dispatch_ms_per_round == 0.0
 
