@@ -50,6 +50,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .perf import stages
+
 
 def heartbeat_schedule(heartbeat_every: int, rounds_per_phase: int) -> list[bool]:
     """Static per-phase heartbeat flags over one schedule period.
@@ -119,6 +121,20 @@ def _core_of(st):
     return st.core if hasattr(st, "core") else st
 
 
+def _jit_window(run, donate: bool):
+    """``jax.jit`` of a window body, as the one thing that reaches the
+    device as a module: named from the scopes' version (the module name
+    is part of the persistent cache's key, named scopes are not:
+    ``perf.stages.VERSION``) and noted in the stage registry while its
+    Python body is traced, so once per trace and never per dispatch."""
+    def window(*args, **kwargs):
+        stages.note_window(jitted, args, kwargs)
+        return run(*args, **kwargs)
+    window.__name__ = window.__qualname__ = stages.window_name()
+    jitted = jax.jit(window, donate_argnums=0 if donate else ())
+    return jitted
+
+
 def make_window(
     step,
     *,
@@ -168,6 +184,18 @@ def make_window(
     allows (the compiled program then contains the step body once, not
     ``check_every`` times). The state is donated (module docstring).
     """
+    return _jit_window(
+        _window_body(step, heartbeat=heartbeat, check=check,
+                     check_every=check_every, observe=observe,
+                     unroll=unroll),
+        donate)
+
+
+def _window_body(step, *, heartbeat=None, check=None, check_every: int = 1,
+                 observe=None, unroll: int = 1):
+    """The unjitted ``run(state, xs, due=None, consts=())`` of
+    :func:`make_window`, for callers that trace it inside a window of
+    their own (:func:`make_scan`)."""
     hb = None if heartbeat is None else min_cycle(heartbeat)
     period = 1 if hb is None else len(hb)
     ce = int(check_every)
@@ -304,7 +332,7 @@ def make_window(
                     ys["obs"])
         return st, out
 
-    return jax.jit(run, donate_argnums=0 if donate else ())
+    return run
 
 
 def make_scan(
@@ -360,8 +388,8 @@ def make_scan(
         static_heartbeat = r > 1
     lcm = math.lcm(he, r)
     sched = heartbeat_schedule(he, r) if static_heartbeat else None
-    win = make_window(step, heartbeat=sched, unroll=unroll, donate=False)
-    raw = win.__wrapped__  # traced inside the adapter's own jit below
+    # traced inside the adapter's own jit below
+    raw = _window_body(step, heartbeat=sched, unroll=unroll)
 
     def run(st, po, pt, pv, up=None, consts=()):
         n_rounds = po.shape[0]
@@ -383,4 +411,4 @@ def make_scan(
             xs = (po, pt, pv) + (() if up is None else (up,))
         st, _ = raw(st, xs, None, tuple(consts))
         return st
-    return jax.jit(run, donate_argnums=0 if donate else ())
+    return _jit_window(run, donate)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from ..ops import bitset
+from ..perf import stages
 from ..state import Delivery, MsgTable, Net
 from ..trace.events import EV
 
@@ -300,6 +301,7 @@ def delivery_round(
     )
 
 
+@stages.scope("deliver")
 def finish_delivery(
     net: Net,
     msgs: MsgTable,

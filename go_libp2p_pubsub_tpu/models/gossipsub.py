@@ -63,6 +63,7 @@ from ..ops.select import (
     select_random_mask,
     select_topk_mask,
 )
+from ..perf import stages
 from ..routers import (
     RouterConfig,
     choke_decide,
@@ -1031,6 +1032,7 @@ def update_fanout_on_publish(
     )
 
 
+@stages.scope("deliver")
 def merge_extra_tx(net: Net, msgs, dlv, info, extra: jax.Array, tick,
                    count_events: bool = True, queue_cap: int = 0,
                    val_delay_topic: tuple | None = None):
@@ -1118,6 +1120,7 @@ def merge_extra_tx(net: Net, msgs, dlv, info, extra: jax.Array, tick,
 # the heartbeat (gossipsub.go:1303-1564)
 
 
+@stages.scope("heartbeat")
 def heartbeat(cfg: GossipSubConfig, net: Net, st: GossipSubState, tp: dict,
               score_params: PeerScoreParams | None,
               nbr_sub: jax.Array, gater_params=None,

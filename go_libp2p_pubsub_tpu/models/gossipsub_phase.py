@@ -103,6 +103,7 @@ import numpy as np
 
 from ..chaos import faults as chaos_faults
 from ..ops import bitset
+from ..perf import stages
 from ..score.engine import (
     apply_delivery_counts,
     on_deliveries,
@@ -392,8 +393,12 @@ def make_gossipsub_phase_step(
         # sites above are LIFT_AUDIT.json's guarded-elision evidence)
         p3_live = p4_live = True
 
-    def _phase(st: GossipSubState, pub_origin, pub_topic, pub_valid, up_next,
-               do_heartbeat: bool, link_deny=None,
+    # ``stage(name)`` moves one `gs.*` scope along the phase (perf/
+    # stages.py); the callees that are a stage of their own nest theirs
+    # inside it
+    @stages.with_cursor
+    def _phase(stage, st: GossipSubState, pub_origin, pub_topic, pub_valid,
+               up_next, do_heartbeat: bool, link_deny=None,
                score_plane=None) -> GossipSubState:
         # lifted score plane (round 16): the VALUE-proved score fields
         # read from the traced plane; score_plane=None is the static
@@ -415,6 +420,7 @@ def make_gossipsub_phase_step(
         # the whole phase, so the panel sums telescope exactly)
         ev_prev = st.core.events if telemetry is not None else None
         # ---- control head (once per phase) ------------------------------
+        stage("control_head")
         if dynamic_peers:
             st, live = apply_peer_transitions(cfg, net, st, up_next, tp_r)
         else:
@@ -555,6 +561,7 @@ def make_gossipsub_phase_step(
             fp_ok = send_score_ok if cfg.score_enabled else net_l.nbr_ok
 
         # ---- data loop: r delivery sub-rounds ---------------------------
+        stage("data_round")
         msgs = core.msgs
         dlv = core.dlv
         mcache = st2.mcache
@@ -1003,6 +1010,7 @@ def make_gossipsub_phase_step(
                     fanout_st = upd
 
         # ---- phase tail (once) ------------------------------------------
+        stage("phase_tail")
         if plan is not None:
             msgs = plan.msgs_at(r)  # the phase-final message table
         # deferred recycled-slot clears (see the loop comment) — one

@@ -10,6 +10,9 @@ ad-hoc scripts + markdown arithmetic:
   * :mod:`.profile`    — library-ified per-op profiler: runs the
     per-round or phase engine at arbitrary ``(N, r, config)`` shapes and
     returns an attributed op table (the BASELINE.md round-5-style table);
+  * :mod:`.stages`     — the engine's stages as ``gs.*`` named scopes,
+    the stage map read from a compiled window, and the registry of the
+    windows traced in this process;
   * :mod:`.projection` — the v5e-8 projection as tested code composing
     measured shard-round times with the collective-cost model pinned by
     tests/test_collectives.py;

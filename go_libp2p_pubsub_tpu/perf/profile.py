@@ -33,6 +33,8 @@ import os
 import re
 from collections import defaultdict
 
+from .stages import UNSCOPED
+
 
 @dataclasses.dataclass
 class OpRow:
@@ -44,6 +46,9 @@ class OpRow:
     occurrences: int = 0
     source: str = ""
     text: str = ""
+    #: the engine stage of the op (perf/stages.py: the innermost ``gs.*``
+    #: scope of its instruction in the compiled window); "" without a map
+    stage: str = ""
 
 
 @dataclasses.dataclass
@@ -61,6 +66,16 @@ class ProfileTable:
         out = defaultdict(float)
         for r in self.rows:
             out[r.category] += r.self_us_per_round
+        return dict(out)
+
+    @property
+    def by_stage(self) -> dict:
+        """Self time per round by engine stage; empty when the table was
+        made without a stage map."""
+        out = defaultdict(float)
+        for r in self.rows:
+            if r.stage:
+                out[r.stage] += r.self_us_per_round
         return dict(out)
 
     @property
@@ -150,8 +165,15 @@ def _category_of(name: str, explicit: str | None) -> str:
     return m.group(0) if m else name
 
 
-def parse_xspace_bytes(blobs, rounds: int) -> ProfileTable:
+def _stage(stage_of: dict | None, name: str) -> str:
+    return "" if stage_of is None else stage_of.get(name, UNSCOPED)
+
+
+def parse_xspace_bytes(blobs, rounds: int,
+                       stage_of: dict | None = None) -> ProfileTable:
     """Aggregate per-op self times from serialized XSpace protos.
+    ``stage_of`` (``perf.stages``: instruction name -> stage) fills each
+    row's ``stage``.
 
     Takes HLO-op events from two plane shapes: device planes (plane name
     contains "device"/"TPU" — TPU runs), and host planes' executor lines
@@ -204,7 +226,7 @@ def parse_xspace_bytes(blobs, rounds: int) -> ProfileTable:
     rows = [
         OpRow(name=k, category=v[2],
               self_us_per_round=v[0] / 1e6 / max(rounds, 1),
-              occurrences=v[1], source=v[3])
+              occurrences=v[1], source=v[3], stage=_stage(stage_of, k))
         for k, v in agg.items()
     ]
     rows.sort(key=lambda r: -r.self_us_per_round)
@@ -417,8 +439,8 @@ def _hlo_stats_converter():
         return None, None
 
 
-def parse_hlo_stats_obj(obj: dict, rounds: int, backend: str = "hlo_stats"
-                        ) -> ProfileTable:
+def parse_hlo_stats_obj(obj: dict, rounds: int, backend: str = "hlo_stats",
+                        stage_of: dict | None = None) -> ProfileTable:
     """Normalize an hlo_stats tool-data object (the converter output
     scripts/profile_trace.py consumed: column 2 = category, 3 = op name,
     4 = HLO text, 9 = self time us, 25 = source) into a ProfileTable."""
@@ -440,7 +462,8 @@ def parse_hlo_stats_obj(obj: dict, rounds: int, backend: str = "hlo_stats"
     rows = [
         OpRow(name=k, category=v[2],
               self_us_per_round=v[0] / max(rounds, 1),
-              occurrences=v[1], source=v[3], text=v[4])
+              occurrences=v[1], source=v[3], text=v[4],
+              stage=_stage(stage_of, k))
         for k, v in agg.items()
     ]
     rows.sort(key=lambda r: -r.self_us_per_round)
@@ -456,9 +479,10 @@ def parse_hlo_stats_obj(obj: dict, rounds: int, backend: str = "hlo_stats"
 # capture + summarize
 
 
-def summarize_logdir(logdir: str, rounds: int) -> ProfileTable:
+def summarize_logdir(logdir: str, rounds: int,
+                      stage_of: dict | None = None) -> ProfileTable:
     """Summarize a captured ``jax.profiler.trace`` logdir with the first
-    working backend."""
+    working backend; ``stage_of`` as in :func:`parse_xspace_bytes`."""
     paths = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
     if not paths:
         raise RuntimeError(f"no xplane.pb under {logdir}")
@@ -469,11 +493,14 @@ def summarize_logdir(logdir: str, rounds: int) -> ProfileTable:
 
             data, _ = conv.xspace_to_tool_data(paths, "hlo_stats", {})
             obj = data if isinstance(data, dict) else json.loads(data)
-            return parse_hlo_stats_obj(obj, rounds, backend=conv_name)
+            table = parse_hlo_stats_obj(obj, rounds, backend=conv_name,
+                                        stage_of=stage_of)
+            if table.rows:  # XLA:CPU traces convert to a table of no rows
+                return table
         except Exception:  # noqa: BLE001 — converter wheels break often;
             pass           # the direct parse below reads the same trace
     blobs = [open(p, "rb").read() for p in paths]
-    return parse_xspace_bytes(blobs, rounds)
+    return parse_xspace_bytes(blobs, rounds, stage_of)
 
 
 def profile_workload(
@@ -492,12 +519,15 @@ def profile_workload(
 
     ``rounds`` is truncated down to a whole number of phases (never to
     zero). The returned table carries the workload fingerprint so a
-    recorded profile is as self-describing as a schema-v2 bench line."""
+    recorded profile is as self-describing as a schema-v2 bench line,
+    and every row the engine stage of its op in the scan's compiled
+    text (``ProfileTable.by_stage``)."""
     import shutil
 
     import jax
     import jax.numpy as jnp
 
+    from . import stages
     from .sweep import (
         bench_schedule,
         build_bench,
@@ -526,7 +556,7 @@ def profile_workload(
         st = scan(st, po, pt, pv)
         jax.block_until_ready(st)
 
-    table = summarize_logdir(logdir, rounds)
+    table = summarize_logdir(logdir, rounds, stages.stages_of(scan))
     table.fingerprint = workload_fingerprint(
         config, n_peers, msg_slots, he, r, seg_rounds=rounds, unroll=u)
     return table
@@ -549,11 +579,16 @@ def format_table(table: ProfileTable, top: int = 30) -> str:
     for k, v in sorted(table.by_category.items(), key=lambda x: -x[1]):
         lines.append(f"  {v:8.1f} us/rd {100 * v / total:5.1f}%  {k}")
     lines.append("")
+    if table.by_stage:
+        lines.append("by stage:")
+        for k, v in sorted(table.by_stage.items(), key=lambda x: -x[1]):
+            lines.append(f"  {v:8.1f} us/rd {100 * v / total:5.1f}%  {k}")
+        lines.append("")
     lines.append(f"top {top} ops:")
     for r in table.top(top):
         lines.append(
             f"  {r.self_us_per_round:7.1f} us/rd {r.name:<30} "
-            f"{r.source[:80]}"
+            f"{r.stage:<13}{r.source[:80]}"
         )
         if r.text:
             lines.append(f"      {r.text[:140]}")

@@ -25,6 +25,7 @@ from flax import struct
 
 from ..config import PeerScoreParams, ticks_for
 from ..ops import bitset
+from ..perf import stages
 from ..state import Net
 
 
@@ -156,6 +157,7 @@ def ip_colocation_surplus_sq(net: Net, threshold: int, whitelist=()) -> jax.Arra
 # the score function (score.go:258-335)
 
 
+@stages.scope("score")
 def compute_scores(
     st: ScoreState,
     in_mesh: jax.Array,   # [N,S,K] bool — router mesh membership
@@ -228,6 +230,7 @@ def compute_scores(
 # decay pass (refreshScores, score.go:497-558)
 
 
+@stages.scope("score")
 def refresh_scores(st: ScoreState, in_mesh: jax.Array, tick, tp: dict, params: PeerScoreParams) -> ScoreState:
     dtz = params.decay_to_zero
     e = lambda a: a[..., None]
@@ -348,6 +351,7 @@ def slot_topic_words(net: Net, msg_topic: jax.Array) -> jax.Array:
     return jnp.where((net.my_topics >= 0)[:, :, None], stw, jnp.uint32(0))
 
 
+@stages.scope("score")
 def on_deliveries(
     st: ScoreState,
     net: Net,
@@ -467,6 +471,7 @@ def on_deliveries(
     )
 
 
+@stages.scope("score")
 def apply_delivery_counts(
     st: ScoreState,
     tp: dict,

@@ -23,6 +23,7 @@ from flax import struct
 
 from . import graph as graphlib
 from .ops import bitset, csr, edges
+from .perf import stages
 from .trace.events import zero_counters
 
 
@@ -95,6 +96,7 @@ class Net:
     # the pre-fusion program bit for bit (the census gate's contract).
     fused: bool = struct.field(pytree_node=False, default=False)
 
+    @stages.scope("edge_gather")
     def edge_gather(self, x: jax.Array) -> jax.Array:
         """x[N, K, ...] -> x[nbr[j,k], rev[j,k], ...] (the edge involution).
         Callers mask with nbr_ok; entries on dead/absent edges are junk
@@ -115,6 +117,7 @@ class Net:
             return edges.edge_permute_banded(x, self.band_off, self.band_rev)
         return edges.edge_permute(x, self.edge_perm)
 
+    @stages.scope("edge_gather")
     def peer_gather(self, v: jax.Array) -> jax.Array:
         """v[N, ...] -> [N, K, ...] neighbor view v[nbr[j,k]]. Same masking
         contract as edge_gather (absent slots read v[0] in both layouts —
@@ -806,6 +809,7 @@ class PhasePubPlan:
     scatter recurrence (last write wins, pads dropped), pinned by
     tests/test_phase_stacked.py against the legacy path."""
 
+    @stages.scope("pub_plan")
     def __init__(self, msgs: MsgTable, n_peers: int, tick0,
                  pub_origin: jax.Array, pub_topic: jax.Array,
                  pub_valid: jax.Array):
@@ -871,6 +875,7 @@ class PhasePubPlan:
         picked = vals_flat[jnp.clip(self._lastw, 0)]        # [r+1, M]
         return jnp.where(self._lastw >= 0, picked, tbl0[None, :])
 
+    @stages.scope("pub_plan")
     def msgs_at(self, i: int) -> MsgTable:
         """The message table as of sub-round ``i`` (after the publishes
         of sub-rounds < i); ``msgs_at(r)`` is the phase-final table."""
@@ -886,6 +891,7 @@ class PhasePubPlan:
             ),
         )
 
+    @stages.scope("pub_plan")
     def apply_to_delivery(self, dlv: "Delivery", i: int, tick_i,
                           scatter_form: bool) -> "Delivery":
         """Sub-round ``i``'s recycled-slot clears + origin seen/fwd/
@@ -931,6 +937,7 @@ class PhasePubPlan:
             pending=pending,
         )
 
+@stages.scope("pub_plan")
 def allocate_publishes(
     msgs: MsgTable,
     dlv: Delivery,
