@@ -50,6 +50,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .ops import edges
 from .perf import stages
 
 
@@ -126,10 +127,16 @@ def _jit_window(run, donate: bool):
     device as a module: named from the scopes' version (the module name
     is part of the persistent cache's key, named scopes are not:
     ``perf.stages.VERSION``) and noted in the stage registry while its
-    Python body is traced, so once per trace and never per dispatch."""
+    Python body is traced, so once per trace and never per dispatch. The
+    same trace counts the rows its edge gathers address by index
+    (``ops/edges.tally_index_rows``), per step call."""
     def window(*args, **kwargs):
-        stages.note_window(jitted, args, kwargs)
-        return run(*args, **kwargs)
+        rows: list = []
+        with edges.tally_index_rows(rows):
+            out = run(*args, **kwargs)
+        stages.note_window(jitted, args, kwargs,
+                           edge_rows=edges.edge_rows_per_dispatch(rows))
+        return out
     window.__name__ = window.__qualname__ = stages.window_name()
     jitted = jax.jit(window, donate_argnums=0 if donate else ())
     return jitted
@@ -206,8 +213,11 @@ def _window_body(step, *, heartbeat=None, check=None, check_every: int = 1,
 
     def call(st, args, j, consts=()):
         if hb is None:
+            edges.mark_dispatch()
             return step(st, *args, *consts)
-        return step(st, *args, *consts, do_heartbeat=hb[j % period])
+        do_heartbeat = hb[j % period]
+        edges.mark_dispatch(do_heartbeat)
+        return step(st, *args, *consts, do_heartbeat=do_heartbeat)
 
     def run(st, xs, due=None, consts=()):
         xs = tuple(xs)

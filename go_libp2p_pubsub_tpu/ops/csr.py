@@ -348,14 +348,14 @@ def unpack_edges(x_e: jax.Array, e_of_nk: jax.Array,
 def edge_permute_flat(x_e: jax.Array, eperm: jax.Array) -> jax.Array:
     """The edge involution in flat space: out[e] = x_e[eperm[e]] —
     E-sized cross-peer movement (the dense form moves N*K)."""
-    _edges._tally("edge", x_e)
+    _edges._tally("edge", x_e, rows=eperm.shape[0])
     return x_e[eperm]
 
 
 def peer_gather_flat(v: jax.Array, col: jax.Array) -> jax.Array:
     """Flat neighbor view: out[e] = v[col[e]] ([N, ...] -> [E, ...])."""
     out = v[col]
-    _edges._tally("peer", out)
+    _edges._tally("peer", out, rows=col.shape[0])
     return out
 
 

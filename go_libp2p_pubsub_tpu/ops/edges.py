@@ -7,6 +7,16 @@ gather lowers to per-element gather HLO — pathologically slow on TPU. But
 N*K edge-slot space, so every such read is a 1-D row gather through a
 static flat index `perm = nbr*K + rev` — the fast TPU gather path.
 
+That gather pays per ROW it addresses, not per byte, and a slot that holds
+no edge is a row like any other (it points at itself). Where the graph's
+degrees are uneven the gather is TIERED (`plan_tiers`, planned once per
+static net by `Net.build` from the column histogram): the head columns
+[0, K0) are gathered whole, of the tail columns [K0, K) only the slots
+that hold an edge move, as one short gather and one scatter onto the
+columns themselves, so every slot comes out bit for bit as `flat[perm]`
+gives it. K0 = K is the one full gather. `_tally` counts the rows every
+gather set addresses, for the window's `edge_rows_per_dispatch`.
+
 Topic-slot payloads ([N,S,K] per-slot bools) are moved across edges by
 packing the S axis into *topic-id bit positions* of uint32 words (T bits
 total), permuting the [N,K,Wt] words, and re-extracting bits at the
@@ -22,6 +32,7 @@ import contextlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax import struct
 
 WORD = 32
 
@@ -35,6 +46,7 @@ WORD = 32
 
 _TALLY: list | None = None
 _BYTES_TALLY: list | None = None
+_ROWS_TALLY: list | None = None
 
 
 class TallyCacheHit(RuntimeError):
@@ -52,9 +64,14 @@ class TallyCacheHit(RuntimeError):
     error instead of a zero."""
 
 
-def _tally(kind: str, moved=None) -> None:
+def _tally(kind: str, moved=None, rows: int = 0) -> None:
+    """One cross-peer gather SET: ``moved`` is the tensor it moves,
+    ``rows`` the rows it addresses by index (a gather's output rows plus
+    a scatter's rows; 0 for rolls, which address none)."""
     if _TALLY is not None:
         _TALLY.append(kind)
+    if _ROWS_TALLY is not None:
+        _ROWS_TALLY.append((kind, int(rows)))
     if _BYTES_TALLY is not None:
         nbytes = None
         if moved is not None and hasattr(moved, "size"):
@@ -94,6 +111,51 @@ def tally_halo_bytes(out: list):
         yield out
     finally:
         _BYTES_TALLY = prev
+
+
+@contextlib.contextmanager
+def tally_index_rows(out: list):
+    """Collect ``(kind, rows)`` per cross-peer gather traced inside the
+    block: the rows addressed by index, the unit the general gather pays
+    in whatever the bytes (PERF.md §6, PR 30: the gather's law).
+    ``driver._jit_window`` arms it around a window's trace
+    and ``mark_dispatch`` cuts the list by step call, so the window's
+    entry in ``perf.stages`` holds the rows of one dispatch."""
+    global _ROWS_TALLY
+    prev = _ROWS_TALLY
+    _ROWS_TALLY = out
+    try:
+        yield out
+    finally:
+        _ROWS_TALLY = prev
+
+
+def mark_dispatch(key=None) -> None:
+    """A window body says that the next step call begins; ``key`` is
+    what the call's trace is keyed by besides its shapes (the static
+    ``do_heartbeat``). No gather set: only the rows tally hears it."""
+    if _ROWS_TALLY is not None:
+        _ROWS_TALLY.append(("dispatch", key))
+
+
+def edge_rows_per_dispatch(tally: list) -> float | None:
+    """Mean ``"edge"`` rows per step call of a ``tally_index_rows`` list
+    cut by ``mark_dispatch``. A jitted step is traced once per key: a
+    later call with the same key replays the cached jaxpr and tallies
+    nothing, so it counts what the first call with its key counted.
+    ``None`` where no call tallied anything (no window body marked a
+    dispatch, or every trace was a replay)."""
+    calls, first = [], {}
+    for kind, val in tally:
+        if kind == "dispatch":
+            calls.append([val, None])
+        elif calls:
+            calls[-1][1] = (calls[-1][1] or 0) + (val if kind == "edge" else 0)
+    for key, rows in calls:
+        if rows is not None:
+            first.setdefault(key, rows)
+    rows = [first[key] for key, _ in calls if key in first]
+    return sum(rows) / len(rows) if rows else None
 
 
 def tally_step(step, state, args=(), kwargs=None, *, net=None,
@@ -196,10 +258,106 @@ def involution_wf(nbr: jax.Array, rev: jax.Array, nbr_ok: jax.Array,
 
 def edge_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
     """x[N, K, ...] -> x[nbr[j,k], rev[j,k], ...] as a flat row gather."""
-    _tally("edge", x)
     n, k = perm.shape
+    _tally("edge", x, rows=n * k)
     flat = x.reshape((n * k,) + x.shape[2:])
     return flat[perm.reshape(-1)].reshape(x.shape)
+
+
+# ---------------------------------------------------------------------------
+# the tiered gather. ``flat[perm]`` pays per ROW it addresses, whatever the
+# row holds, and most of all for a row that points at itself
+# (scripts/gather_law.py; PERF.md §6, PR 30). Slots are left-packed, so on
+# a graph of uneven degree the high columns hold next to no edge, yet every
+# slot of them is a row of the gather. So columns [0, K0) are gathered
+# whole (the head: N*K0 rows out of the same full table), and of columns
+# [K0, K) only the slots that hold an edge move, as one short list (the
+# tail: one small gather, one scatter onto the columns themselves).
+
+#: The tiered gather's cost on a TPU v5e, fitted to the whole tiered gather
+#: of the 100k-peer ``random_connect`` graph at 5 words a row and K0 = 20,
+#: 24, 28 (36.0 / 28.5 / 31.1 ms; my chip run, PR 30): ns per head row and
+#: ns per tail row (its gather and its scatter: ten head rows). The fixed
+#: cost (a slice, a short gather, a scatter and a join more than the one
+#: full gather) is an estimate: the host's clock cannot resolve it.
+HEAD_ROW_NS = 11.3
+TAIL_ROW_NS = 113.0
+TIER_FIXED_NS = 15_000.0
+
+
+@struct.dataclass
+class Tiers:
+    """The plan of a tiered edge gather (``plan_tiers``): index planes of
+    one static graph, baked into the program like ``edge_perm``. K0 is
+    ``head.shape[1]``."""
+
+    head: jax.Array       # [N, K0] i32 = edge_perm[:, :K0]
+    tail_src: jax.Array   # [T] i32 into the slot space n*K + k
+    tail_dst: jax.Array   # [T] i32 into the tail's own n*(K-K0) + k-K0,
+                          # ascending and duplicate-free
+
+    @property
+    def rows(self) -> int:
+        """Rows one gather addresses by index: the head's gather, the
+        tail's gather and the tail's scatter."""
+        return self.head.size + 2 * self.tail_dst.size
+
+
+def tier_cost_ns(col_fill, n: int) -> np.ndarray:
+    """``[K+1]`` modelled ns of one gather for every K0 over the column
+    histogram ``nbr_ok.sum(0)``: ``N*K0`` head rows and the ``tail(K0)``
+    present slots right of them. K0 = K is the one full gather: no tail,
+    no fixed cost, every row at the head's price (the law prices a row of
+    the full gather at up to three times that: the model errs against
+    tiering)."""
+    col_fill = np.asarray(col_fill, np.int64)
+    k = col_fill.size
+    tail = np.append(np.cumsum(col_fill[::-1])[::-1], 0)
+    k0 = np.arange(k + 1)
+    return (n * k0 * HEAD_ROW_NS + tail * TAIL_ROW_NS
+            + np.where(k0 < k, TIER_FIXED_NS, 0.0))
+
+
+def pick_k0(col_fill, n: int) -> int:
+    """The K0 of least ``tier_cost_ns``; K0 = K wins ties."""
+    cost = tier_cost_ns(col_fill, n)
+    k = cost.size - 1
+    best = int(np.argmin(cost))
+    return k if cost[k] <= cost[best] else best
+
+
+def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray,
+               k0: int | None = None) -> Tiers | None:
+    """Plan the tiered gather of one static graph, on the host. ``None``
+    is K0 = K: the one full gather, today's program. ``k0`` is for the
+    tests; ``Net.build`` lets ``pick_k0`` derive it from the graph."""
+    n, k = perm.shape
+    if k0 is None:
+        k0 = pick_k0(nbr_ok.sum(axis=0), n)
+    if k0 >= k:
+        return None
+    rows, cols = np.nonzero(nbr_ok[:, k0:])     # row-major: ascending
+    # cast on the host: a device-side convert is one more program to compile
+    i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
+    return Tiers(head=i32(perm[:, :k0]), tail_src=i32(perm[rows, cols + k0]),
+                 tail_dst=i32(rows * (k - k0) + cols))
+
+
+def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
+    """``edge_permute(x, edge_perm)`` bit for bit on every slot, absent
+    ones included, addressing ``tiers.rows`` rows instead of N*K: an
+    absent slot of the tail keeps its own entry, as its self-pointing row
+    of ``edge_perm`` gives it."""
+    n, k = x.shape[:2]
+    k0 = tiers.head.shape[1]
+    trail = x.shape[2:]
+    _tally("edge", x, rows=tiers.rows)
+    flat = x.reshape((n * k,) + trail)
+    head = flat[tiers.head.reshape(-1)].reshape((n, k0) + trail)
+    tail = x[:, k0:].reshape((n * (k - k0),) + trail).at[tiers.tail_dst].set(
+        flat[tiers.tail_src], unique_indices=True, indices_are_sorted=True)
+    return jnp.concatenate(
+        [head, tail.reshape((n, k - k0) + trail)], axis=1)
 
 
 def detect_banded(

@@ -16,8 +16,9 @@ Three things live here and nowhere else:
 * the registry of the windows traced in this process (``note_window``,
   called by ``driver.make_window`` / ``make_scan`` while the window's
   Python body is traced, never per dispatch) and ``traced_windows()``,
-  which hands the newest of them back, each with its XLA module name
-  and, on demand and memoised, its stage map.
+  which hands the newest of them back, each with its XLA module name,
+  the rows its edge gathers address per step call and, on demand and
+  memoised, its stage map.
 
 What each stage covers (the scope sits inside the callee wherever one
 callee does the work, so every caller gets it):
@@ -147,6 +148,11 @@ class TracedWindow:
     module_name: str
     signature: tuple        # (treedef, ShapeDtypeStruct leaves)
     sharded: bool
+    #: rows the edge gathers of ONE step call address by index, counted
+    #: while the window was traced (``ops/edges.edge_rows_per_dispatch``:
+    #: gather output rows plus scatter rows, 0 for rolls); ``None`` where
+    #: the trace replayed a step traced before it
+    edge_rows_per_dispatch: float | None = None
     _stages: dict | None = None
 
     def stages(self) -> dict | None:
@@ -174,10 +180,12 @@ KEPT_WINDOWS = 16
 _WINDOWS: collections.deque = collections.deque(maxlen=KEPT_WINDOWS)
 
 
-def note_window(jitted, args: tuple, kwargs: dict) -> None:
+def note_window(jitted, args: tuple, kwargs: dict,
+                edge_rows: float | None = None) -> None:
     """Called from inside a window's traced Python body: note its
-    signature once. Outside a trace (``jax.disable_jit``) nothing reaches
-    the device as a module and nothing is noted."""
+    signature once, with the edge rows its trace counted per step call.
+    Outside a trace (``jax.disable_jit``) nothing reaches the device as a
+    module and nothing is noted."""
     import jax
 
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
@@ -198,7 +206,7 @@ def note_window(jitted, args: tuple, kwargs: dict) -> None:
                 and w.sharded == sharded):
             return          # a retrace of what is noted (``stages`` lowers)
     _WINDOWS.append(TracedWindow(jitted, "jit_" + jitted.__name__,
-                                 signature, sharded))
+                                 signature, sharded, edge_rows))
 
 
 def traced_windows() -> list:

@@ -30,7 +30,17 @@ from .trace.events import zero_counters
 @struct.dataclass
 class Net:
     """Static network: topology + subscriptions + identity (survey L0
-    collapsed into arrays; see graph.py for field semantics)."""
+    collapsed into arrays; see graph.py for field semantics).
+
+    ``edge_gather`` moves a per-edge plane across the edge involution by
+    what ``build`` saw in the graph, with no switch of the caller's: rolls
+    on a banded net (``band_off``), the flat [E] space on a CSR build, and
+    on every other dense net a row gather through ``edge_perm``: tiered
+    (``tiers``: head columns whole, of the tail columns only the present
+    slots) where the degree histogram makes that the cheaper program, the
+    one full gather where it does not (full or near-regular columns, toy
+    nets) and on a ``dynamic`` net, whose ``edge_perm`` is traced. Every
+    form returns the same plane on every slot, absent ones included."""
 
     nbr: jax.Array         # [N, K] i32
     nbr_ok: jax.Array      # [N, K] bool
@@ -95,6 +105,10 @@ class Net:
     # edge_layout: one build traces exactly ONE kernel set, False traces
     # the pre-fusion program bit for bit (the census gate's contract).
     fused: bool = struct.field(pytree_node=False, default=False)
+    # tiered edge gather (ops/edges.plan_tiers): planned by ``build`` on a
+    # dense, unbanded, static net whose high columns are nearly empty;
+    # None is K0 = K, the one full gather through ``edge_perm``
+    tiers: edges.Tiers | None = None
 
     @stages.scope("edge_gather")
     def edge_gather(self, x: jax.Array) -> jax.Array:
@@ -115,6 +129,8 @@ class Net:
             return jnp.where(present, got, x)
         if self.band_off is not None:
             return edges.edge_permute_banded(x, self.band_off, self.band_rev)
+        if self.tiers is not None:
+            return edges.edge_permute_tiered(x, self.tiers)
         return edges.edge_permute(x, self.edge_perm)
 
     @stages.scope("edge_gather")
@@ -132,7 +148,7 @@ class Net:
         if self.band_off is not None:
             return edges.peer_gather_banded(v, self.band_off)
         out = v[jnp.clip(self.nbr, 0)]
-        edges._tally("peer", out)
+        edges._tally("peer", out, rows=self.nbr.size)
         return out
 
     # -- flat-edge-space face (edge_layout="csr" only) ---------------------
@@ -318,9 +334,16 @@ class Net:
         else:
             band = (None if dynamic
                     else edges.detect_banded(topo.nbr, topo.rev, topo.nbr_ok))
+        edge_perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
+        # the tiers are static like the band: a dense net that is neither
+        # banded (rolls) nor dynamic (``edge_perm`` is traced there)
+        tiers = (edges.plan_tiers(edge_perm, topo.nbr_ok)
+                 if edge_layout == "dense" and band is None and not dynamic
+                 else None)
         return cls(
             edge_layout=edge_layout,
             fused=bool(fused),
+            tiers=tiers,
             **csr_kw,
             band_off=band[0] if band else None,
             band_rev=band[1] if band else None,
@@ -333,9 +356,7 @@ class Net:
             slot_of=jnp.asarray(subs.slot_of),
             ip_group=jnp.asarray(ip_group),
             direct=jnp.asarray(direct),
-            edge_perm=jnp.asarray(
-                edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
-            ),
+            edge_perm=jnp.asarray(edge_perm),
             protocol=jnp.asarray(protocol, jnp.int8),
         )
 
@@ -356,6 +377,11 @@ class Net:
         if self.band_off is not None or self.csr_band_off is not None:
             raise ValueError(
                 "with_overlay: banded-roll structure is static — build "
+                "the net with Net.build(..., dynamic=True)"
+            )
+        if self.tiers is not None:
+            raise ValueError(
+                "with_overlay: the tiered gather's plan is static — build "
                 "the net with Net.build(..., dynamic=True)"
             )
         kw = dict(nbr=topo.nbr, nbr_ok=topo.nbr_ok, rev=topo.rev,

@@ -283,6 +283,39 @@ def test_gossipsub_phase_parity(r):
     assert_trees_equal(run("dense"), run("csr"), f"phase r={r}")
 
 
+def test_gossipsub_phase_tiered_gather_parity():
+    """The phase engine over a tiered edge gather (K0 < K, forced through
+    the plan helper) leaves the same full state tree as the same net with
+    the one full gather (K0 = K), after 3 phases with lossy links."""
+    from go_libp2p_pubsub_tpu.ops import edges
+
+    r = 4
+    topo = ragged_topo()
+    subs = graph.subscribe_all(N, 1)
+    sp = default_peer_score_params(1)
+    po, pt, pv = publish_schedule(3 * r)
+    base = Net.build(topo, subs)
+    k = base.max_degree
+    perm = np.asarray(base.edge_perm)
+
+    def run(tiers):
+        net = base.replace(tiers=tiers)
+        cfg = _gossip_cfg("dense", heartbeat_every=r)
+        st = GossipSubState.init(net, M, cfg, score_params=sp, seed=0)
+        step = make_gossipsub_phase_step(cfg, net, r, score_params=sp)
+        for p in range(3):
+            st = step(st, po[p * r:(p + 1) * r], pt[:r], pv[:r],
+                      do_heartbeat=True)
+        return st
+
+    full = run(None)
+    assert int(full.core.tick) == 3 * r
+    for k0 in (k // 2, 2):
+        tiers = edges.plan_tiers(perm, topo.nbr_ok, k0)
+        assert tiers.head.shape[1] == k0 < k and tiers.tail_dst.size
+        assert_trees_equal(full, run(tiers), f"phase tiers K0={k0}")
+
+
 def test_scanned_window_parity():
     """driver.make_scan over a CSR step == the dense python loop — the
     scanned window carries the sparse exchange inside one program."""
