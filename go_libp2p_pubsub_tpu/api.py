@@ -729,6 +729,22 @@ class Network:
     def dense_connect(self, d: int = 10, seed: int = 0) -> None:
         self.sparse_connect(d, seed)
 
+    def subnet_connect(self, d_any: int = 10, d_subnet: int = 5,
+                       seed: int = 0) -> None:
+        """``graph.subnet_connect`` over the topics joined so far: each
+        node dials ``d_any`` random others plus ``d_subnet`` co-subscribers
+        in every topic it has joined (the Eth2 attestation-subnet wiring:
+        without the second kind a node that joins 2 of 64 topics finds no
+        mesh peers). Call after the joins, before ``start()``."""
+        subscribed = np.zeros((len(self.nodes), max(1, len(self.topic_ids))),
+                              bool)
+        for node in self.nodes:
+            for t in node.topics.values():
+                subscribed[node.idx, t.tid] = True
+        for a, b in zip(*graphlib.subnet_dials(subscribed, d_any, d_subnet,
+                                               seed)):
+            self.connect(self.nodes[int(a)], self.nodes[int(b)])
+
     # -- internal assembly hooks ------------------------------------------
 
     def _check_not_started(self, what: str) -> None:

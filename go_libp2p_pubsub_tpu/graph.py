@@ -128,6 +128,91 @@ def random_connect(n: int, d: int, seed: int = 0, max_degree: int | None = None)
     return _from_edge_lists(n, dialed, max_degree)
 
 
+def _from_dial_arrays(n: int, src: np.ndarray, dst: np.ndarray,
+                      max_degree: int | None) -> Topology:
+    """``_from_edge_lists`` for dials given as arrays ``src -> dst``, in
+    bulk (a 100k-peer graph of 4 M edge slots in seconds): slots
+    left-packed, neighbours ascending. A connection dialed from both ends
+    is one edge, ``outbound`` at its lower-numbered end."""
+    lo, hi = np.minimum(src, dst), np.maximum(src, dst)
+    key, first = np.unique(lo.astype(np.int64) * n + hi, return_index=True)
+    lo, hi = key // n, key % n
+    dial_lo = src[first] == lo
+    a = np.concatenate([lo, hi])
+    b = np.concatenate([hi, lo])
+    out = np.concatenate([dial_lo, ~dial_lo])
+    order = np.lexsort((b, a))
+    a, b, out = a[order], b[order], out[order]
+    deg = np.bincount(a, minlength=n).astype(np.int32)
+    k = max(1, int(deg.max())) if max_degree is None else max_degree
+    if int(deg.max(initial=0)) > k:
+        raise ValueError(f"max degree {int(deg.max())} exceeds K={k}")
+    start = np.concatenate([[0], np.cumsum(deg)[:-1]])
+    slot = np.arange(a.size) - start[a]
+    nbr = np.full((n, k), -1, np.int32)
+    rev = np.zeros((n, k), np.int32)
+    outb = np.zeros((n, k), bool)
+    nbr[a, slot] = b
+    outb[a, slot] = out
+    # edge i of the first half and edge i of the second are each other's
+    # reverse: where the sort put the one, the other's slot is read
+    where = np.empty(order.size, np.int64)
+    where[order] = np.arange(order.size)
+    rev[a, slot] = slot[where[(order + lo.size) % order.size]]
+    return Topology(nbr=nbr, nbr_ok=nbr >= 0, rev=rev, outbound=outb,
+                    degree=deg)
+
+
+def _draw_others(rng, m: int, d: int) -> np.ndarray:
+    """``[m, min(d, m-1)]``: for each of ``m`` members that many DISTINCT
+    others, as positions 0..m-1 (everybody else where there are no more);
+    a row with a repeat is drawn again."""
+    d = min(int(d), m - 1)
+    if d <= 0:
+        return np.zeros((m, 0), np.int64)
+    me = np.arange(m)[:, None]
+    if d == m - 1:
+        others = np.tile(np.arange(m - 1), (m, 1))
+        return others + (others >= me)
+    picks = rng.integers(0, m - 1, size=(m, d))
+    while True:
+        srt = np.sort(picks, axis=1)
+        again = np.flatnonzero((srt[:, 1:] == srt[:, :-1]).any(axis=1))
+        if again.size == 0:
+            return picks + (picks >= me)
+        picks[again] = rng.integers(0, m - 1, size=(again.size, d))
+
+
+def subnet_dials(subscribed: np.ndarray, d_any: int, d_subnet: int,
+                 seed: int = 0) -> tuple[np.ndarray, np.ndarray]:
+    """The dials ``(src, dst)`` of :func:`subnet_connect`."""
+    n, n_topics = subscribed.shape
+    rng = np.random.default_rng(int(seed))
+    picks = _draw_others(rng, n, d_any)
+    src = [np.repeat(np.arange(n), picks.shape[1])]
+    dst = [picks.reshape(-1)]
+    for t in range(n_topics):
+        members = np.flatnonzero(subscribed[:, t])
+        picks = _draw_others(rng, members.size, d_subnet)
+        src.append(np.repeat(members, picks.shape[1]))
+        dst.append(members[picks].reshape(-1))
+    return np.concatenate(src), np.concatenate(dst)
+
+
+def subnet_connect(subs: "Subscriptions", d_any: int = 10, d_subnet: int = 5,
+                   seed: int = 0, max_degree: int | None = None) -> Topology:
+    """The graph sparse subscriptions need (the Eth2 attestation subnets:
+    2 of 64 topics a peer): every peer dials ``d_any`` random others
+    (denseConnect) plus ``d_subnet`` distinct random co-subscribers in
+    each topic it subscribes — what a client's discovery (ENR
+    ``attnets``) leaves behind. Clamped to the members there are: a topic
+    of one member dials nobody. With ``random_connect`` alone a peer has
+    well under one co-subscribed neighbour per topic and no mesh forms;
+    over a ring lattice a topic's induced subgraph fragments."""
+    src, dst = subnet_dials(np.asarray(subs.subscribed), d_any, d_subnet, seed)
+    return _from_dial_arrays(subs.subscribed.shape[0], src, dst, max_degree)
+
+
 def ring_lattice(n: int, d: int, max_degree: int | None = None) -> Topology:
     """Deterministic ring lattice (each node dials its next d ring
     neighbors); used for reproducible small tests and the scale bench.

@@ -179,6 +179,9 @@ class GossipSubConfig:
     validator_timeout_rounds: int = 0
     # fanout (publishing to unjoined topics, gossipsub.go:981-1002,1517-1554)
     fanout_slots: int = 2         # concurrent unjoined publish topics/peer
+    # FanoutTTL in HEARTBEATS (ticks_for(fanout_ttl, heartbeat_interval));
+    # ``lastpub`` and ``tick`` count delivery ROUNDS, so expiry compares
+    # against ``fanout_ttl_rounds``
     fanout_ttl_ticks: int = 60
     # aggregate trace counters (EventTracer accounting). Tracing is opt-in
     # in the reference (WithEventTracer); False skips the event popcount
@@ -369,6 +372,12 @@ class GossipSubConfig:
                 opportunistic_graft_threshold=thresholds.opportunistic_graft_threshold,
             )
         return cls(**kw)
+
+    @property
+    def fanout_ttl_rounds(self) -> int:
+        """FanoutTTL on the clock ``tick`` counts in: delivery rounds
+        (``fanout_ttl_ticks`` heartbeats of ``heartbeat_every`` rounds)."""
+        return self.fanout_ttl_ticks * self.heartbeat_every
 
     def validation_timed_out(self, topic: int) -> bool:
         """True when this topic's async verdict can never land inside the
@@ -815,6 +824,7 @@ def fanout_topic_words(fanout_topic: jax.Array, msg_topic: jax.Array) -> jax.Arr
     return bitset.pack(bits)
 
 
+@stages.part("fanout")
 def fanout_carry_words(fanout_peers: jax.Array, fanout_topic: jax.Array,
                        msg_topic: jax.Array) -> jax.Array:
     """[N,K,W]: words each peer pushes on edge k for its fanout topics
@@ -829,37 +839,32 @@ def fanout_carry_words(fanout_peers: jax.Array, fanout_topic: jax.Array,
 # The [N, F, K] bool peers plane is a pathological write target on TPU —
 # bit-packed pred tiles make every sub-round update a read-modify-write
 # over layout-padded tiles (the 2-axis scatter measured 670 us/round at
-# eth2 N=100k, the P-step where-chain still 226 us). K <= 32, so the K
-# axis packs into ONE u32 per (peer, slot): updates become [N, F] u32
-# selects and the carry consumer extracts bits on the fly. The phase
-# engine packs at its head and unpacks at its tail, so the state
-# dataclass, the heartbeat, peer transitions, and every external consumer
-# keep the bool plane.
+# eth2 N=100k, the P-step where-chain still 226 us at K = 16 and 4,551 us
+# at K = 65: PERF.md §6, PR 31). So the K axis packs into ceil(K/32) u32
+# words per (peer, slot): updates become [N, F, Wk] u32 selects and the
+# carry consumer extracts bits on the fly. The phase engine packs at its
+# head and unpacks at its tail, so the state dataclass, the heartbeat,
+# peer transitions, and every external consumer keep the bool plane.
 
+@stages.part("fanout")
 def pack_fanout_peers(fanout_peers: jax.Array) -> jax.Array:
-    """[N,F,K] bool -> [N,F] u32 edge bitmask (K <= 32)."""
-    k = fanout_peers.shape[-1]
-    assert k <= 32, "packed fanout form needs max_degree <= 32"
-    w = jnp.uint32(1) << jnp.arange(k, dtype=jnp.uint32)
-    return jnp.sum(
-        jnp.where(fanout_peers, w, jnp.uint32(0)), axis=-1, dtype=jnp.uint32
-    )
+    """[..., K] bool -> [..., ceil(K/32)] u32 edge bitmask words."""
+    return bitset.pack(fanout_peers)
 
 
+@stages.part("fanout")
 def unpack_fanout_peers(fp_pack: jax.Array, k: int) -> jax.Array:
-    """[N,F] u32 -> [N,F,K] bool."""
-    return (
-        (fp_pack[:, :, None] >> jnp.arange(k, dtype=jnp.uint32)) & 1
-    ).astype(bool)
+    """[N,F,ceil(K/32)] u32 -> [N,F,K] bool."""
+    return bitset.unpack(fp_pack, k)
 
 
+@stages.part("fanout")
 def fanout_carry_words_packed(fp_pack: jax.Array, k: int,
                               fanout_topic: jax.Array,
                               msg_topic: jax.Array) -> jax.Array:
-    """fanout_carry_words on the packed [N,F] u32 peers form (the
+    """fanout_carry_words on the packed [N,F,Wk] u32 peers form (the
     on-the-fly unpack fuses into the carry fold — same XLA graph, but
-    the loop reads 0.8 MB of packed words instead of the padded bool
-    plane)."""
+    the loop reads the packed words instead of the padded bool plane)."""
     return fanout_carry_words(
         unpack_fanout_peers(fp_pack, k), fanout_topic, msg_topic
     )
@@ -911,6 +916,7 @@ def gossip_edge_mask(cfg: GossipSubConfig, net: Net, st: GossipSubState,
     return mask & joined_words[:, None, :]
 
 
+@stages.part("fanout")
 def update_fanout_on_publish(
     cfg: GossipSubConfig,
     net: Net,
@@ -927,7 +933,7 @@ def update_fanout_on_publish(
     D random eligible peers (gossipsub.go:983-998) and stamps lastpub.
 
     Returns the updated state — or, when ``fp_pack`` (the phase loop's
-    packed [N,F] u32 peers form) is given, ``(state, fp_pack)`` with
+    packed [N,F,Wk] u32 peers form) is given, ``(state, fp_pack)`` with
     ``state.fanout_peers`` left untouched (stale; the phase tail unpacks
     the packed form back into it)."""
     thr = cfg if thr is None else thr
@@ -953,19 +959,31 @@ def update_fanout_on_publish(
     oldest_slot = jnp.argmin(st.fanout_lastpub[o] + jnp.where(ftop_o >= 0, 0, -(2**30)), axis=1)
     fresh = need & ~has_match
     idx_p = jnp.arange(p_dim)
-    same_origin_before = (
+    earlier_same_origin = (
         fresh[None, :] & fresh[:, None]
         & (o[None, :] == o[:, None]) & (idx_p[None, :] < idx_p[:, None])
-    )
-    fresh_rank = jnp.sum(same_origin_before.astype(jnp.int32), axis=1)  # [P]
+    )  # [j, i]: i is an earlier fresh publish of j's origin
+    # a fresh publish that repeats an earlier one of its round (same
+    # origin AND topic) shares that one's slot and keeps its peers: the
+    # reference's fanout is a map by topic (gossipsub.go:444), one entry
+    # a topic
+    twin = earlier_same_origin & (t[None, :] == t[:, None])
+    has_twin = jnp.any(twin, axis=1)
+    first_twin = jnp.argmax(twin, axis=1)
+    fresh_rank = jnp.sum(
+        (earlier_same_origin & ~has_twin[None, :]).astype(jnp.int32), axis=1
+    )  # [P]
     slot = jnp.where(has_match, match_slot, (oldest_slot + fresh_rank) % f_dim)
+    slot = jnp.where(has_twin, slot[first_twin], slot)
+    fresh = fresh & ~has_twin
 
     # a matched slot whose peer set has emptied (churn, threshold filtering)
     # is repopulated like a fresh one (gossipsub.go:983-989: empty fanout
     # map entry => select peers anew)
     if fp_pack is not None:
-        match_empty = has_match & (
-            jnp.take_along_axis(fp_pack[o], slot[:, None], axis=1)[:, 0] == 0
+        match_empty = has_match & jnp.all(
+            jnp.take_along_axis(fp_pack[o], slot[:, None, None], axis=1)
+            [:, 0, :] == 0, axis=-1
         )
     else:
         match_empty = has_match & (
@@ -1006,7 +1024,7 @@ def update_fanout_on_publish(
     # [N, F] winner plane + two-chain combine broke the single loop
     # fusion XLA builds for this direct P-step where-chain)
     packed = fp_pack is not None
-    sel_pack = pack_fanout_peers(sel) if packed else None  # [P] u32
+    sel_pack = pack_fanout_peers(sel) if packed else None  # [P,Wk] u32
     fanout_peers = st.fanout_peers
     for j in range(p_dim):
         mask = ((rows == jnp.where(need[j], o[j], net.n_peers))[:, None]
@@ -1014,7 +1032,8 @@ def update_fanout_on_publish(
         fanout_topic = jnp.where(mask, t[j], fanout_topic)
         fanout_lastpub = jnp.where(mask, tick, fanout_lastpub)
         if packed:
-            fp_pack = jnp.where(mask & fresh[j], sel_pack[j], fp_pack)
+            fp_pack = jnp.where((mask & fresh[j])[:, :, None],
+                                sel_pack[j][None, None, :], fp_pack)
         else:
             fanout_peers = jnp.where(
                 (mask & fresh[j])[:, :, None], sel[j][None, None, :],
@@ -1339,36 +1358,37 @@ def heartbeat(cfg: GossipSubConfig, net: Net, st: GossipSubState, tp: dict,
     fpeers = st.fanout_peers
     flastpub = st.fanout_lastpub
     if nbr_sub_words is not None and cfg.fanout_slots > 0:
-        # expire by FanoutTTL since last publish (gossipsub.go:1518-1524)
-        expired = (ft >= 0) & (flastpub + cfg.fanout_ttl_ticks < tick)
-        ft = jnp.where(expired, -1, ft)
-        f_live = ft >= 0
-        fpeers = fpeers & f_live[:, :, None]
-        # drop peers below the publish threshold (gossipsub.go:1528-1534)
-        if cfg.score_enabled:
-            fpeers = fpeers & (scores[:, None, :] >= thr.publish_threshold)
-        # neighbor-subscribes-fanout-topic via topic-bit extraction
-        n_f, f_dim = ft.shape
-        nbr_sub_f = bitset.bit_get(
-            jnp.broadcast_to(
-                nbr_sub_words[:, None, :, :], (n_f, f_dim) + nbr_sub_words.shape[1:]
-            ),
-            jnp.broadcast_to(jnp.clip(ft, 0)[:, :, None], fpeers.shape),
-        )
-        mesh_capable = (net.peer_gather(net.protocol) >= 1) & net.nbr_ok
-        base_f = (
-            nbr_sub_f
-            & mesh_capable[:, None, :]
-            & ~net.direct[:, None, :]
-            & f_live[:, :, None]
-        )
-        cand_f = base_f & ~fpeers
-        if cfg.score_enabled:
-            cand_f = cand_f & (scores[:, None, :] >= thr.publish_threshold)
-        ineed_f = jnp.where(f_live, msh.D - count_true(fpeers), 0)
-        kf1, kf2 = jax.random.split(jax.random.fold_in(key, 11))
-        fpeers = fpeers | masked_width_random(kf1, cand_f, ineed_f, k_dim,
-                                              fused=cfg.fused)
+        with stages.part("fanout"):
+            # expire by FanoutTTL since last publish (gossipsub.go:1518-1524)
+            expired = (ft >= 0) & (flastpub + cfg.fanout_ttl_rounds < tick)
+            ft = jnp.where(expired, -1, ft)
+            f_live = ft >= 0
+            fpeers = fpeers & f_live[:, :, None]
+            # drop peers below the publish threshold (gossipsub.go:1528-1534)
+            if cfg.score_enabled:
+                fpeers = fpeers & (scores[:, None, :] >= thr.publish_threshold)
+            # neighbor-subscribes-fanout-topic via topic-bit extraction
+            n_f, f_dim = ft.shape
+            nbr_sub_f = bitset.bit_get(
+                jnp.broadcast_to(
+                    nbr_sub_words[:, None, :, :], (n_f, f_dim) + nbr_sub_words.shape[1:]
+                ),
+                jnp.broadcast_to(jnp.clip(ft, 0)[:, :, None], fpeers.shape),
+            )
+            mesh_capable = (net.peer_gather(net.protocol) >= 1) & net.nbr_ok
+            base_f = (
+                nbr_sub_f
+                & mesh_capable[:, None, :]
+                & ~net.direct[:, None, :]
+                & f_live[:, :, None]
+            )
+            cand_f = base_f & ~fpeers
+            if cfg.score_enabled:
+                cand_f = cand_f & (scores[:, None, :] >= thr.publish_threshold)
+            ineed_f = jnp.where(f_live, msh.D - count_true(fpeers), 0)
+            kf1, kf2 = jax.random.split(jax.random.fold_in(key, 11))
+            fpeers = fpeers | masked_width_random(kf1, cand_f, ineed_f, k_dim,
+                                                  fused=cfg.fused)
 
     # ---- choke/unchoke decision (routers/choke.py, DESIGN.md §24b) ------
     # after mesh maintenance (the guard must see the post-maintenance
@@ -1425,28 +1445,29 @@ def heartbeat(cfg: GossipSubConfig, net: Net, st: GossipSubState, tp: dict,
 
     # fanout-topic gossip (gossipsub.go:1551-1553; fanout peers excluded)
     if nbr_sub_words is not None and cfg.fanout_slots > 0:
-        gossip_cand_f = base_f & ~fpeers
-        if gossip_suppress is not None:
-            gossip_cand_f = gossip_cand_f & ~gossip_suppress[:, None, :]
-        if cfg.score_enabled:
-            gossip_cand_f = gossip_cand_f & (scores[:, None, :] >= thr.gossip_threshold)
-        n_cand_f = count_true(gossip_cand_f)
-        target_f = jnp.where(
-            (ft >= 0),
-            jnp.maximum(
-                msh.Dlazy,
-                (jnp.asarray(msh.gossip_factor, jnp.float32)
-                 * n_cand_f.astype(jnp.float32)).astype(jnp.int32),
-            ),
-            0,
-        )
-        chosen_f = masked_width_random(kf2, gossip_cand_f, target_f, k_dim,
-                                       fused=cfg.fused)  # [N,F,K]
-        ftw = fanout_topic_words(ft, st.core.msgs.topic)
-        adv_f = jnp.where(
-            chosen_f[..., None], (gwin[:, None, :] & ftw)[:, :, None, :], jnp.uint32(0)
-        )
-        ihave_out = ihave_out | bitset.word_or_reduce(adv_f, axis=1)
+        with stages.part("fanout"):
+            gossip_cand_f = base_f & ~fpeers
+            if gossip_suppress is not None:
+                gossip_cand_f = gossip_cand_f & ~gossip_suppress[:, None, :]
+            if cfg.score_enabled:
+                gossip_cand_f = gossip_cand_f & (scores[:, None, :] >= thr.gossip_threshold)
+            n_cand_f = count_true(gossip_cand_f)
+            target_f = jnp.where(
+                (ft >= 0),
+                jnp.maximum(
+                    msh.Dlazy,
+                    (jnp.asarray(msh.gossip_factor, jnp.float32)
+                     * n_cand_f.astype(jnp.float32)).astype(jnp.int32),
+                ),
+                0,
+            )
+            chosen_f = masked_width_random(kf2, gossip_cand_f, target_f, k_dim,
+                                           fused=cfg.fused)  # [N,F,K]
+            ftw = fanout_topic_words(ft, st.core.msgs.topic)
+            adv_f = jnp.where(
+                chosen_f[..., None], (gwin[:, None, :] & ftw)[:, :, None, :], jnp.uint32(0)
+            )
+            ihave_out = ihave_out | bitset.word_or_reduce(adv_f, axis=1)
 
     # mcache.Shift (gossipsub.go:1563)
     mcache = jnp.concatenate(

@@ -122,7 +122,6 @@ from .gossipsub import (
     apply_validation_throttle,
     control_exchange,
     control_exchange_coalesced,
-    fanout_carry_words,
     fanout_carry_words_packed,
     handle_graft_prune,
     pack_fanout_peers,
@@ -569,13 +568,12 @@ def make_gossipsub_phase_step(
         served_lo, served_hi = st2.served_lo, st2.served_hi
         promise_mid = st2.promise_mid
         fanout_st = st2  # fanout_topic/lastpub evolve per sub-round
-        # fanout peers ride the loop in packed [N,F] u32 form (the bool
-        # [N,F,K] plane is a pathological per-sub-round write target —
-        # see pack_fanout_peers); unpacked back at the phase tail. The
-        # packing needs K <= 32; wider-degree nets keep the bool path.
+        # fanout peers ride the loop in packed [N,F,ceil(K/32)] u32 form
+        # (the bool [N,F,K] plane is a pathological per-sub-round write
+        # target — see pack_fanout_peers); unpacked back at the phase tail
         fp_pack = (
             pack_fanout_peers(st2.fanout_peers)
-            if cfg.fanout_slots > 0 and k_dim <= 32 else None
+            if cfg.fanout_slots > 0 else None
         )
 
         zkw = jnp.zeros((n_peers, k_dim, w), jnp.uint32)
@@ -711,10 +709,6 @@ def make_gossipsub_phase_step(
             if fp_pack is not None:
                 carry = carry | fanout_carry_words_packed(
                     fp_pack, k_dim, fanout_st.fanout_topic, msgs.topic
-                )
-            elif cfg.fanout_slots > 0:
-                carry = carry | fanout_carry_words(
-                    fanout_st.fanout_peers, fanout_st.fanout_topic, msgs.topic
                 )
             carry = carry | jnp.where(
                 flood_send[:, :, None], jnp.uint32(0xFFFFFFFF), jnp.uint32(0)
@@ -994,7 +988,7 @@ def make_gossipsub_phase_step(
                 n_pub = n_pub + jnp.sum(is_pub.astype(jnp.int32))
 
             if cfg.fanout_slots > 0:
-                upd = update_fanout_on_publish(
+                fanout_st, fp_pack = update_fanout_on_publish(
                     cfg, net_l,
                     fanout_st.replace(core=fanout_st.core.replace(tick=tick_i)),
                     pub_origin[i], pub_topic[i],
@@ -1004,10 +998,6 @@ def make_gossipsub_phase_step(
                     nbr_sub_words_l,
                     fp_pack=fp_pack, thr=thr, msh=msh,
                 )
-                if fp_pack is not None:
-                    fanout_st, fp_pack = upd
-                else:
-                    fanout_st = upd
 
         # ---- phase tail (once) ------------------------------------------
         stage("phase_tail")

@@ -730,7 +730,7 @@ class OracleGossipSub:
             # threshold filtering, top-up to D
             if cfg.fanout_slots > 0 and self.fanout[i]:
                 for t in list(self.fanout[i]):
-                    if self.fanout_lastpub[i].get(t, 0) + cfg.fanout_ttl_ticks < tick:
+                    if self.fanout_lastpub[i].get(t, 0) + cfg.fanout_ttl_rounds < tick:
                         del self.fanout[i][t]
                         self.fanout_lastpub[i].pop(t, None)
                         continue

@@ -47,6 +47,19 @@ Scopes nest (``gs.data_round/gs.edge_gather``): the innermost is the
 stage. An instruction with none is ``unscoped``: what XLA itself puts
 in (carry copies, layout conversions of the scan, the ``while``).
 
+Parts. A second, finer family of scopes marks a mechanism INSIDE the
+stages: ``part(name)`` is ``jax.named_scope("gsx." + name)``, ``PARTS``
+the fixed tuple. ``stage_of`` does not see them (a part's instructions
+stay booked to the stage around them, and the stages still add up to
+the window); ``part_of`` / ``instruction_parts`` / ``TracedWindow.parts``
+give ``{instruction: part}`` for the instructions inside one, from the
+same compiled text.
+
+  fanout        publishing to a topic the origin has not joined:
+                ``gossipsub.update_fanout_on_publish``, ``fanout_carry_
+                words[_packed]``, the packed form's pack / unpack, and the
+                heartbeat's fanout maintenance and fanout gossip blocks
+
 Known limits. A fusion carries one ``op_name``, its root's: a fusion
 that spans two stages is booked to the root's. A tracer carries no
 device assignment, so ``traced_windows()`` can only lower for one
@@ -68,15 +81,21 @@ from typing import Any
 #: a named scope is debug info: an executable compiled before a scope
 #: moved would be loaded for the program after it, with the old names.
 #: The module name IS part of the key. So ANY PR THAT MOVES, ADDS OR
-#: RENAMES A SCOPE BUMPS THIS.
+#: RENAMES A SCOPE BUMPS THIS. (The ``gsx.fanout`` part came without a
+#: bump: it is traced only where ``fanout_slots`` > 0, and the PR that
+#: brought it changed those programs' FanoutTTL constant, so no
+#: executable from before it has their key.)
 VERSION = 1
 
 PREFIX = "gs."
 STAGES = ("control_head", "pub_plan", "data_round", "edge_gather", "deliver",
           "score", "heartbeat", "phase_tail")
 UNSCOPED = "unscoped"
+PART_PREFIX = "gsx."
+PARTS = ("fanout",)
 
 _SCOPE_RE = re.compile(re.escape(PREFIX) + r"([a-z_]+)")
+_PART_RE = re.compile(re.escape(PART_PREFIX) + r"([a-z_]+)")
 _INSTRUCTION_RE = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 
@@ -89,6 +108,16 @@ def scope(stage: str):
     import jax
 
     return jax.named_scope(PREFIX + stage)
+
+
+def part(name: str):
+    """``jax.named_scope`` of one of ``PARTS``: context manager and
+    decorator, as ``scope``."""
+    if name not in PARTS:
+        raise ValueError(f"no part {name!r} in perf.stages.PARTS")
+    import jax
+
+    return jax.named_scope(PART_PREFIX + name)
 
 
 class Cursor(contextlib.ExitStack):
@@ -124,19 +153,40 @@ def stage_of(op_name: str) -> str:
     return found[-1] if found else UNSCOPED
 
 
-def instruction_stages(hlo_text: str) -> dict:
-    """``{instruction name: stage}`` of a COMPILED module's text: every
-    instruction of the entry, of ``while`` bodies, of called and of fused
-    computations (a fusion is an instruction of its caller and has its
-    own metadata)."""
-    out = {}
+def part_of(op_name: str) -> str | None:
+    """The innermost ``gsx.*`` component of an ``op_name``, else ``None``."""
+    found = [p for p in _PART_RE.findall(op_name) if p in PARTS]
+    return found[-1] if found else None
+
+
+def _instruction_maps(hlo_text: str) -> tuple:
+    """``({instruction name: stage}, {instruction name: part})`` of a
+    COMPILED module's text: every instruction of the entry, of ``while``
+    bodies, of called and of fused computations (a fusion is an
+    instruction of its caller and has its own metadata). The second map
+    holds only the instructions inside a part."""
+    stage_map, part_map = {}, {}
     for line in hlo_text.splitlines():
         m = _INSTRUCTION_RE.match(line)
         if m is None:
             continue
         op = _OP_NAME_RE.search(line)
-        out[m.group(1)] = stage_of(op.group(1)) if op else UNSCOPED
-    return out
+        stage_map[m.group(1)] = stage_of(op.group(1)) if op else UNSCOPED
+        inside = part_of(op.group(1)) if op else None
+        if inside is not None:
+            part_map[m.group(1)] = inside
+    return stage_map, part_map
+
+
+def instruction_stages(hlo_text: str) -> dict:
+    """``{instruction name: stage}`` of a compiled module's text."""
+    return _instruction_maps(hlo_text)[0]
+
+
+def instruction_parts(hlo_text: str) -> dict:
+    """``{instruction name: part}`` of a compiled module's text, for the
+    instructions inside a ``gsx.*`` scope."""
+    return _instruction_maps(hlo_text)[1]
 
 
 @dataclasses.dataclass(eq=False)
@@ -154,22 +204,30 @@ class TracedWindow:
     #: the trace replayed a step traced before it
     edge_rows_per_dispatch: float | None = None
     _stages: dict | None = None
+    _parts: dict | None = None
 
-    def stages(self) -> dict | None:
-        """The stage map of this window's compiled module, or ``None``
-        where it cannot be had (``sharded``). Lowers and compiles from
-        the signature once: with the persistent cache on that is a
+    def _maps(self) -> tuple:
+        """Both maps of this window's compiled module. Lowers and compiles
+        from the signature once: with the persistent cache on that is a
         retrace and a cache load."""
-        if self.sharded:
-            return None
         if self._stages is None:
             import jax
 
             treedef, leaves = self.signature
             args, kwargs = jax.tree_util.tree_unflatten(treedef, leaves)
-            self._stages = instruction_stages(
+            self._stages, self._parts = _instruction_maps(
                 self.jitted.lower(*args, **kwargs).compile().as_text())
-        return self._stages
+        return self._stages, self._parts
+
+    def stages(self) -> dict | None:
+        """The stage map of this window's compiled module, or ``None``
+        where it cannot be had (``sharded``)."""
+        return None if self.sharded else self._maps()[0]
+
+    def parts(self) -> dict | None:
+        """``{instruction: part}`` of the same module, for the
+        instructions inside a part; ``None`` where ``stages`` is."""
+        return None if self.sharded else self._maps()[1]
 
 
 #: The registry holds its windows, because a trace is read once the loop

@@ -137,14 +137,20 @@ def bench_cell(n_peers: int, msg_slots: int, seed: int = 0, config: str = "defau
     default — GossipSub v1.1, single topic, live scoring (the BASELINE.json
               north-star workload the driver measures)
     eth2    — 100k-peer Eth2 attestation-subnet geometry: 64 topics, each
-              peer subscribed to 2 random subnets (BASELINE.json config #5).
-              A THROUGHPUT workload, not a coverage one: over the banded
-              ring-lattice adjacency a topic's 3%-density induced subgraph
-              fragments into segments (1-D lattices don't percolate under
-              dilution), so publishes propagate within their segment only —
-              coverage claims live in the parity suite's random-graph
-              configs (PARITY.md eth2 row: reachability structurally
-              attributed)
+              peer subscribed to 2 random subnets (BASELINE.json config #5),
+              on ``graph.subnet_connect`` (10 random dials a peer plus 5
+              to co-subscribers in each of its subnets): the deployment
+              ``benchmark/configs/eth2-100k.json`` measures, on which a
+              publish DELIVERS (every subscriber of the topic, where the
+              origin subscribes it or has a neighbour that does: PARITY.md
+              "eth2 subnets: 64 topics" row, and the benchmark's
+              ``topic_undelivered``). Until PR 31 this cell sat on the
+              banded ring lattice, where a topic's 3%-density induced
+              subgraph fragments and publishes stay in their segment:
+              every eth2 rate in BASELINE.md and BENCH_r*.json is that
+              form's (K=16, rolls), a throughput number of a network
+              that does not deliver, and is not comparable with this one
+              (K is the draw's largest degree, near 65 at 100k)
     sybil   — 20% sybil attackers (control-plane-only peers that never
               forward data), peer gater + deficit scoring enabled
               (BASELINE.json config #4; default BENCH_N 50k)
@@ -202,15 +208,16 @@ def bench_cell(n_peers: int, msg_slots: int, seed: int = 0, config: str = "defau
             "the run on the devices it is meant for"
         )
 
-    # bounded-degree topology (K stays small and static for the compiler)
-    topo = graph.ring_lattice(n_peers, d=8)  # degree 16, K=16
     if config == "eth2":
         n_topics = 64  # attestation subnet count
         subs = graph.subscribe_random(n_peers, n_topics=n_topics,
                                       topics_per_peer=2, seed=seed)
+        topo = graph.subnet_connect(subs, d_any=10, d_subnet=5, seed=seed)
     else:
         n_topics = 1
         subs = graph.subscribe_all(n_peers, 1)
+        # bounded-degree topology (K stays small and static for the compiler)
+        topo = graph.ring_lattice(n_peers, d=8)  # degree 16, K=16
     layout = bench_edge_layout(edge_layout)
     net = Net.build(topo, subs, edge_layout=layout, fused=fused)
 
@@ -456,7 +463,9 @@ def workload_fingerprint(
         "config": config,
         "n_peers": int(n_peers),
         "msg_slots": int(msg_slots),
-        "degree": 16,  # ring_lattice(d=8) — K = 2d
+        # ring_lattice(d=8) — K = 2d; eth2's subnet_connect has no fixed
+        # degree (K is the draw's largest, mean 40)
+        "degree": None if config == "eth2" else 16,
         "n_topics": n_topics,
         "topics_per_peer": 2 if config == "eth2" else 1,
         "adversary_fraction": 0.2 if config == "sybil" else 0.0,
