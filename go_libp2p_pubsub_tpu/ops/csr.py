@@ -348,7 +348,8 @@ def unpack_edges(x_e: jax.Array, e_of_nk: jax.Array,
 def edge_permute_flat(x_e: jax.Array, eperm: jax.Array) -> jax.Array:
     """The edge involution in flat space: out[e] = x_e[eperm[e]] —
     E-sized cross-peer movement (the dense form moves N*K)."""
-    _edges._tally("edge", x_e, rows=eperm.shape[0])
+    _edges._tally("edge", x_e, rows=eperm.shape[0],
+                  table_rows=x_e.shape[0])
     return x_e[eperm]
 
 

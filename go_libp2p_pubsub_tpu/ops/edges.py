@@ -12,10 +12,13 @@ no edge is a row like any other (it points at itself). Where the graph's
 degrees are uneven the gather is TIERED (`plan_tiers`, planned once per
 static net by `Net.build` from the column histogram): the head columns
 [0, K0) are gathered whole, of the tail columns [K0, K) only the slots
-that hold an edge move, as one short gather and one scatter onto the
-columns themselves, so every slot comes out bit for bit as `flat[perm]`
+that hold an edge move, as a short list scattered onto the columns
+themselves; where the full table is too large to be read cheaply the
+head's gather reads a COMPACT one (the head columns with the tail's
+present rows appended). Every slot comes out bit for bit as `flat[perm]`
 gives it. K0 = K is the one full gather. `_tally` counts the rows every
-gather set addresses, for the window's `edge_rows_per_dispatch`.
+gather set addresses and the rows of the table it reads, for the window's
+`edge_rows_per_dispatch` and `edge_table_rows`.
 
 Topic-slot payloads ([N,S,K] per-slot bools) are moved across edges by
 packing the S axis into *topic-id bit positions* of uint32 words (T bits
@@ -64,14 +67,19 @@ class TallyCacheHit(RuntimeError):
     error instead of a zero."""
 
 
-def _tally(kind: str, moved=None, rows: int = 0) -> None:
+def _tally(kind: str, moved=None, rows: int = 0,
+           table_rows: int = 0) -> None:
     """One cross-peer gather SET: ``moved`` is the tensor it moves,
     ``rows`` the rows it addresses by index (a gather's output rows plus
-    a scatter's rows; 0 for rolls, which address none)."""
+    a scatter's rows; 0 for rolls, which address none), ``table_rows``
+    the rows of the table its largest gather reads (an entry of its own,
+    ``("table", n)``, and none for rolls)."""
     if _TALLY is not None:
         _TALLY.append(kind)
     if _ROWS_TALLY is not None:
         _ROWS_TALLY.append((kind, int(rows)))
+        if table_rows:
+            _ROWS_TALLY.append(("table", int(table_rows)))
     if _BYTES_TALLY is not None:
         nbytes = None
         if moved is not None and hasattr(moved, "size"):
@@ -156,6 +164,14 @@ def edge_rows_per_dispatch(tally: list) -> float | None:
             first.setdefault(key, rows)
     rows = [first[key] for key, _ in calls if key in first]
     return sum(rows) / len(rows) if rows else None
+
+
+def edge_table_rows(tally: list) -> int | None:
+    """The largest table an edge gather of a ``tally_index_rows`` list
+    reads, in rows: N*K through the full ``edge_perm``, N*K0 + T where the
+    tiered gather's compact table engaged. ``None`` where no gather read
+    one (rolls, or every trace was a replay)."""
+    return max((val for kind, val in tally if kind == "table"), default=None)
 
 
 def tally_step(step, state, args=(), kwargs=None, *, net=None,
@@ -259,7 +275,7 @@ def involution_wf(nbr: jax.Array, rev: jax.Array, nbr_ok: jax.Array,
 def edge_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
     """x[N, K, ...] -> x[nbr[j,k], rev[j,k], ...] as a flat row gather."""
     n, k = perm.shape
-    _tally("edge", x, rows=n * k)
+    _tally("edge", x, rows=n * k, table_rows=n * k)
     flat = x.reshape((n * k,) + x.shape[2:])
     return flat[perm.reshape(-1)].reshape(x.shape)
 
@@ -270,51 +286,106 @@ def edge_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
 # (scripts/gather_law.py; PERF.md §6, PR 30). Slots are left-packed, so on
 # a graph of uneven degree the high columns hold next to no edge, yet every
 # slot of them is a row of the gather. So columns [0, K0) are gathered
-# whole (the head: N*K0 rows out of the same full table), and of columns
-# [K0, K) only the slots that hold an edge move, as one short list (the
-# tail: one small gather, one scatter onto the columns themselves).
+# whole (the head: N*K0 rows), and of columns [K0, K) only the T slots that
+# hold an edge move (the tail: one short gather, one scatter onto the
+# columns themselves).
+#
+# Which table the head's gather reads is the plan's too (PR 32). Out of the
+# full N*K-row table a head row of 5 words costs half again what it costs
+# out of a table of its own once that table is large, so there the gather
+# reads a COMPACT table: the head columns as they are with the tail's T
+# present rows appended (one short gather), N*K0 + T rows, and its index
+# plane, which lives in that space, ends in the T tail slots' sources: one
+# gather, no patch. A smaller full table costs no more than a compact one,
+# which then only adds its own steps; and a compact table that is itself
+# large is read whole at the price of the law's cliff (``TABLE_CLIFF_ROWS``).
 
 #: The tiered gather's cost on a TPU v5e, fitted to the whole tiered gather
-#: of the 100k-peer ``random_connect`` graph at 5 words a row and K0 = 20,
-#: 24, 28 (36.0 / 28.5 / 31.1 ms; my chip run, PR 30): ns per head row and
-#: ns per tail row (its gather and its scatter: ten head rows). The fixed
-#: cost (a slice, a short gather, a scatter and a join more than the one
-#: full gather) is an estimate: the host's clock cannot resolve it.
+#: of the 100k-peer ``random_connect`` graph at 5 words a row: ns per head
+#: row out of the full table and ns per tail row (its gather and its
+#: scatter: ten head rows), from K0 = 20, 24, 28 (36.0 / 28.5 / 31.1 ms; my
+#: chip run, PR 30; PR 32's points at K0 = 20, 24, 26, 28, 35.8 / 28.6 /
+#: 29.2 / 30.9 ms, fit 11.0 and 110.6); ns per head row out of the compact
+#: table, from the same four K0 (30.7 / 21.8 / 21.9 / 22.2 ms: 8.1 with
+#: 117.0 a tail row; my chip run, PR 32). The fixed cost (a slice, a short
+#: gather, a scatter and a join more than the one full gather) is an
+#: estimate: the host's clock cannot resolve it.
 HEAD_ROW_NS = 11.3
+COMPACT_HEAD_ROW_NS = 8.1
 TAIL_ROW_NS = 113.0
 TIER_FIXED_NS = 15_000.0
+
+#: The largest table a gather was measured to read at the law's low price
+#: (same script; my chip runs). Of 5 words a row: full tables of 0.36, 0.88,
+#: 1.75 and 2.7 M rows give their K0 = 24 head rows up as cheaply as a
+#: compact one does, whose extra steps only add 0.2-0.9 ms (PR 32, `compact`
+#: against `tierB` at 10k, 25k, 50k, 75k peers), and 3.5 M rows out of 3.5 M
+#: cost 5.3 ns each (PR 30, graph seed 2); the full table of 4.1 M rows
+#: charges a head row 7.7 ns against 5.1 (PR 30), the whole gather 28.6 ms
+#: against the compact form's 21.8 (PR 32); and a compact table of 4.62 M
+#: rows read whole (``eth2-100k``'s graph, 6 words) costs 19 ns a row, 88.4
+#: ms against 52.0 out of the full 6.5 M-row table (PR 32). So the compact
+#: table pays where it brings the table from beyond this size to within it.
+TABLE_CLIFF_ROWS = 3_500_000
 
 
 @struct.dataclass
 class Tiers:
     """The plan of a tiered edge gather (``plan_tiers``): index planes of
     one static graph, baked into the program like ``edge_perm``. K0 is
-    ``head.shape[1]``."""
+    ``head.shape[1]``, T ``tail_dst.size``. ``head`` and ``tail_src``
+    address the table the big gather reads. ``compact``: the table
+    ``[N*K0 + T]`` of the head columns with the tail's present rows
+    appended, where slot (n, k) of a head column is row ``n*K0 + k`` and
+    the t-th present tail slot (row-major) is row ``N*K0 + t``. Else the
+    full slot table ``[N*K]``, row ``n*K + k``, as ``edge_perm`` has it."""
 
-    head: jax.Array       # [N, K0] i32 = edge_perm[:, :K0]
-    tail_src: jax.Array   # [T] i32 into the slot space n*K + k
+    head: jax.Array       # [N, K0] i32: where each head slot's partner
+                          # sits in the table; an absent slot points at
+                          # itself
+    tail_src: jax.Array   # [T] i32: the same for the present tail slots
     tail_dst: jax.Array   # [T] i32 into the tail's own n*(K-K0) + k-K0,
-                          # ascending and duplicate-free
+                          # ascending and duplicate-free: where the tail's
+                          # rows go, and the rows a compact table appends
+    compact: bool = struct.field(pytree_node=False, default=False)
 
     @property
     def rows(self) -> int:
-        """Rows one gather addresses by index: the head's gather, the
-        tail's gather and the tail's scatter."""
-        return self.head.size + 2 * self.tail_dst.size
+        """Rows one gather addresses by index: the head's and the tail's
+        gathers and the tail's scatter, and the T rows a compact table
+        appends."""
+        return (self.head.size
+                + (3 if self.compact else 2) * self.tail_dst.size)
+
+    def table_rows(self, k: int) -> int:
+        """Rows of the table the big gather of a ``[N, k]`` plane reads."""
+        return (self.head.size + self.tail_dst.size if self.compact
+                else self.head.shape[0] * k)
+
+
+def compact_pays(full_rows, compact_rows):
+    """Whether the big gather should read the compact table of
+    ``compact_rows`` rows and not the full one of ``full_rows``, by the
+    law above (elementwise over arrays)."""
+    return (compact_rows <= TABLE_CLIFF_ROWS) & (TABLE_CLIFF_ROWS < full_rows)
 
 
 def tier_cost_ns(col_fill, n: int) -> np.ndarray:
     """``[K+1]`` modelled ns of one gather for every K0 over the column
-    histogram ``nbr_ok.sum(0)``: ``N*K0`` head rows and the ``tail(K0)``
-    present slots right of them. K0 = K is the one full gather: no tail,
-    no fixed cost, every row at the head's price (the law prices a row of
-    the full gather at up to three times that: the model errs against
+    histogram ``nbr_ok.sum(0)``: ``N*K0`` head rows, at the price of the
+    table ``compact_pays`` gives that K0, and the ``tail(K0)`` present
+    slots right of them. K0 = K is the one full gather: no tail, no fixed
+    cost, every row at the full table's head price (the law prices a row
+    of the full gather at up to three times that: the model errs against
     tiering)."""
     col_fill = np.asarray(col_fill, np.int64)
     k = col_fill.size
     tail = np.append(np.cumsum(col_fill[::-1])[::-1], 0)
     k0 = np.arange(k + 1)
-    return (n * k0 * HEAD_ROW_NS + tail * TAIL_ROW_NS
+    # (at K0 = K the "compact" table is the full one: never the cheaper)
+    head_ns = np.where(compact_pays(n * k, n * k0 + tail),
+                       COMPACT_HEAD_ROW_NS, HEAD_ROW_NS)
+    return (n * k0 * head_ns + tail * TAIL_ROW_NS
             + np.where(k0 < k, TIER_FIXED_NS, 0.0))
 
 
@@ -326,36 +397,61 @@ def pick_k0(col_fill, n: int) -> int:
     return k if cost[k] <= cost[best] else best
 
 
-def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray,
-               k0: int | None = None) -> Tiers | None:
+def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
+               compact: bool | None = None) -> Tiers | None:
     """Plan the tiered gather of one static graph, on the host. ``None``
-    is K0 = K: the one full gather, today's program. ``k0`` is for the
-    tests; ``Net.build`` lets ``pick_k0`` derive it from the graph."""
+    is K0 = K: the one full gather, today's program. ``k0`` and
+    ``compact`` are for the tests; ``Net.build`` lets ``pick_k0`` and
+    ``compact_pays`` derive them from the graph."""
     n, k = perm.shape
     if k0 is None:
         k0 = pick_k0(nbr_ok.sum(axis=0), n)
     if k0 >= k:
         return None
     rows, cols = np.nonzero(nbr_ok[:, k0:])     # row-major: ascending
+    if compact is None:
+        compact = compact_pays(n * k, n * k0 + rows.size)
+    src = perm
+    if compact:
+        # every slot's row in the compact table. An absent tail slot has
+        # none (-1) and nobody asks: a present slot's partner is present,
+        # an absent head slot points at itself.
+        addr = np.full((n, k), -1, np.int32)
+        addr[:, :k0] = np.arange(n * k0, dtype=np.int32).reshape(n, k0)
+        addr[rows, cols + k0] = n * k0 + np.arange(rows.size, dtype=np.int32)
+        src = addr.reshape(-1)[perm]
     # cast on the host: a device-side convert is one more program to compile
     i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
-    return Tiers(head=i32(perm[:, :k0]), tail_src=i32(perm[rows, cols + k0]),
-                 tail_dst=i32(rows * (k - k0) + cols))
+    return Tiers(head=i32(src[:, :k0]), tail_src=i32(src[rows, cols + k0]),
+                 tail_dst=i32(rows * (k - k0) + cols), compact=bool(compact))
 
 
 def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
     """``edge_permute(x, edge_perm)`` bit for bit on every slot, absent
     ones included, addressing ``tiers.rows`` rows instead of N*K: an
     absent slot of the tail keeps its own entry, as its self-pointing row
-    of ``edge_perm`` gives it."""
+    of ``edge_perm`` gives it. One parameter, ``tiers.compact``: which
+    table the head's gather reads."""
     n, k = x.shape[:2]
     k0 = tiers.head.shape[1]
     trail = x.shape[2:]
-    _tally("edge", x, rows=tiers.rows)
-    flat = x.reshape((n * k,) + trail)
-    head = flat[tiers.head.reshape(-1)].reshape((n, k0) + trail)
-    tail = x[:, k0:].reshape((n * (k - k0),) + trail).at[tiers.tail_dst].set(
-        flat[tiers.tail_src], unique_indices=True, indices_are_sorted=True)
+    _tally("edge", x, rows=tiers.rows, table_rows=tiers.table_rows(k))
+    if tiers.compact:
+        tail = x[:, k0:].reshape((n * (k - k0),) + trail)
+        table = jnp.concatenate(
+            [x[:, :k0].reshape((n * k0,) + trail), tail[tiers.tail_dst]])
+        # ONE gather: the head block, then the T rows the tail slots take
+        moved = table[
+            jnp.concatenate([tiers.head.reshape(-1), tiers.tail_src])]
+        head = moved[:n * k0].reshape((n, k0) + trail)
+        tail = tail.at[tiers.tail_dst].set(
+            moved[n * k0:], unique_indices=True, indices_are_sorted=True)
+    else:
+        flat = x.reshape((n * k,) + trail)
+        head = flat[tiers.head.reshape(-1)].reshape((n, k0) + trail)
+        tail = x[:, k0:].reshape((n * (k - k0),) + trail).at[
+            tiers.tail_dst].set(flat[tiers.tail_src], unique_indices=True,
+                                indices_are_sorted=True)
     return jnp.concatenate(
         [head, tail.reshape((n, k - k0) + trail)], axis=1)
 

@@ -203,6 +203,11 @@ class TracedWindow:
     #: gather output rows plus scatter rows, 0 for rolls); ``None`` where
     #: the trace replayed a step traced before it
     edge_rows_per_dispatch: float | None = None
+    #: rows of the largest table an edge gather of the window reads
+    #: (``ops/edges.edge_table_rows``): N*K through the full ``edge_perm``,
+    #: N*K0 + T where the tiered gather's compact table engaged; ``None``
+    #: for rolls and replays
+    edge_table_rows: int | None = None
     _stages: dict | None = None
     _parts: dict | None = None
 
@@ -239,9 +244,11 @@ _WINDOWS: collections.deque = collections.deque(maxlen=KEPT_WINDOWS)
 
 
 def note_window(jitted, args: tuple, kwargs: dict,
-                edge_rows: float | None = None) -> None:
+                edge_rows: float | None = None,
+                table_rows: int | None = None) -> None:
     """Called from inside a window's traced Python body: note its
-    signature once, with the edge rows its trace counted per step call.
+    signature once, with the edge rows its trace counted per step call
+    and the rows of the table its edge gathers read.
     Outside a trace (``jax.disable_jit``) nothing reaches the device as a
     module and nothing is noted."""
     import jax
@@ -264,7 +271,7 @@ def note_window(jitted, args: tuple, kwargs: dict,
                 and w.sharded == sharded):
             return          # a retrace of what is noted (``stages`` lowers)
     _WINDOWS.append(TracedWindow(jitted, "jit_" + jitted.__name__,
-                                 signature, sharded, edge_rows))
+                                 signature, sharded, edge_rows, table_rows))
 
 
 def traced_windows() -> list:

@@ -37,10 +37,14 @@ class Net:
     on a banded net (``band_off``), the flat [E] space on a CSR build, and
     on every other dense net a row gather through ``edge_perm``: tiered
     (``tiers``: head columns whole, of the tail columns only the present
-    slots) where the degree histogram makes that the cheaper program, the
-    one full gather where it does not (full or near-regular columns, toy
-    nets) and on a ``dynamic`` net, whose ``edge_perm`` is traced. Every
-    form returns the same plane on every slot, absent ones included."""
+    slots; out of the full slot table, or, where that table lies beyond
+    the size the chip reads cheaply and the plan's own does not, as ONE
+    gather out of a compact table that holds the head columns and the
+    tail's present rows and no other) where the degree histogram makes
+    that the cheaper program, the one full gather where it does not (full
+    or near-regular columns, toy nets) and on a ``dynamic`` net, whose
+    ``edge_perm`` is traced. Every form returns the same plane on every
+    slot, absent ones included."""
 
     nbr: jax.Array         # [N, K] i32
     nbr_ok: jax.Array      # [N, K] bool
@@ -106,8 +110,9 @@ class Net:
     # the pre-fusion program bit for bit (the census gate's contract).
     fused: bool = struct.field(pytree_node=False, default=False)
     # tiered edge gather (ops/edges.plan_tiers): planned by ``build`` on a
-    # dense, unbanded, static net whose high columns are nearly empty;
-    # None is K0 = K, the one full gather through ``edge_perm``
+    # dense, unbanded, static net whose high columns are nearly empty, K0
+    # and the table its head reads both from the degree histogram; None is
+    # K0 = K, the one full gather through ``edge_perm``
     tiers: edges.Tiers | None = None
 
     @stages.scope("edge_gather")

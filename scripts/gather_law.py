@@ -2,11 +2,16 @@
 ``ops/edges.edge_permute`` costs on the chip, by rows, by table size, by
 row width and by K, on the benchmark's own random graphs; what a scatter
 of a short row list costs; and what a tiered gather made of them costs
-whole. PR 30's first chip call, and the source of the three constants
-``ops/edges.pick_k0`` prices a graph with.
+whole. PR 30's first chip call and PR 32's, and the source of the three
+constants ``ops/edges.pick_k0`` prices a graph with.
 
     python scripts/gather_law.py [--n 100000] [--d 10] [--reps 7]
+        [--graph random_connect|subnet_connect] [--k0 20 24 28]
+        [--widths 2 5 14] [--cases compact tierB ...]
         [--out chiprun_out/gather_law.json]
+
+``--graph subnet_connect`` is ``eth2-100k``'s own graph (its configuration
+file's draw; ``--d`` is not read); ``--cases`` runs only the cases named.
 
 Every program is its own ``jax.jit`` over u32 tables with the index
 planes as arguments (one case bakes them in, as the engine does, to show
@@ -25,7 +30,12 @@ Cases (``rows_out`` gathered from a ``rows_table``-row table, W words):
   list      the tail's (and the patches') sources from the full table
   scatter   the tail's rows scattered onto the tail columns
   tierA     head from its compact table + patches + tail, joined
-  tierB     head from the full table + tail, joined
+  tierB     head from the full table + tail, joined (PR 30's engine)
+  compact   ``ops/edges.edge_permute_tiered`` with ``compact=True``: ONE
+            gather of N*K0 + T rows out of the compact table (the head
+            columns plus the tail's T present rows appended), the last T
+            scattered onto the tail columns, joined (PR 32's engine where
+            ``ops/edges.compact_pays``; ``tierB`` is its other form)
   whole     ``edge_permute`` as the engine calls it ([N, K, W] in and out)
 """
 
@@ -44,13 +54,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def graph(n: int, d: int, seed: int):
+def graph(kind: str, n: int, d: int, seed: int):
     """``(perm[N,K] full-space flat involution, nbr_ok[N,K])`` of the
-    benchmark's ``random_connect`` graph."""
-    from benchmark.harness import graphs
+    benchmark's ``random_connect`` graph, or of ``eth2-100k``'s
+    ``subnet_connect`` with the configuration's own parameters."""
+    from benchmark.harness import graphs, subnets
     from go_libp2p_pubsub_tpu.ops import edges
 
-    g = graphs.build_graph({"kind": "random_connect", "d": d, "seed": seed}, n)
+    if kind == "subnet_connect":
+        with open(os.path.join(ROOT, "benchmark/configs/eth2-100k.json")) as f:
+            config = json.load(f)
+        config["graph"] = dict(config["graph"], seed=seed)
+        g, _ = subnets.build(config, n)
+    else:
+        g = graphs.build_graph({"kind": kind, "d": d, "seed": seed}, n)
     return (edges.build_edge_perm(g["nbr"], g["rev"], g["nbr_ok"]),
             g["nbr_ok"])
 
@@ -66,7 +83,10 @@ def pad_k(perm, nbr_ok, k_new):
 
 
 def tier_indices(perm, nbr_ok, k0):
-    """Every index plane either tiered variant needs, numpy."""
+    """Every index plane a tiered variant needs, numpy; ``compact`` is
+    the engine's own plan."""
+    from go_libp2p_pubsub_tpu.ops import edges
+
     n, k = perm.shape
     pn, pk = perm // k, perm % k
     head_ok = nbr_ok[:, :k0]
@@ -82,6 +102,7 @@ def tier_indices(perm, nbr_ok, k0):
         "patch_dst": (pr * k0 + pc).astype(np.int32),
         "tail_src": perm[tr, tc + k0].astype(np.int32),
         "tail_dst": (tr * (k - k0) + tc).astype(np.int32),
+        "compact": edges.plan_tiers(perm, nbr_ok, k0, compact=True),
     }
 
 
@@ -92,19 +113,28 @@ def main(argv=None) -> int:
     ap.add_argument("--reps", type=int, default=7)
     ap.add_argument("--k0", type=int, nargs="*", default=[20, 24, 28])
     ap.add_argument("--widths", type=int, nargs="*", default=[2, 5, 14])
+    ap.add_argument("--graph", default="random_connect",
+                    choices=["random_connect", "subnet_connect"])
+    ap.add_argument("--cases", nargs="*", default=None,
+                    help="run only these cases (default: all)")
     ap.add_argument("--out", default="chiprun_out/gather_law.json")
     args = ap.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
 
+    from go_libp2p_pubsub_tpu.ops import edges
+
     dev = jax.devices()[0]
-    where = {"platform": dev.platform, "device_kind": dev.device_kind}
+    where = {"platform": dev.platform, "device_kind": dev.device_kind,
+             "graph": args.graph}
     n = args.n
     lines = []
 
     def timed(case, w, rows_out, rows_table, fn, *operands, expect=None,
               **extra):
+        if args.cases is not None and case not in args.cases:
+            return
         operands = [jnp.asarray(a) for a in operands]
         jit = jax.jit(fn)
         t0 = time.perf_counter()
@@ -133,12 +163,15 @@ def main(argv=None) -> int:
 
     gather = lambda flat, idx: flat[idx.reshape(-1)]
 
-    perm, ok = graph(n, args.d, 1)
+    perm, ok = graph(args.graph, n, args.d, 1)
     k = perm.shape[1]
     fill = ok.sum(axis=0)
     print(json.dumps(dict(where, n=n, k=k, present=int(ok.sum()),
                           col_fill=fill.tolist())), flush=True)
-    perm2, ok2 = graph(n, args.d, 2)
+    # graph seed 2 only where a case reads it
+    runs = lambda *cases: args.cases is None or set(cases) & set(args.cases)
+    perm2, ok2 = (graph(args.graph, n, args.d, 2) if runs("seed2", "pad48")
+                  else (perm, ok))
     k2 = perm2.shape[1]
     k_pad = -(-max(k, k2) // 16) * 16
     tiers = {k0: tier_indices(perm, ok, k0) for k0 in args.k0 if k0 < k}
@@ -167,7 +200,7 @@ def main(argv=None) -> int:
     baked = jnp.asarray(perm.reshape(-1))
     timed("full_baked", w, n * k, n * k, lambda flat: flat[baked], full, k=k)
 
-    for w in args.widths[1:]:
+    for w in args.widths:
         full = table(n * k, w)
         x = full.reshape(n, k, w)
 
@@ -213,6 +246,10 @@ def main(argv=None) -> int:
                 return jnp.concatenate(
                     [head.reshape(n, k0, w), tail.reshape(n, kt, w)], axis=1)
 
+            def compact(x, head, tail_src, tail_dst):
+                return edges.edge_permute_tiered(
+                    x, edges.Tiers(head, tail_src, tail_dst, compact=True))
+
             rows_a = n * k0 + 2 * (n_t + n_p)
             rows_b = n * k0 + 2 * n_t
             timed("tierA", w, rows_a, n * k, tier_a, x, t["perm_head"],
@@ -221,6 +258,10 @@ def main(argv=None) -> int:
                   expect=want)
             timed("tierB", w, rows_b, n * k, tier_b, x, t["head_full"],
                   t["tail_src"], t["tail_dst"], k0=k0, tail=n_t, expect=want)
+            plan = t["compact"]
+            timed("compact", w, plan.rows, plan.table_rows(k), compact, x,
+                  plan.head, plan.tail_src, plan.tail_dst, k0=k0, tail=n_t,
+                  expect=want)
 
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:

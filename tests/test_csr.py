@@ -283,10 +283,12 @@ def test_gossipsub_phase_parity(r):
     assert_trees_equal(run("dense"), run("csr"), f"phase r={r}")
 
 
-def test_gossipsub_phase_tiered_gather_parity():
+@pytest.mark.parametrize("compact", [False, True])
+def test_gossipsub_phase_tiered_gather_parity(compact):
     """The phase engine over a tiered edge gather (K0 < K, forced through
-    the plan helper) leaves the same full state tree as the same net with
-    the one full gather (K0 = K), after 3 phases with lossy links."""
+    the plan helper, out of the full table or the compact one) leaves the
+    same full state tree as the same net with the one full gather
+    (K0 = K), after 3 phases with lossy links."""
     from go_libp2p_pubsub_tpu.ops import edges
 
     r = 4
@@ -311,8 +313,9 @@ def test_gossipsub_phase_tiered_gather_parity():
     full = run(None)
     assert int(full.core.tick) == 3 * r
     for k0 in (k // 2, 2):
-        tiers = edges.plan_tiers(perm, topo.nbr_ok, k0)
+        tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
         assert tiers.head.shape[1] == k0 < k and tiers.tail_dst.size
+        assert tiers.compact is compact
         assert_trees_equal(full, run(tiers), f"phase tiers K0={k0}")
 
 

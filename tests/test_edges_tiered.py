@@ -1,8 +1,10 @@
 """The tiered edge gather (``ops/edges.plan_tiers`` /
 ``edge_permute_tiered``, planned by ``Net.build``): bit for bit the one
 full gather ``edge_permute(x, edge_perm)`` on every slot, absent ones
-included, on UNMASKED planes; planned only where the code can see that it
-pays; counted by the rows it addresses."""
+included, on UNMASKED planes, through one gather out of a compact table
+(the head columns plus the tail's present rows); planned only where the
+code can see that it pays; counted by the rows it addresses and the rows
+of the table it reads."""
 
 from __future__ import annotations
 
@@ -60,18 +62,85 @@ def test_tiered_gather_equals_the_full_gather_on_every_slot(name):
     if name == "isolated-peer":
         assert not topo.nbr_ok[0].any()
     for k0 in every_k0(k):
-        tiers = edges.plan_tiers(perm, topo.nbr_ok, k0)
-        assert tiers.head.shape == (n, k0)
-        tail = int(topo.nbr_ok[:, k0:].sum())
-        assert tiers.rows == n * k0 + 2 * tail
+        for compact in (True, False):
+            tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
+            assert tiers.head.shape == (n, k0) and tiers.compact is compact
+            for what, x in planes((n, k), seed=k0).items():
+                want = edges.edge_permute(x, jnp.asarray(perm))
+                got = jax.jit(edges.edge_permute_tiered)(x, tiers)
+                assert got.dtype == want.dtype and got.shape == want.shape
+                np.testing.assert_array_equal(
+                    np.asarray(got), np.asarray(want),
+                    err_msg=f"{what} K0={k0} compact={compact}")
+
+
+@pytest.mark.parametrize("name", sorted(TOPOS))
+def test_plan_lives_in_the_compact_table(name):
+    """Every index of the plan lies inside ``[0, N*K0 + T)``; the rows the
+    table appends are exactly the present tail slots, each once; and each
+    index is the compact address of the slot's partner in ``edge_perm``."""
+    topo = TOPOS[name]()
+    perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
+    n, k = perm.shape
+    for k0 in every_k0(k):
+        tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=True)
         dst = np.asarray(tiers.tail_dst)
+        t = dst.size
+        assert tiers.table_rows(k) == n * k0 + t
         assert (np.diff(dst) > 0).all()         # sorted, unique
-        for what, x in planes((n, k), seed=k0).items():
-            want = edges.edge_permute(x, jnp.asarray(perm))
-            got = jax.jit(edges.edge_permute_tiered)(x, tiers)
-            assert got.dtype == want.dtype and got.shape == want.shape
-            np.testing.assert_array_equal(
-                np.asarray(got), np.asarray(want), err_msg=f"{what} K0={k0}")
+        present = np.zeros(n * (k - k0), bool)
+        present[dst] = True
+        np.testing.assert_array_equal(
+            present.reshape(n, k - k0), topo.nbr_ok[:, k0:])
+        src = np.concatenate(
+            [np.asarray(tiers.head).reshape(-1), np.asarray(tiers.tail_src)])
+        assert src.size == n * k0 + t
+        assert src.min(initial=0) >= 0 and src.max(initial=0) < n * k0 + t
+        # the compact table's rows, named by the full-space slot they hold
+        slot_of_row = np.concatenate([
+            (np.arange(n)[:, None] * k + np.arange(k0)[None, :]).reshape(-1),
+            (dst // (k - k0)) * k + k0 + dst % (k - k0)])
+        asked = np.concatenate([perm[:, :k0].reshape(-1),
+                                perm.reshape(-1)[slot_of_row[n * k0:]]])
+        np.testing.assert_array_equal(slot_of_row[src], asked)
+
+
+@pytest.mark.parametrize("compact", [True, False])
+@pytest.mark.parametrize("name", sorted(TOPOS))
+def test_rows_are_what_the_program_addresses(name, compact):
+    """``Tiers.rows`` and ``table_rows`` against the traced program: the
+    gathers' output rows plus the scatter's update rows, and the operand
+    of the big gather. The full-table form keeps its indices in
+    ``edge_perm``'s own space."""
+    topo = TOPOS[name]()
+    perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
+    n, k = perm.shape
+    x = planes((n, k))["u32[N,K,5]"]
+    for k0 in every_k0(k):
+        tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
+        tail = int(topo.nbr_ok[:, k0:].sum())
+        assert tiers.rows == n * k0 + (3 if compact else 2) * tail
+        jaxpr = jax.make_jaxpr(edges.edge_permute_tiered)(x, tiers)
+        gathers = [e for e in jaxpr.eqns if e.primitive.name == "gather"]
+        scatters = [e for e in jaxpr.eqns if e.primitive.name == "scatter"]
+        # (jax drops the full form's head gather of no rows at K0 = 0)
+        assert len(gathers) == 2 - (k0 == 0 and not compact)
+        assert len(scatters) == 1
+        addressed = (sum(e.outvars[0].aval.shape[0] for e in gathers)
+                     + sum(e.invars[2].aval.shape[0] for e in scatters))
+        assert addressed == tiers.rows
+        if compact:     # the table's appended rows first, then the big one
+            short, big = gathers
+            assert big.outvars[0].aval.shape[0] == n * k0 + tail
+            want = n * k0 + tail
+        else:           # the head's, then the tail's, out of one table
+            big, short = gathers[0], gathers[-1]
+            assert k0 == 0 or big.outvars[0].aval.shape[0] == n * k0
+            assert short.invars[0].aval.shape[0] == n * k
+            np.testing.assert_array_equal(tiers.head, perm[:, :k0])
+            want = n * k
+        assert short.outvars[0].aval.shape[0] == tail
+        assert big.invars[0].aval.shape[0] == tiers.table_rows(k) == want
 
 
 def test_built_net_gathers_through_its_plan():
@@ -150,9 +219,17 @@ def test_tally_records_the_plan_rows():
         jax.eval_shape(net.peer_gather, x[:, 0])
     k0 = net.tiers.head.shape[1]
     tail = int(topo.nbr_ok[:, k0:].sum())
-    assert rows == [("edge", n * k0 + 2 * tail), ("edge", n * k),
-                    ("peer", n * k)]
+    assert not net.tiers.compact        # a table of 3000 * K rows is small
+    compact = net.replace(tiers=edges.plan_tiers(
+        np.asarray(net.edge_perm), topo.nbr_ok, compact=True))
+    with edges.tally_index_rows(rows):
+        jax.eval_shape(compact.edge_gather, x)
+    assert rows == [("edge", n * k0 + 2 * tail), ("table", n * k),
+                    ("edge", n * k), ("table", n * k), ("peer", n * k),
+                    ("edge", n * k0 + 3 * tail), ("table", n * k0 + tail)]
     assert net.tiers.rows == n * k0 + 2 * tail < n * k
+    assert edges.edge_table_rows(rows) == n * k
+    assert edges.edge_table_rows(rows[-2:]) == n * k0 + tail < n * k
     # a tiered gather is still ONE gather set (hlo-audit, cost model)
     assert sets == ["edge", "edge", "peer"]
     banded = Net.build(graph.ring_lattice(64, d=4), graph.subscribe_all(64, 1))
@@ -161,13 +238,24 @@ def test_tally_records_the_plan_rows():
         jax.eval_shape(banded.edge_gather, jnp.zeros((64, 8, 2), jnp.uint32))
         jax.eval_shape(banded.peer_gather, jnp.zeros((64,), jnp.uint32))
     assert rows == [("edge", 0), ("peer", 0)]   # rolls address no row
+    assert edges.edge_table_rows(rows) is None  # and read no table
+
+
+def compact_pays(col_fill, n, k0):
+    """The table rule, spelt out: the full table lies beyond the cliff,
+    the compact one this side of it."""
+    return (n * k0 + int(np.sum(col_fill[k0:]))
+            <= edges.TABLE_CLIFF_ROWS < n * len(col_fill))
 
 
 def cost(col_fill, n, k0):
-    """The cost rule, spelt out: head rows, tail rows, the fixed cost."""
+    """The cost rule, spelt out: head rows at their table's price, tail
+    rows, the fixed cost."""
     tail = int(np.sum(col_fill[k0:]))
     fixed = edges.TIER_FIXED_NS if k0 < len(col_fill) else 0.0
-    return n * k0 * edges.HEAD_ROW_NS + tail * edges.TAIL_ROW_NS + fixed
+    head = (edges.COMPACT_HEAD_ROW_NS if compact_pays(col_fill, n, k0)
+            else edges.HEAD_ROW_NS)
+    return n * k0 * head + tail * edges.TAIL_ROW_NS + fixed
 
 
 @pytest.mark.parametrize("col_fill,n,why", [
@@ -179,6 +267,13 @@ def cost(col_fill, n, k0):
     ([32, 1, 1, 1, 1, 1, 1, 1], 33, "a toy star: the fixed cost decides"),
     ([20_000] + [1] * 63, 20_000, "a star's hub"),
     ([0, 0, 0], 10, "no edge at all"),
+    ([100_000] * 10 + [99_000, 90_000, 60_000, 30_000, 9_000, 2_000, 300,
+                       40, 5, 1] + [0] * 21, 100_000,
+     "random-100k's K = 41: a large table, the compact form"),
+    ([100_000] * 36 + [90_000, 50_000, 9_000, 300, 5] + [0] * 24, 100_000,
+     "eth2-100k's shape: the compact table would lie beyond the cliff"),
+    ([10_000] * 10 + [9_900, 9_000, 6_000, 3_000, 900, 200, 30, 4] + [0] * 18,
+     10_000, "random-10k's shape: a small table, the full form"),
 ])
 def test_pick_k0_is_the_least_cost_on_a_hand_made_histogram(col_fill, n, why):
     k = len(col_fill)
@@ -193,3 +288,39 @@ def test_pick_k0_is_the_least_cost_on_a_hand_made_histogram(col_fill, n, why):
     assert (plan is None) == (k0 == k)
     if plan is not None:
         assert plan.head.shape[1] == k0
+        assert plan.compact == compact_pays(col_fill, n, k0)
+
+
+@pytest.mark.parametrize("form", ["compact", "tiered", "full", "rolls"])
+def test_a_traced_window_notes_the_table_its_gathers_read(form):
+    """``driver``'s windows keep ``edge_table_rows`` beside the rows
+    addressed: how a reader of a traced run sees that the compact table
+    engaged."""
+    from go_libp2p_pubsub_tpu import driver
+    from go_libp2p_pubsub_tpu.perf import stages
+
+    if form == "rolls":
+        topo = graph.ring_lattice(64, d=4)
+        net = Net.build(topo, graph.subscribe_all(64, 1))
+        want = None
+    else:
+        topo = graph.random_connect(3000, d=4, seed=5)
+        net = Net.build(topo, graph.subscribe_all(3000, 1))
+        n, k = topo.nbr.shape
+        tiers = edges.plan_tiers(np.asarray(net.edge_perm), topo.nbr_ok,
+                                 compact=True)
+        assert tiers.table_rows(k) == int(
+            n * tiers.head.shape[1]
+            + topo.nbr_ok[:, tiers.head.shape[1]:].sum()) < n * k
+        want = tiers.table_rows(k) if form == "compact" else n * k
+        net = net.replace(tiers={"compact": tiers, "tiered": net.tiers,
+                                 "full": None}[form])
+
+    def step(st, x):
+        return net.edge_gather(st) ^ x
+
+    win = driver.make_window(step, donate=False)
+    x = jnp.zeros(topo.nbr.shape + (2,), jnp.uint32)
+    jax.eval_shape(win, x, (jnp.stack([x, x]),))
+    (entry,) = [w for w in stages.traced_windows() if w.jitted is win]
+    assert entry.edge_table_rows == want
