@@ -130,12 +130,10 @@ LIFTED_BASE = "gossipsub"
 #: selection and capacity-bounded segmented scan under the full guard
 #: set (fusion is a pure recomposition: schema must stay the csr
 #: variant of the committed ``gossipsub`` rows). ``lifted_fused`` is
-#: the lifted row rebuilt with ``fused=True`` AND the PUBSUB_FUSED
-#: dense Pallas data plane armed: the former ``float(threshold)``
-#: SHAPE seam excluded lifted builds from that kernel — now the
-#: thresholds ride the traced ``thr`` param, so the alternating-plane
-#: one-compile sentinel runs THROUGH the fused kernel (the A/B
-#: acceptance invariant of the seam close).
+#: the lifted row rebuilt with ``fused=True``: the alternating-plane
+#: one-compile sentinel runs through the sort-form selection
+#: composites (a threshold that re-entered the program as a Python
+#: scalar would recompile here).
 CSR_FUSED_ENGINE = "csr_fused"
 CSR_FUSED_BASE = "gossipsub"
 LIFTED_FUSED_ENGINE = "lifted_fused"
@@ -398,26 +396,14 @@ def build_csr_fused_harness() -> EngineHarness:
 
 def build_lifted_fused_harness() -> EngineHarness:
     """The lifted+fused path (round 21): ``lift_scores=True`` AND
-    ``fused=True`` AND the PUBSUB_FUSED dense Pallas delivery kernel
-    armed (env read at factory time — set around the build, restored
-    after). Before round 21 the kernel's ``float(threshold)`` calls
-    forced SHAPE on the lifted plane, so this build fell back to the
-    XLA path; the thresholds now ride the traced ``thr`` param and the
-    alternating-plane A/B run exercises the kernel itself."""
+    ``fused=True`` (the sort-form selection composites) in one step,
+    under the alternating-plane one-compile A/B run."""
     from ..perf.sweep import build_bench
 
-    old = os.environ.get("PUBSUB_FUSED")
-    os.environ["PUBSUB_FUSED"] = "1"
-    try:
-        st, step, _, _ = build_bench(
-            GUARD_N, GUARD_M, heartbeat_every=1, rounds_per_phase=1,
-            lift_scores=True, fused=True,
-        )
-    finally:
-        if old is None:
-            os.environ.pop("PUBSUB_FUSED", None)
-        else:
-            os.environ["PUBSUB_FUSED"] = old
+    st, step, _, _ = build_bench(
+        GUARD_N, GUARD_M, heartbeat_every=1, rounds_per_phase=1,
+        lift_scores=True, fused=True,
+    )
     plane_a, plane_b = lifted_plane_pair()
 
     def make_args(i):
@@ -1091,16 +1077,15 @@ def run_csr_fused_engine(base_rows: list | None) -> list:
 def run_lifted_fused_engine(base_rows: list | None) -> list:
     """All guards for the lifted+fused row (round 21): schema equal to
     the committed ``gossipsub`` rows (neither the score plane nor the
-    fused kernel may leak into state), donation, and the alternating
-    A/B plane run under transfer_guard with the one-compile sentinel —
-    run THROUGH the PUBSUB_FUSED Pallas delivery kernel, pinning the
-    ``float(threshold)`` seam closed (a recompile here means a
-    threshold re-entered the program as a Python scalar)."""
+    fused composites may leak into state), donation, and the
+    alternating A/B plane run under transfer_guard with the one-compile
+    sentinel (a recompile here means a threshold re-entered the program
+    as a Python scalar)."""
     h = build_lifted_fused_harness()
     out_tree = strict_trace(h)
     rows = check_schema_equal(
         h, out_tree, base_rows, LIFTED_FUSED_BASE,
-        "the lifted plane or the fused kernel leaked into the state tree",
+        "the lifted plane or the fused composites leaked into the state tree",
     )
     check_donation(h)
     run_rounds_guarded(h)

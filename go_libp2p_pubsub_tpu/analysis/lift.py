@@ -20,9 +20,6 @@ call edges — and classifies each use site:
           selects, traced-index gathers. Liftable: replacing the baked
           constant with a traced scalar/row yields the same ops on the
           same dtypes, bit-exact at matched values.
-  GATED   lexically inside a statically-disabled path of the lifted
-          build (the ``use_fused`` Pallas branch) — recorded, excluded
-          from the lifted-path verdict.
 
 Per-field verdicts aggregate the sites: any un-excused SHAPE site ⇒
 ``SHAPE``; SHAPE sites all covered by the declared :data:`ELISION_OK`
@@ -130,12 +127,6 @@ TP_KEY_FIELD = {
     "w4": "TopicScoreParams.invalid_message_deliveries_weight",
     "decay4": "TopicScoreParams.invalid_message_deliveries_decay",
 }
-
-#: if-test names recognized as STATIC GATES of paths the lifted build
-#: disables (the fused Pallas branch: ``fused_eligible`` includes
-#: ``not lift_scores``, so reads under ``if use_fused:`` never trace
-#: in a lifted program)
-STATIC_GATES = frozenset({"use_fused"})
 
 #: calls whose argument values are baked at trace time (all-args shape
 #: sinks unless a position tuple narrows it)
@@ -256,7 +247,7 @@ class Site:
     rel: str
     line: int
     qual: str
-    kind: str      # "value" | "shape" | "branch" | "gated"
+    kind: str      # "value" | "shape" | "branch"
     context: str   # why / what construct
 
     def as_row(self) -> dict:
@@ -442,19 +433,11 @@ def _call_root(node) -> str:
 def _classify(node, parents: dict, rel: str) -> tuple:
     """(kind, context) for a tracked read at ``node`` by walking the
     ancestor chain up to its enclosing statement."""
-    # static-gate check first: a read anywhere under `if use_fused:`
-    # belongs to a path the lifted build statically disables
     anc = parents.get(id(node))
     chain = []
     while anc is not None:
         chain.append(anc)
         anc = parents.get(id(anc))
-    for a in chain:
-        if isinstance(a, ast.If):
-            test_names = {n.id for n in ast.walk(a.test)
-                          if isinstance(n, ast.Name)}
-            if test_names & STATIC_GATES:
-                return "gated", f"under static gate {sorted(test_names & STATIC_GATES)[0]!r}"
     prev = node
     for a in chain:
         # Python-branch tests: structure decisions
@@ -696,9 +679,7 @@ def field_verdicts(sites: list) -> dict:
     is in :data:`DECLARED_SHAPE`). ``VALUE_GUARDED``: every
     shape/branch site is covered by the :data:`ELISION_OK` table (a
     value-neutral build-time elision the lifted engines resolve
-    conservatively). ``VALUE``: traced arithmetic only. GATED sites
-    never count against liftability (they are statically absent from
-    lifted builds) but stay in the evidence."""
+    conservatively). ``VALUE``: traced arithmetic only."""
     by_field: dict = {}
     for s in sites:
         by_field.setdefault(s.field, []).append(s)

@@ -223,12 +223,7 @@ SANCTIONED_DROPS = {
 #: the simlint ``narrow-dtype`` rule replays this cross-check against
 #: the committed artifact): per device-scope file, the ORDERED narrow
 #: target dtypes of its ``.astype`` callsites, each justified here.
-NARROW_ASTYPE_MANIFEST = {
-    # first-arrival edge slot codes: k_dim <= 128 is asserted at the
-    # int8 plane's source (ops/bitset.py first_set_idx) and the pallas
-    # kernel is pinned bit-equal to that XLA twin
-    "ops/pallas_delivery.py": ("int8",),
-}
+NARROW_ASTYPE_MANIFEST: dict = {}
 
 
 class RangeContractViolation(Exception):

@@ -499,8 +499,7 @@ class Network:
         # delivers locally and enters mcache/IHAVE, but every transmit
         # drops it (the sendRPC fragmentRPC drop, gossipsub.go:1126-1140).
         # Opt-in here (None = unchecked): enabling it adds the per-message
-        # wire_block plane to the device state, which the opt-in Pallas
-        # fast paths (PUBSUB_PALLAS/PUBSUB_FUSED) predate — pass
+        # wire_block plane to the device state — pass
         # max_message_size=1 << 20 for the reference's default behavior.
         self.max_message_size = max_message_size
         self.oversized_publishes = 0

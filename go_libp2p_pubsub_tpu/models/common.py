@@ -17,8 +17,6 @@ counters, and the score engine later consumes it for delivery attribution.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 from flax import struct
@@ -27,34 +25,6 @@ from ..ops import bitset
 from ..perf import stages
 from ..state import Delivery, MsgTable, Net
 from ..trace.events import EV
-
-# opt-in fused Pallas delivery kernel for banded topologies (exact parity
-# with the XLA path — tests/test_pallas.py). Off by default: Mosaic
-# refuses the packed-word shape casts (ops/pallas_delivery.py docstring
-# has the compiler's message; tests/test_chip_compile.py pins it). Like
-# every Pallas switch here it runs interpreted off-TPU and COMPILED on a
-# TPU backend (_pallas_interpret), and a kernel that cannot be used for
-# the build raises — the XLA path is never taken in its place.
-USE_PALLAS = os.environ.get("PUBSUB_PALLAS", "") == "1"
-
-# opt-in fused Pallas kernels for the flat-[E] CSR plane (round 21,
-# ops/pallas_csr.py — exact parity with the fused composite,
-# tests/test_pallas_csr.py). Same rules as PUBSUB_PALLAS; refused by
-# the TPU lowering today (docstring there); requires a `fused=True` Net
-# (the composite and the kernel share the capacity-bounded scan contract).
-USE_PALLAS_CSR = os.environ.get("PUBSUB_PALLAS_CSR", "") == "1"
-
-
-def _pallas_block() -> int:
-    return int(os.environ.get("PUBSUB_PALLAS_BLOCK", "2000"))
-
-
-def _pallas_interpret() -> bool:
-    """Interpret mode is a property of the backend, never a switch (the
-    rule models/gossipsub.py applies to ops/fused_round.py): a TPU runs
-    the compiled kernel or the compile error, nothing interpreted."""
-    return jax.default_backend() != "tpu"
-
 
 @struct.dataclass
 class RoundInfo:
@@ -210,23 +180,6 @@ def delivery_round(
     # it here means a caller can never mismatch the two
     val_delay = 0 if dlv.pending is None else dlv.pending.shape[1]
 
-    if (USE_PALLAS and net.band_off is not None and forward_mask is None
-            and val_delay == 0 and queue_cap == 0
-            and msgs.wire_block is None):  # kernel predates the block plane
-        from ..ops.pallas_delivery import pallas_supported
-
-        block = min(_pallas_block(), n)
-        if not pallas_supported(net.band_off, n, block):
-            raise ValueError(
-                f"PUBSUB_PALLAS=1 but the banded kernel cannot tile this "
-                f"net: block {block} must divide n_peers={n} and cover the "
-                f"widest band offset, with at most 127 bands "
-                f"({len(net.band_off)} here) — set PUBSUB_PALLAS_BLOCK")
-        return _delivery_round_pallas(
-            net, msgs, dlv, edge_mask, tick, block=block,
-            interpret=_pallas_interpret(), count_events=count_events,
-        )
-
     not_mine = ~origin_msg_words(net, msgs)  # [N, W]
     if msgs.wire_block is not None:
         # oversized messages never cross any edge (sendRPC's fragmentRPC
@@ -248,12 +201,6 @@ def delivery_round(
         # delivery semantics stay single-source and dense-vs-CSR
         # parity is bit-exact (tests/test_csr.py, all four engines).
         flat_resident = dlv.fe_words.ndim == 2
-        if (flat_resident and net.fused and USE_PALLAS_CSR
-                and val_delay == 0 and queue_cap == 0):
-            return _delivery_round_pallas_csr(
-                net, msgs, dlv, edge_mask, not_mine, tick,
-                forward_mask=forward_mask, count_events=count_events,
-            )
         fwd_e = net.peer_gather_flat(dlv.fwd)                    # [E, W]
         echo_e = net.edge_gather_flat(
             dlv.fe_words if flat_resident
@@ -478,7 +425,7 @@ def finish_delivery_flat(
 
 def _round_info(trans, new_words, m, valid_words, count_events=True) -> RoundInfo:
     """Delivery observables from a round's transmit/new sets (shared by the
-    XLA and pallas paths so the trace-counter semantics stay single-source).
+    dense and flat commits so the trace-counter semantics stay single-source).
 
     `count_events=False` (no EventTracer attached — tracing is opt-in in
     the reference, pubsub.go WithEventTracer) skips the aggregate popcount
@@ -509,97 +456,6 @@ def _round_info(trans, new_words, m, valid_words, count_events=True) -> RoundInf
         n_duplicate=n_rpc - n_new,
         n_rpc=n_rpc,
     )
-
-
-def _delivery_round_pallas(net, msgs, dlv, edge_mask, tick, block=None,
-                           interpret=False, count_events=True):
-    """Banded fast path: one fused kernel for the whole round (see
-    ops/pallas_delivery.py). Bit-identical to the generic path above.
-    The kernel speaks the [N, M] i8 first-edge form; the packed state is
-    converted at the boundary (this path is opt-in)."""
-    from ..ops.pallas_delivery import delivery_round_banded
-
-    n, k_slots = net.nbr.shape
-    m = msgs.capacity
-    w = bitset.n_words(m)
-    ok_words = jnp.where(net.nbr_ok[..., None], jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
-    emask_flat = (edge_mask & ok_words).reshape(n, k_slots * w)
-    valid_words = bitset.pack(msgs.valid)
-    fe_i8 = bitset.first_edge_of(dlv.fe_words, m)
-    trans, have2, fwd2, fr2, fe2 = delivery_round_banded(
-        dlv.fwd, fe_i8, emask_flat, dlv.have, dlv.first_round,
-        msgs.origin, valid_words, tick,
-        block=min(block or n, n), m=m,
-        offsets=net.band_off, revs=net.band_rev,
-        interpret=interpret,
-    )
-    new_words = have2 & ~dlv.have
-    dlv2 = dlv.replace(
-        have=have2, fwd=fwd2, first_round=fr2,
-        fe_words=bitset.edge_eq_words(fe2, k_slots),
-    )
-    return dlv2, _round_info(trans, new_words, m, valid_words, count_events)
-
-
-def _pick_div(total: int, lo: int, want: int) -> int | None:
-    """Largest divisor of ``total`` in [lo, want] (static block sizing)."""
-    for b in range(min(want, total), lo - 1, -1):
-        if total % b == 0:
-            return b
-    return None
-
-
-def _delivery_round_pallas_csr(net, msgs, dlv, edge_mask, not_mine, tick,
-                               forward_mask=None, count_events=True):
-    """The CSR-resident round through the fused Pallas kernels
-    (ops/pallas_csr.csr_delivery — the three-call form of the flat
-    gather/scan/commit chain). Bit-identical to the composite path
-    below (tests/test_pallas_csr.py); opt-in via PUBSUB_PALLAS_CSR=1 on
-    a fused Net. Raises when the static block preconditions don't hold
-    (the composite is never taken in the kernel's place)."""
-    from ..ops import edges as _edges
-    from ..ops import pallas_csr as pcsr
-
-    e = net.n_edges
-    cap = net.max_degree
-    want = _pallas_block()
-    # at least two edge blocks: each grid step reads the previous one
-    block = _pick_div(e, cap, min(want, e // 2))
-    block_rows = _pick_div(net.n_peers, 1, want)
-    if (block is None or block_rows is None
-            or not pcsr.pallas_csr_supported(e, block, cap)):
-        raise ValueError(
-            f"PUBSUB_PALLAS_CSR=1 but the CSR kernels cannot tile this "
-            f"net: no block <= {want} (PUBSUB_PALLAS_BLOCK) divides "
-            f"n_edges={e} with block >= max_degree={cap} and at least two "
-            f"blocks, and n_peers={net.n_peers} in blocks")
-    interpret = _pallas_interpret()
-    m = msgs.capacity
-    mask_e = net.pack_edges(edge_mask)
-    valid_words = bitset.pack(msgs.valid)
-    # the kernel's col/eperm gathers ARE the flat peer/edge halo set the
-    # composite path tallies (peer_gather_flat / edge_gather_flat)
-    _edges._tally("peer", dlv.fe_words)
-    _edges._tally("edge", dlv.fe_words)
-    res = pcsr.csr_delivery(
-        dlv.fwd, dlv.fe_words, mask_e, not_mine, dlv.have,
-        dlv.first_round, valid_words[None, :], tick,
-        net.csr_col, net.csr_row, net.csr_eperm, net.csr_seg_start,
-        net.csr_row_last, net.csr_row_nonempty,
-        cap=cap, block=block, block_rows=block_rows, interpret=interpret,
-    )
-    fwd_next = res["fwd"]
-    if forward_mask is not None:
-        fwd_next = fwd_next & forward_mask
-    dlv2 = dlv.replace(
-        have=res["have"], fwd=fwd_next, first_round=res["first_round"],
-        fe_words=res["fe"],
-    )
-    new_words = res["new"]
-    info = _round_info(res["trans_e"], new_words, m, valid_words,
-                       count_events)
-    info = info.replace(recv_new_words=new_words)
-    return dlv2, info
 
 
 def accumulate_round_events(events: jax.Array, info: RoundInfo, n_publish) -> jax.Array:

@@ -37,7 +37,6 @@ CDF comparison):
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +53,6 @@ from ..config import (
     ticks_for,
 )
 from ..ops import bitset, edges
-from ..ops import fused_round as fr
 from ..ops.select import (
     count_true,
     masked_width_random,
@@ -213,7 +211,7 @@ class GossipSubConfig:
     # the CSR delivery commit (via the matching Net.build(fused=True)).
     # A frozen static like edge_layout: False traces the pre-fusion
     # program bit for bit (the census gate's contract); True is
-    # bit-exact in VALUES (tests/test_pallas_csr.py, all four engines)
+    # bit-exact in VALUES (tests/test_fused_composites.py, all four engines)
     # and is what `make cost-audit`'s fusion contract prices.
     fused: bool = False
     # int-packed control counters (round 15 narrowing contract, docs/
@@ -757,9 +755,15 @@ def handle_ihave(cfg: GossipSubConfig, net: Net, st: GossipSubState,
 
 def _served_capped(cfg: GossipSubConfig, lo: jax.Array, hi: jax.Array) -> jax.Array:
     """Word-mask of slots whose 2-bit served count has reached the
-    retransmission cap (cap clamps to the counter range 0..3). Shared with
-    the fused kernel so the two paths cannot drift."""
-    return fr.served_capped_mask(cfg.gossip_retransmission, lo, hi)
+    retransmission cap (cap clamps to the counter range 0..3)."""
+    cap = min(max(cfg.gossip_retransmission, 0), 3)
+    if cap >= 3:
+        return hi & lo
+    if cap == 2:
+        return hi
+    if cap == 1:
+        return hi | lo
+    return jnp.full_like(lo, np.uint32(0xFFFFFFFF))
 
 
 def iwant_responses(cfg: GossipSubConfig, net: Net, st: GossipSubState,
@@ -1953,11 +1957,11 @@ def accept_gates(cfg: GossipSubConfig, net_l: Net, st: GossipSubState,
     return acc_ok, acc_msg
 
 
-def control_parts(cfg: GossipSubConfig, net: Net, st: GossipSubState,
-                  include_score: bool):
+def control_parts(cfg: GossipSubConfig, net: Net, st: GossipSubState):
     """The control-plane outboxes as named packed word tensors — the wire
-    format both exchange paths (XLA gather-merge and fused Pallas halo
-    kernel) transmit, kept single-source so the two cannot drift."""
+    format both engines' exchanges transmit (``control_exchange`` here, the
+    phase engine's stacked wire), kept single-source so they cannot
+    drift."""
     named_parts = [
         ("graft", edges.topic_pack(st.graft_out, net.my_topics, net.n_topics)),
         ("prune", edges.topic_pack(st.prune_out, net.my_topics, net.n_topics)),
@@ -1967,7 +1971,7 @@ def control_parts(cfg: GossipSubConfig, net: Net, st: GossipSubState,
         named_parts.append(
             ("px", edges.topic_pack(st.prune_px_out, net.my_topics, net.n_topics))
         )
-    if include_score and cfg.score_enabled:
+    if cfg.score_enabled:
         named_parts.append(
             ("score",
              jax.lax.bitcast_convert_type(st.scores, jnp.uint32)[..., None])
@@ -1991,13 +1995,13 @@ def control_unpack(cfg: GossipSubConfig, net: Net, net_l: Net, w_seg):
 
 def control_exchange(cfg: GossipSubConfig, net: Net, net_l: Net,
                      st: GossipSubState):
-    """Merged control-plane wire exchange (XLA path): every per-edge outbox
+    """Merged control-plane wire exchange: every per-edge outbox
     crosses the edge involution in as few gathers as the measured
     gather-merge policy allows — the vectorized analogue of the reference
     piggybacking all control into one RPC (gossipsub.go:1096-1141 sendRPC +
     piggyback). Returns (graft_in_raw, prune_in_raw, ihave_in_raw,
     px_in_raw, nbr_score_of_me)."""
-    named_parts = control_parts(cfg, net, st, include_score=True)
+    named_parts = control_parts(cfg, net, st)
     parts = [p for _, p in named_parts]
     # Gather-merge policy (measured on the real chip, round 3).
     # Each gathered tensor = one set of rolled halo permutes on
@@ -2081,7 +2085,7 @@ def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
 
     Returns (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
     nbr_score_of_me, window_g, app_g)."""
-    named_parts = control_parts(cfg, net, st, include_score=True)
+    named_parts = control_parts(cfg, net, st)
     names = [nm for nm, _ in named_parts]
     parts = [p for _, p in named_parts]
     n_ctrl = len([nm for nm in names if nm != "score"])
@@ -2198,10 +2202,7 @@ def make_gossipsub_step(
     compiled program (the recompile-free A/B sentinel) and a vmapped
     plane axis sweeps weight populations. Matched values reproduce the
     static build bit for bit (tests/test_score_lift.py). Requires
-    ``cfg.score_enabled``. Since round 21 the fused Pallas data
-    plane is eligible too: its kernel takes the thresholds as a traced
-    [1, 2] f32 row, closing the float(threshold) SHAPE seam the audit
-    used to pin.
+    ``cfg.score_enabled``.
 
     With ``static_heartbeat=True`` (and ``cfg.heartbeat_every > 1``) the
     step takes a trailing *static* python bool ``do_heartbeat`` instead of
@@ -2266,7 +2267,7 @@ def make_gossipsub_step(
     (death/replacement rides the up plane), a net built with
     ``Net.build(..., dynamic=True)``, and none of the planes that bake
     neighbor identity into jit constants (adversary, announce holes,
-    PX / edge-liveness, fused/banded kernels). No schedule — i.e.
+    PX / edge-liveness, banded rolls, the fused composites). No schedule — i.e.
     ``dynamic_topo=False``, the default — elides the plane statically:
     the traced program, kernel census and state tree are the pre-dynamics
     ones, bit for bit (tests/test_dynamics.py).
@@ -2289,8 +2290,8 @@ def make_gossipsub_step(
         if net.band_off is not None or net.fused or cfg.fused:
             raise ValueError(
                 "dynamic_topo=True needs an unbanded net "
-                "(Net.build(..., dynamic=True)) — the banded/fused halo "
-                "kernels bake the edge geometry at trace time"
+                "(Net.build(..., dynamic=True)) — the banded rolls and the "
+                "fused composites bake the edge geometry at trace time"
             )
         if net.edge_layout == "csr" and (
             not net.csr_identity
@@ -2371,16 +2372,6 @@ def make_gossipsub_step(
     nbr_sub_words = consts.nbr_sub_words
     sender_fwd_ok = consts.sender_fwd_ok
 
-    # fused Pallas data plane (ops/fused_round.py): the whole edge-crossing
-    # exchange + delivery as two kernels on banded topologies. Opt-in via
-    # PUBSUB_FUSED=1 (bit-identical to the XLA path — tests/
-    # test_fused_round.py, and on the v5e at N=100k, PR 23): the kernels
-    # lose to XLA's fusion pipeline there (1.8154 s vs 0.1703 s per 64
-    # ticks, my chip run, PR 23), so the XLA path stays the production
-    # default. The async-validation pipeline always
-    # keeps the XLA path (pending stages live outside the kernel).
-    from .common import USE_PALLAS as _old_pallas
-
     # chaos plane (chaos/faults.py): None elides it statically — every
     # chaos branch below disappears from the trace and the program is
     # the pre-chaos one, bit for bit (tests/test_chaos.py)
@@ -2388,30 +2379,6 @@ def make_gossipsub_step(
     chaos_sched = chaos is not None and chaos.scheduled
     adv = consts.adv
 
-    fused_env = os.environ.get("PUBSUB_FUSED", "")
-    fused_eligible = (
-        net.band_off is not None
-        and fr.fused_supported(net.n_peers, net.band_off, net.max_degree)
-        and cfg.validation_delay_rounds == 0
-        and cfg.queue_cap == 0
-        and not _old_pallas
-        and chaos is None  # the fused halo kernel predates the chaos plane
-        and adv is None    # ... and the adversary plane
-        and cfg.router is None  # ... and the router plane (§24)
-        # lifted ScoreParams builds are eligible since round 21: the
-        # kernel takes thresholds as a traced [1, 2] f32 row, so the
-        # former float(threshold) SHAPE seam is closed (the lifted+fused
-        # guards row pins the one-compile A/B sentinel on this path)
-    )
-    fused_interp = jax.default_backend() != "tpu"
-    use_fused = fused_eligible and fused_env == "1"
-    fused_block = (
-        fr.pick_block(net.n_peers, net.band_off) if use_fused else None
-    )
-    sender_fwd_full = (
-        sender_fwd_ok if sender_fwd_ok is not None
-        else jnp.ones(net.nbr.shape, bool)
-    )
     if dynamic_topo:
         # lazy import: the static build's module graph (and trace) stays
         # byte-identical to the pre-dynamics one
@@ -2516,35 +2483,9 @@ def make_gossipsub_step(
         # concatenated word tensor (graft | prune | ihave [| px] [| score])
         # and is split receiver-side — the vectorized analogue of the
         # reference piggybacking all control into one RPC (gossipsub.go:
-        # 1096-1141 sendRPC + piggyback). On banded topologies the gather
-        # runs as a Pallas halo kernel (ops/fused_round.edge_exchange) and
-        # the score plane rides as f32 instead of a bitcast word.
-        n_peers = net.n_peers
-        k_dim = net.max_degree
-        if use_fused:
-            # the score plane rides inside the kernel as f32, not a part
-            parts = [p for _, p in control_parts(cfg, net, st,
-                                                 include_score=False)]
-            sizes = np.cumsum([0] + [p.shape[-1] for p in parts])
-            wc = int(sizes[-1])
-            wire_flat, nbr_score_of_me = fr.edge_exchange(
-                jnp.concatenate(parts, axis=-1).reshape(n_peers, k_dim * wc),
-                st.scores if cfg.score_enabled else None,
-                net_l.nbr_ok.astype(jnp.uint32),
-                block=fused_block, offsets=net.band_off, revs=net.band_rev,
-                c=wc, score_enabled=cfg.score_enabled,
-                interpret=fused_interp,
-            )
-            wire = wire_flat.reshape(n_peers, k_dim, wc)
-            if not cfg.score_enabled:
-                nbr_score_of_me = None
-            graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw = (
-                control_unpack(cfg, net, net_l,
-                               lambda i: wire[..., sizes[i] : sizes[i + 1]])
-            )
-        else:
-            (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
-             nbr_score_of_me) = control_exchange(cfg, net, net_w, st)
+        # 1096-1141 sendRPC + piggyback).
+        (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
+         nbr_score_of_me) = control_exchange(cfg, net, net_w, st)
 
         # 1. GRAFT/PRUNE ingest
         st2, prune_resp, px_resp, px_ok, n_graft, n_prune = handle_graft_prune(
@@ -2569,240 +2510,142 @@ def make_gossipsub_step(
         slotw = slot_topic_words(net_l, core.msgs.topic)
         pre_have = core.dlv.have
         n_adv_drop = None
-        if use_fused:
-            if core.msgs.wire_block is not None:
-                raise NotImplementedError(
-                    "the fused Pallas data plane predates the wire_block "
-                    "(max-message-size) plane — use the default XLA path"
-                )
-            # 2+3+4 fused: IHAVE ingest first (it consumes nothing the
-            # delivery kernel writes), then the whole delivery plane —
-            # mesh/fanout/flood push, echo suppression, IWANT service with
-            # retransmission counters, seen-cache dedup, first-arrival
-            # attribution — in one Pallas kernel over the post-graft mesh.
-            asked_old = st2.iwant_out
-            served_lo_old, served_hi_old = st2.served_lo, st2.served_hi
-            st2 = handle_ihave(cfg, net_l, st2, joined_words, acc_ok, ihave_in_raw)
+        # 2. IWANT service (requests sent to me last round -> delivery
+        # carry) — the mcache-window gather rides the wire view, so a
+        # flapped link's responses are lost (and its retransmission
+        # counters don't tick: the data never arrived)
+        st2, iwant_resp = iwant_responses(cfg, net_w, st2,
+                                          nbr_score_of_me, thr=thr)
 
-            carry = sender_carry_words(st2.mesh, slotw)
-            if cfg.fanout_slots > 0:
-                carry = carry | fanout_carry_words(
-                    st2.fanout_peers, st2.fanout_topic, core.msgs.topic
-                )
-            origin_w = origin_msg_words(net_l, core.msgs)
-            if cfg.flood_publish:
-                # sender-side fold of v1.1 flood-publish: the origin pushes
-                # its own messages on every edge it scores above
-                # publishThreshold (gossipsub.go:957-963) — equivalent to
-                # the receiver-side origin compare, because nbr_score_of_me
-                # at the receiver IS the sender's score of that edge
-                fp_ok = (
-                    (st.scores >= thr.publish_threshold)
-                    if cfg.score_enabled else net_l.nbr_ok
-                )
-                carry = carry | jnp.where(
-                    fp_ok[:, :, None], origin_w[:, None, :], jnp.uint32(0)
-                )
-            flags = fr.make_flags(
-                acc_msg, flood_from, i_am_floodsub, sender_fwd_full,
-                net_l.nbr_ok,
-            )
-            mcw = bitset.word_or_reduce(st2.mcache, axis=1)
-            w_dim = bitset.n_words(m)
-            kw = k_dim * w_dim
-            res = fr.fused_delivery(
-                carry.reshape(n_peers, kw),
-                core.dlv.fe_words.reshape(n_peers, kw),
-                core.dlv.fwd, mcw,
-                nbr_score_of_me,
-                asked_old.reshape(n_peers, kw),
-                served_lo_old.reshape(n_peers, kw),
-                served_hi_old.reshape(n_peers, kw),
-                flags, pre_have, origin_w, joined_words,
-                bitset.pack(core.msgs.valid)[None, :],
-                block=fused_block, offsets=net.band_off, revs=net.band_rev,
-                w=w_dim, score_enabled=cfg.score_enabled,
-                want_cohorts=cfg.count_events,
-                retrans_cap=cfg.gossip_retransmission,
-                gossip_thr=jnp.asarray(thr.gossip_threshold, jnp.float32),
-                publish_thr=jnp.asarray(thr.publish_threshold, jnp.float32),
-                interpret=fused_interp,
-            )
-            new_words_f = res["new"]
-            new_bits_f = bitset.unpack(new_words_f, m)
-            dlv = core.dlv.replace(
-                have=res["have"], fwd=res["fwd"],
-                first_round=jnp.where(new_bits_f, tick, core.dlv.first_round),
-                fe_words=res["fe"].reshape(n_peers, k_dim, w_dim),
-            )
-            st2 = st2.replace(
-                served_lo=res["served_lo"].reshape(n_peers, k_dim, w_dim),
-                served_hi=res["served_hi"].reshape(n_peers, k_dim, w_dim),
-            )
-            if cfg.count_events:
-                # cohort-split counters matching the XLA path's two-stage
-                # accounting (delivery_round then merge_extra_tx): RPCs
-                # count mesh-push and IWANT-response transmissions
-                # separately even when they overlap on an (edge, msg)
-                valid_pack = bitset.pack(core.msgs.valid)
-                n_rpc = (
-                    bitset.popcount(res["mesh_trans"], axis=None).sum()
-                    + bitset.popcount(res["extra"], axis=None).sum()
-                ).astype(jnp.int32)
-                n_new = bitset.popcount(new_words_f, axis=None).sum().astype(jnp.int32)
-                n_deliver = (
-                    bitset.popcount(new_words_f & valid_pack[None, :], axis=None)
-                    .sum().astype(jnp.int32)
-                )
-                n_reject = n_new - n_deliver
-                n_duplicate = n_rpc - n_new
-            else:
-                n_rpc = n_new = n_deliver = n_reject = n_duplicate = jnp.int32(0)
-            info = RoundInfo(
-                trans=res["trans"].reshape(n_peers, k_dim, w_dim),
-                new_words=new_words_f,
-                new_bits=new_bits_f,
-                recv_new_words=new_words_f,
-                n_deliver=n_deliver, n_reject=n_reject,
-                n_duplicate=n_duplicate, n_rpc=n_rpc,
-            )
+        # 3. IHAVE ingest (advertisements -> next round's requests)
+        st2 = handle_ihave(cfg, net_l, st2, joined_words, acc_ok,
+                           ihave_in_raw, thr=thr)
+
+        # 4. delivery: mesh/fanout push + flood edges + IWANT responses
+        # floodsub-peer edges: sender floodsub => flood; receiver floodsub
+        # => gossipsub sender still sends everything (score-gated,
+        # gossipsub.go:973-978)
+        if cfg.score_enabled:
+            recv_ok = nbr_score_of_me >= thr.publish_threshold
         else:
-            # 2. IWANT service (requests sent to me last round -> delivery
-            # carry) — the mcache-window gather rides the wire view, so a
-            # flapped link's responses are lost (and its retransmission
-            # counters don't tick: the data never arrived)
-            st2, iwant_resp = iwant_responses(cfg, net_w, st2,
-                                              nbr_score_of_me, thr=thr)
-
-            # 3. IHAVE ingest (advertisements -> next round's requests)
-            st2 = handle_ihave(cfg, net_l, st2, joined_words, acc_ok,
-                               ihave_in_raw, thr=thr)
-
-            # 4. delivery: mesh/fanout push + flood edges + IWANT responses
-            # floodsub-peer edges: sender floodsub => flood; receiver floodsub
-            # => gossipsub sender still sends everything (score-gated,
-            # gossipsub.go:973-978)
-            if cfg.score_enabled:
-                recv_ok = nbr_score_of_me >= thr.publish_threshold
-            else:
-                recv_ok = net_l.nbr_ok
-            flood_edges = flood_from_l | (i_am_floodsub[:, None] & recv_ok & net_l.nbr_ok)
-            edge_mask = gossip_edge_mask(
-                cfg, net_l, st2, joined_words, acc_msg, slotw,
-                core.msgs.topic, flood_edges,
-                nbr_score_of_me, thr=thr,
-            )
-            if sender_fwd_ok is not None:
-                edge_mask = jnp.where(sender_fwd_ok[:, :, None], edge_mask, jnp.uint32(0))
-                iwant_resp = jnp.where(sender_fwd_ok[:, :, None], iwant_resp, jnp.uint32(0))
-            # adversary data plane (chaos/adversary.py): drop-on-
-            # forward / censorship suppress bits on edges from ACTIVE
-            # attackers — one AND into the receiver gathers the step
-            # already performs, zero extra halo permutes (the behavior
-            # masks and their neighbor views are eager jit constants)
-            if adv is not None and adv.data_plane:
-                edge_mask, rem_mask = adv.mask_transmit_nbr(
-                    tick, edge_mask, core.msgs)
-                iwant_resp, rem_resp = adv.mask_transmit_nbr(
-                    tick, iwant_resp, core.msgs)
-                if cfg.count_events:
-                    # withheld-transmission attribution: suppressed
-                    # carry bits ∩ the senders' forward sets (the same
-                    # fwd gather delivery_round performs — XLA CSE
-                    # merges the two); IWANT-response bits are actual
-                    # serves, counted whole
-                    fwd_g = net_l.peer_gather(core.dlv.fwd)
-                    n_adv_drop = (
-                        bitset.popcount(rem_mask & fwd_g, axis=None).sum()
-                        + bitset.popcount(rem_resp, axis=None).sum()
-                    ).astype(jnp.int32)
-            # ---- router plane (docs/DESIGN.md §24) ----------------------
-            # receiver-side data suppression: both IDONTWANT (§24a) and
-            # choke (§24b) land as ANDs on edge_mask BEFORE delivery_round,
-            # so the dense and the flat-[E] CSR layouts (which pack
-            # edge_mask internally) are covered identically, with zero
-            # extra halo permutes — the sender's view of "I was told not
-            # to" is receiver-indexed, exactly like the adversary masks
-            n_dup_sup = None
-            ring_tx = None
-            if router is not None:
-                mesh_edge = jnp.any(st2.mesh, axis=1)
-                suppress = jnp.zeros_like(edge_mask)
-                if router.idontwant_eligible:
-                    suppress = suppress | dontwant_suppression(
-                        st.dontwant, mesh_edge
-                    )
-                if router.choke:
-                    ch_edge = choke_suppression(st2.choked)
-                    suppress = suppress | jnp.where(
-                        ch_edge[:, :, None], jnp.uint32(0xFFFFFFFF),
-                        jnp.uint32(0),
-                    )
-                removed = edge_mask & suppress
-                edge_mask = edge_mask & ~suppress
-                if cfg.count_events:
-                    # suppressed-transmission attribution: withheld carry
-                    # bits ∩ the senders' forward sets — the n_adv_drop
-                    # convention above (same fwd gather delivery_round
-                    # performs; XLA CSE merges them)
-                    fwd_g = net_l.peer_gather(core.dlv.fwd)
-                    n_dup_sup = bitset.popcount(
-                        removed & fwd_g, axis=None
-                    ).sum().astype(jnp.int32)
-                if router.latency_rounds > 0:
-                    # §24c latency ring — store-and-forward: the sender's
-                    # fwd plane is a ONE-round window (this round's
-                    # validated cohort, models/common.py), so a commit
-                    # landing d rounds later would find it already empty.
-                    # The decision therefore resolves against the
-                    # sender's fwd window and the echo exclusion AT SEND
-                    # TIME (what's on the wire was valid when it left),
-                    # and the ring carries the resolved transmission
-                    # words; slot-0 pops commit below via merge_extra_tx,
-                    # the path built for transmissions outside senders'
-                    # current fwd sets (IWANT responses). Delay-0 edges
-                    # never enter the ring: they keep the v1.1
-                    # delivery_round path bit-for-bit.
-                    d0w = jnp.where(
-                        (link_delay_c == 0)[:, :, None],
-                        jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
-                    eager = (edge_mask & net_l.peer_gather(core.dlv.fwd)
-                             & ~net_l.edge_gather(core.dlv.fe_words)
-                             & ~d0w)
-                    ring_tx, inflight_next = ring_commit(
-                        st.inflight, eager, link_delay_c
-                    )
-                    edge_mask = edge_mask & d0w
-            dlv, info = delivery_round(
-                net_l, core.msgs, core.dlv, edge_mask, tick,
-                count_events=cfg.count_events, queue_cap=cfg.queue_cap,
-                val_delay_topic=cfg.validation_delay_topic,
-            )
-            if ring_tx is not None:
-                # latency-ring arrivals land this round (merged before
-                # the IWANT responses so the recovery attribution below
-                # stays IWANT-only)
-                dlv, info = merge_extra_tx(
-                    net_l, core.msgs, dlv, info, ring_tx, tick,
-                    count_events=cfg.count_events, queue_cap=cfg.queue_cap,
-                    val_delay_topic=cfg.validation_delay_topic)
-            iwant_resp = jnp.where(acc_msg[:, :, None], iwant_resp, jnp.uint32(0))
-            have_pre_merge = dlv.have
-            dlv, info = merge_extra_tx(net_l, core.msgs, dlv, info, iwant_resp, tick,
-                                       count_events=cfg.count_events,
-                                       queue_cap=cfg.queue_cap,
-                                       val_delay_topic=cfg.validation_delay_topic)
-            if chaos is not None and cfg.count_events:
-                # IWANT-recovery attribution: receipts whose FIRST arrival
-                # rode the IWANT service rather than an eager push (the
-                # chaos metrics' recovery-efficacy numerator; valid-plane
-                # membership read at arrival — under async validation the
-                # verdict lands later, same arrival-cohort convention as
-                # the duplicate counter)
-                n_iwant_rec = bitset.popcount(
-                    (dlv.have & ~have_pre_merge)
-                    & bitset.pack(core.msgs.valid)[None, :], axis=None,
+            recv_ok = net_l.nbr_ok
+        flood_edges = flood_from_l | (i_am_floodsub[:, None] & recv_ok & net_l.nbr_ok)
+        edge_mask = gossip_edge_mask(
+            cfg, net_l, st2, joined_words, acc_msg, slotw,
+            core.msgs.topic, flood_edges,
+            nbr_score_of_me, thr=thr,
+        )
+        if sender_fwd_ok is not None:
+            edge_mask = jnp.where(sender_fwd_ok[:, :, None], edge_mask, jnp.uint32(0))
+            iwant_resp = jnp.where(sender_fwd_ok[:, :, None], iwant_resp, jnp.uint32(0))
+        # adversary data plane (chaos/adversary.py): drop-on-
+        # forward / censorship suppress bits on edges from ACTIVE
+        # attackers — one AND into the receiver gathers the step
+        # already performs, zero extra halo permutes (the behavior
+        # masks and their neighbor views are eager jit constants)
+        if adv is not None and adv.data_plane:
+            edge_mask, rem_mask = adv.mask_transmit_nbr(
+                tick, edge_mask, core.msgs)
+            iwant_resp, rem_resp = adv.mask_transmit_nbr(
+                tick, iwant_resp, core.msgs)
+            if cfg.count_events:
+                # withheld-transmission attribution: suppressed
+                # carry bits ∩ the senders' forward sets (the same
+                # fwd gather delivery_round performs — XLA CSE
+                # merges the two); IWANT-response bits are actual
+                # serves, counted whole
+                fwd_g = net_l.peer_gather(core.dlv.fwd)
+                n_adv_drop = (
+                    bitset.popcount(rem_mask & fwd_g, axis=None).sum()
+                    + bitset.popcount(rem_resp, axis=None).sum()
+                ).astype(jnp.int32)
+        # ---- router plane (docs/DESIGN.md §24) ----------------------
+        # receiver-side data suppression: both IDONTWANT (§24a) and
+        # choke (§24b) land as ANDs on edge_mask BEFORE delivery_round,
+        # so the dense and the flat-[E] CSR layouts (which pack
+        # edge_mask internally) are covered identically, with zero
+        # extra halo permutes — the sender's view of "I was told not
+        # to" is receiver-indexed, exactly like the adversary masks
+        n_dup_sup = None
+        ring_tx = None
+        if router is not None:
+            mesh_edge = jnp.any(st2.mesh, axis=1)
+            suppress = jnp.zeros_like(edge_mask)
+            if router.idontwant_eligible:
+                suppress = suppress | dontwant_suppression(
+                    st.dontwant, mesh_edge
+                )
+            if router.choke:
+                ch_edge = choke_suppression(st2.choked)
+                suppress = suppress | jnp.where(
+                    ch_edge[:, :, None], jnp.uint32(0xFFFFFFFF),
+                    jnp.uint32(0),
+                )
+            removed = edge_mask & suppress
+            edge_mask = edge_mask & ~suppress
+            if cfg.count_events:
+                # suppressed-transmission attribution: withheld carry
+                # bits ∩ the senders' forward sets — the n_adv_drop
+                # convention above (same fwd gather delivery_round
+                # performs; XLA CSE merges them)
+                fwd_g = net_l.peer_gather(core.dlv.fwd)
+                n_dup_sup = bitset.popcount(
+                    removed & fwd_g, axis=None
                 ).sum().astype(jnp.int32)
+            if router.latency_rounds > 0:
+                # §24c latency ring — store-and-forward: the sender's
+                # fwd plane is a ONE-round window (this round's
+                # validated cohort, models/common.py), so a commit
+                # landing d rounds later would find it already empty.
+                # The decision therefore resolves against the
+                # sender's fwd window and the echo exclusion AT SEND
+                # TIME (what's on the wire was valid when it left),
+                # and the ring carries the resolved transmission
+                # words; slot-0 pops commit below via merge_extra_tx,
+                # the path built for transmissions outside senders'
+                # current fwd sets (IWANT responses). Delay-0 edges
+                # never enter the ring: they keep the v1.1
+                # delivery_round path bit-for-bit.
+                d0w = jnp.where(
+                    (link_delay_c == 0)[:, :, None],
+                    jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+                eager = (edge_mask & net_l.peer_gather(core.dlv.fwd)
+                         & ~net_l.edge_gather(core.dlv.fe_words)
+                         & ~d0w)
+                ring_tx, inflight_next = ring_commit(
+                    st.inflight, eager, link_delay_c
+                )
+                edge_mask = edge_mask & d0w
+        dlv, info = delivery_round(
+            net_l, core.msgs, core.dlv, edge_mask, tick,
+            count_events=cfg.count_events, queue_cap=cfg.queue_cap,
+            val_delay_topic=cfg.validation_delay_topic,
+        )
+        if ring_tx is not None:
+            # latency-ring arrivals land this round (merged before
+            # the IWANT responses so the recovery attribution below
+            # stays IWANT-only)
+            dlv, info = merge_extra_tx(
+                net_l, core.msgs, dlv, info, ring_tx, tick,
+                count_events=cfg.count_events, queue_cap=cfg.queue_cap,
+                val_delay_topic=cfg.validation_delay_topic)
+        iwant_resp = jnp.where(acc_msg[:, :, None], iwant_resp, jnp.uint32(0))
+        have_pre_merge = dlv.have
+        dlv, info = merge_extra_tx(net_l, core.msgs, dlv, info, iwant_resp, tick,
+                                   count_events=cfg.count_events,
+                                   queue_cap=cfg.queue_cap,
+                                   val_delay_topic=cfg.validation_delay_topic)
+        if chaos is not None and cfg.count_events:
+            # IWANT-recovery attribution: receipts whose FIRST arrival
+            # rode the IWANT service rather than an eager push (the
+            # chaos metrics' recovery-efficacy numerator; valid-plane
+            # membership read at arrival — under async validation the
+            # verdict lands later, same arrival-cohort convention as
+            # the duplicate counter)
+            n_iwant_rec = bitset.popcount(
+                (dlv.have & ~have_pre_merge)
+                & bitset.pack(core.msgs.valid)[None, :], axis=None,
+            ).sum().astype(jnp.int32)
 
         # exact-trace duplicate plane: arrivals beyond the first per
         # (peer, msg) — captured pre-throttle (throttled receipts are
@@ -2817,8 +2660,7 @@ def make_gossipsub_step(
 
         # router choke signal: fold this round's per-edge lateness into
         # the EMA (arrival-based, pre-throttle — the same cohort the dup
-        # counter uses). Router builds never take the fused path, so
-        # info/dlv here are always the XLA delivery plane's.
+        # counter uses).
         if router is not None and router.choke:
             choke_ema_next = choke_lateness_update(
                 router, st2.choke_ema, info.trans, dlv.fe_words,

@@ -95,12 +95,11 @@ Known deviations vs the per-round step, all bounded in PARITY.md:
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import state as state_mod
 from ..chaos import faults as chaos_faults
 from ..ops import bitset
 from ..perf import stages
@@ -279,10 +278,6 @@ def make_gossipsub_phase_step(
     ``t + i`` exactly as the per-round step would, so workload timing and
     the propagation CDF are directly comparable.
 
-    The fused Pallas data plane (PUBSUB_FUSED) is not applicable here —
-    the phase engine's sender-side form already collapses the exchange to
-    one gather per sub-round.
-
     ``cfg.wire_coalesced`` (default True) selects the round-7 stacked
     data plane — coalesced control-head exchange, head publish plan,
     stacked accumulator folds (see the module docstring); False builds
@@ -356,10 +351,9 @@ def make_gossipsub_phase_step(
     )
     n_peers, k_dim = net.nbr.shape
     val_delay = cfg.validation_delay_rounds
-    use_counts = (
-        score_counts if score_counts is not None
-        else os.environ.get("PUBSUB_PHASE_COUNTS", "") == "1"
-    )
+    use_counts = bool(score_counts)
+    # read through the module at build time (tests move the crossover)
+    scatter_form = n_peers >= state_mod.SCATTER_FORM_MIN_PEERS
     # static weight elision: the topic score params are jit constants, so
     # attribution planes whose consuming weights are zero EVERYWHERE can
     # be skipped at build time. The mmd counter has TWO consumers: P3
@@ -590,7 +584,7 @@ def make_gossipsub_phase_step(
         # the r-per-phase popcount trees cost more VPU time than the
         # plane ORs cost HBM stores on this libtpu. The PLANE path is
         # therefore the default; the count path stays as an opt-in
-        # (score_counts=True / PUBSUB_PHASE_COUNTS=1) for workloads where
+        # (score_counts=True) for workloads where
         # within-phase slot recycling would otherwise shave score credit,
         # and is required-off for the async-validation pipeline (pend_dup
         # needs cross-sub-round word algebra).
@@ -870,8 +864,9 @@ def make_gossipsub_phase_step(
                 mcache = mcache.at[:, 0, :].set(mcache[:, 0, :] | put)
 
             # publishes for this sub-round + recycled-slot cleanup (the
-            # scatter form wins in the phase sub-round at N >= 20k —
-            # state.py allocate_publishes docstring has the measurements)
+            # scatter form wins in the phase sub-round from
+            # state.SCATTER_FORM_MIN_PEERS on — allocate_publishes'
+            # docstring has the measurements)
             if plan is not None:
                 # the table half already lives in the head snapshots
                 # (msgs_at(i+1) is read at the next iteration's top); only
@@ -880,14 +875,14 @@ def make_gossipsub_phase_step(
                 _slots, is_pub = plan.sidx[i], plan.is_pub[i]
                 keep_w, pub_words = plan.keep_w[i], plan.pub_words[i]
                 dlv = plan.apply_to_delivery(
-                    dlv, i, tick_i, scatter_form=n_peers >= 20_000
+                    dlv, i, tick_i, scatter_form=scatter_form
                 )
                 origin_w = (origin_w & keep_w[None, :]) | pub_words
             else:
                 msgs, dlv, _slots, is_pub, keep_w, pub_words = \
                     allocate_publishes(
                         msgs, dlv, tick_i, pub_origin[i], pub_topic[i],
-                        pub_valid[i], scatter_form=n_peers >= 20_000,
+                        pub_valid[i], scatter_form=scatter_form,
                     )
             # incremental membership-plane maintenance (narrow universes):
             # recycled columns clear, then each publish ORs its one-hot

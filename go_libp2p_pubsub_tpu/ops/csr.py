@@ -403,7 +403,7 @@ def segment_or_scan(words_e: jax.Array, seg_start: jax.Array,
     13, and the cost audit charges each level's [E, W] operand bytes,
     so the bounded form is the one whose hbm_bytes/round the fusion
     contract pins. Bit-exact with the unbounded scan for any legal
-    ``cap`` (tests/test_pallas_csr.py) — both realize the same
+    ``cap`` (tests/test_fused_composites.py) — both realize the same
     segmented-OR monoid, the bound only truncates provably-masked
     levels."""
     flags = jnp.asarray(seg_start, bool)

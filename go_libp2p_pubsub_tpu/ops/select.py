@@ -78,7 +78,7 @@ def rank_desc(values: jax.Array, mask: jax.Array, key: jax.Array | None = None,
     shuffle-before-sort idiom (gossipsub.go:1391-1395).
 
     Two statically-selected forms (``cfg.fused``, round 21 — bit-exact,
-    tests/test_pallas_csr.py):
+    tests/test_fused_composites.py):
 
       * ``fused=False`` (default): an O(K^2) pairwise comparison count —
         the neighbor axis K is small (<= 64) and padded-static, so the
@@ -88,9 +88,7 @@ def rank_desc(values: jax.Array, mask: jax.Array, key: jax.Array | None = None,
         compare planes are K× the row data.
       * ``fused=True``: the sort composite (:func:`_rank_desc_sorted`) —
         O(K) bytes per row instead of O(K^2), the form the round-19
-        cost audit's hbm_bytes fits select. The Pallas twin
-        (ops/pallas_csr.select_topk_pallas) keeps the pairwise compare
-        entirely in VMEM — same math, zero HBM intermediates.
+        cost audit's hbm_bytes fits select.
     """
     if key is not None:
         noise = jax.random.uniform(key, values.shape)

@@ -32,10 +32,6 @@ import numpy as np
 #: publish batch width every bench/sweep cell uses ([R, 4] schedules)
 PUBS_PER_ROUND = 4
 
-#: the phase engine flips allocate_publishes to its scatter form at this
-#: peer count (models/gossipsub_phase.py; state.py has the measurements)
-SCATTER_ALLOC_MIN_N = 20_000
-
 #: incremental membership planes are a narrow-universe optimization
 #: (gossipsub_phase.py round-4 addendum 4)
 INCR_MEMBERS_MAX_TOPICS = 8
@@ -449,6 +445,8 @@ def workload_fingerprint(
     engine's static weight elision dropped the mesh-credit (P3/mmd) and
     invalid-delivery (P4/imd) attribution planes for this config — a
     workload property that changes what the headline prices."""
+    from ..state import SCATTER_FORM_MIN_PEERS
+
     n_topics = 64 if config == "eth2" else 1
     tp, sp = bench_score_params(config, n_topics)
     phase = rounds_per_phase > 1
@@ -501,7 +499,8 @@ def workload_fingerprint(
             "validation_capacity": 8 if config == "sybil" else 0,
             "count_events": False,
             "fanout_slots": 2 if config == "eth2" else 0,
-            "scatter_publish_alloc": bool(phase and n_peers >= SCATTER_ALLOC_MIN_N),
+            "scatter_publish_alloc": bool(
+                phase and n_peers >= SCATTER_FORM_MIN_PEERS),
             # incremental membership planes exist only in the phase
             # engine (gossipsub_phase.py round-4 addendum 4)
             "incr_members": bool(phase and n_topics <= INCR_MEMBERS_MAX_TOPICS),

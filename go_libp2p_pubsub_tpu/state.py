@@ -101,8 +101,8 @@ class Net:
     csr_identity: bool = struct.field(pytree_node=False, default=False)
     csr_band_off: tuple = struct.field(pytree_node=False, default=None)
     csr_band_rev: tuple = struct.field(pytree_node=False, default=None)
-    # fused data plane (round 21, docs/DESIGN.md §21): statically select
-    # the bandwidth-lean composite kernels on the shared delivery seam —
+    # fused composites (round 21, docs/DESIGN.md §21): statically select
+    # the bandwidth-lean XLA forms on the shared delivery seam —
     # the capacity-bounded segmented OR in the flat commit
     # (ops/csr.segment_or_scan cap=K) and, in engines that read it, the
     # sort-form selection (ops/select fused=True). Pytree-AUX like
@@ -261,7 +261,7 @@ class Net:
             )
         if dynamic and fused:
             raise ValueError(
-                "dynamic=True is incompatible with the fused kernel set "
+                "dynamic=True is incompatible with the fused composites "
                 "(cfg.fused) — the composites assume a static edge list"
             )
         if dynamic and edge_shards is not None:
@@ -332,8 +332,8 @@ class Net:
                 csr_band_off=band_flat[0] if band_flat else None,
                 csr_band_rev=band_flat[1] if band_flat else None,
             )
-            # the DENSE banded-roll and Pallas fast paths key off
-            # band_off; a CSR build must never fall into them (the flat
+            # the DENSE banded-roll path keys off band_off; a CSR
+            # build must never fall into it (the flat
             # analogue rides csr_band_off above)
             band = None
         else:
@@ -929,13 +929,8 @@ class PhasePubPlan:
         first_round stamps on the delivery state — the dlv half of
         ``allocate_publishes``, fed by the precomputed masks (wide word
         folds only; bit-identical to the per-sub-round scatter path).
-        ``scatter_form`` honors the same PUBSUB_PUB_SCATTER A/B override
-        as allocate_publishes (both forms are exact-equivalent)."""
-        import os
-
-        env = os.environ.get("PUBSUB_PUB_SCATTER")
-        if env is not None:
-            scatter_form = env == "1"
+        ``scatter_form`` chooses as in allocate_publishes (both forms are
+        exact-equivalent)."""
         keep = self.keep_w[i]
         pw = self.pub_words[i]
         n_peers = dlv.have.shape[0]
@@ -968,6 +963,12 @@ class PhasePubPlan:
             pending=pending,
         )
 
+#: peers from which the phase engine takes the scatter form of the publish
+#: stamp (allocate_publishes' docstring has both forms and the readings);
+#: the per-round engine keeps the plane form at every N
+SCATTER_FORM_MIN_PEERS = 20_000
+
+
 @stages.scope("pub_plan")
 def allocate_publishes(
     msgs: MsgTable,
@@ -994,15 +995,16 @@ def allocate_publishes(
     publish (undefined where ~is_pub).
 
     Two exact-equivalent forms for the first_round/pub_words updates
-    (PUBSUB_PUB_SCATTER=0/1 overrides both callers, for the equivalence
-    test — tests/test_ops.py):
+    (``scatter_form``; tests/test_ops.py runs a phase build under each
+    and compares every state plane):
 
       * scatter form: the recycled-column clear + origin stamp as ONE
         <=P-column scatter, pub_words as a P-element word scatter. The
         plane form's where(reused)/one-hot+pack reads and writes the
         whole [N, M] s32 plane (~50 MB of HBM traffic at N=100k/M=64)
         to touch at most P columns — profiled 42 us/sub-round, 7% of
-        the phase round. The PHASE engine selects it at N >= 20k:
+        the phase round. The PHASE engine selects it at
+        N >= SCATTER_FORM_MIN_PEERS:
         +6-11% on the N=100k bench (r=8: 1424 -> 1559; r=16: 1691 ->
         1882 rounds/s, round 5).
       * plane form (default): scatters carry a fixed per-op cost that
@@ -1012,8 +1014,6 @@ def allocate_publishes(
         with the surrounding per-round [N, M] work that the phase
         sub-round doesn't have. Callers that profile a win opt in.
     """
-    import os
-
     m = msgs.capacity
     pub_valid = jnp.asarray(pub_valid)
     accept, ignored = decode_verdicts(pub_valid)
@@ -1026,10 +1026,7 @@ def allocate_publishes(
     sidx = jnp.where(is_pub, slots, m)
 
     n_peers = dlv.have.shape[0]
-    env = os.environ.get("PUBSUB_PUB_SCATTER")
-    if env is not None:
-        scatter_form = env == "1"
-    elif scatter_form is None:
+    if scatter_form is None:
         scatter_form = False
 
     # clear recycled slots: bit columns in have/fwd/fe, rows in first_round

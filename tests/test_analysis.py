@@ -3,6 +3,7 @@ FIRE on a deliberately broken snippet/config (negative), and the repo
 itself must pass clean (positive) — so `make analyze` is demonstrably a
 live gate, not a rubber stamp. docs/DESIGN.md §9."""
 
+import glob
 import json
 import os
 import textwrap
@@ -549,8 +550,8 @@ def test_narrow_dtype_rule_negatives():
 
 def test_narrow_dtype_rule_on_repo_source():
     """The in-tree device scope matches the committed manifest exactly
-    (the int8 delivery-plane pack in ops/pallas_delivery.py), and a
-    missing artifact is itself a violation, not a silent pass."""
+    (no sub-i32 cast today), and a missing artifact is itself a
+    violation, not a silent pass."""
     assert simlint._rule_narrow_dtype(PKG) == []
     vs = simlint._rule_narrow_dtype(os.path.join(PKG, "analysis"))
     assert vs and "RANGE_AUDIT.json is missing" in vs[0].msg
@@ -584,6 +585,58 @@ def test_repo_lints_clean():
     the donated-reuse rule — simlint.run covers both)."""
     kept, _allowed = simlint.run(PKG)
     assert kept == [], "\n".join(v.format() for v in kept)
+
+
+def _environment_reads(src: str) -> list:
+    """Lines of ``os.environ`` / ``os.getenv`` (however imported)."""
+    import ast
+
+    hits = []
+    for node in ast.walk(ast.parse(src)):
+        if (isinstance(node, ast.Attribute)
+                and node.attr in ("environ", "getenv")):
+            hits.append(node.lineno)
+        elif (isinstance(node, ast.ImportFrom) and node.module == "os"
+              and any(a.name in ("environ", "getenv") for a in node.names)):
+            hits.append(node.lineno)
+    return hits
+
+
+@pytest.mark.parametrize("part", ["models", "ops", "score", "chaos",
+                                  "routers", "state.py"])
+def test_engines_read_no_environment(part):
+    """What an engine traces is a function of its arguments: no process-
+    wide switch under the traced code (a build that differs by the
+    caller's shell is a second, untested program)."""
+    assert _environment_reads("import os\nx = os.environ.get('A')") == [2]
+    assert _environment_reads("from os import getenv") == [1]
+    path = os.path.join(PKG, part)
+    files = ([path] if path.endswith(".py") else
+             glob.glob(os.path.join(path, "**", "*.py"), recursive=True))
+    assert files
+    found = {}
+    for f in files:
+        with open(f) as fh:
+            lines = _environment_reads(fh.read())
+        if lines:
+            found[os.path.relpath(f, PKG)] = lines
+    assert found == {}
+
+
+def test_package_does_not_import_pallas():
+    """One data plane: the engines' import chain loads no Pallas (every
+    cell's set-up pays what the package imports)."""
+    import subprocess
+    import sys
+
+    code = ("import sys; "
+            "import go_libp2p_pubsub_tpu.models.gossipsub_phase; "
+            "print(sorted(m for m in sys.modules if 'pallas' in m))")
+    out = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, check=True,
+        capture_output=True, text=True,
+        env=dict(os.environ, JAX_PLATFORMS="cpu")).stdout
+    assert out.strip().splitlines()[-1] == "[]"
 
 
 # ---------------------------------------------------------------------------

@@ -1,13 +1,9 @@
 """What the chip's compiler says, asked without the chip: the main
-path's phase step and each Pallas kernel are lowered at the bench's real
-size against a DESCRIBED ``v5e:2x2`` device (the TPU compiler is
-installed; nothing is attached and nothing runs). A compile that passes
-here is not a chip run — it guards every later PR against a program the
-chip would refuse, at no chip time.
-
-A kernel either compiles, or its refusal by this libtpu is pinned with
-the compiler's message so ROADMAP queue 3 item 3 can delete it on
-evidence.
+path's phase step is lowered at the bench's real size against a
+DESCRIBED ``v5e:2x2`` device (the TPU compiler is installed; nothing is
+attached and nothing runs). A compile that passes here is not a chip
+run — it guards every later PR against a program the chip would refuse,
+at no chip time.
 
 EVERY test that loads the TPU library lives in this one file — these
 compiles, and the PJRT bridge tests below that open the same library —
@@ -18,8 +14,6 @@ of its lock under workers.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -76,9 +70,8 @@ def _on(sharding, tree):
 
 
 def test_default_phase_step_compiles_at_bench_size(one_chip, bench_prng):
-    """The program bench.py's scanned window is made of: pure XLA (the
-    Pallas switches are off by default), accepted by the v5e compiler,
-    inside one chip's HBM."""
+    """The program bench.py's scanned window is made of: pure XLA,
+    accepted by the v5e compiler, inside one chip's HBM."""
     from go_libp2p_pubsub_tpu.perf.sweep import PUBS_PER_ROUND, bench_cell
 
     r = 8
@@ -95,118 +88,6 @@ def test_default_phase_step_compiles_at_bench_size(one_chip, bench_prng):
                 + ma.generated_code_size_in_bytes)
     assert resident < V5E_HBM_BYTES, ma
     assert "tpu_custom_call" not in compiled.as_text()
-
-
-@pytest.fixture(scope="module")
-def fused_calls(bench_prng):
-    """``{name: (jitted kernel, args, kwargs)}`` exactly as the per-round
-    bench step calls ops/fused_round.py at N=100,000 with PUBSUB_FUSED=1
-    (block = pick_block(100_000, band_off)), captured at trace time."""
-    from go_libp2p_pubsub_tpu.ops import fused_round as fr
-    from go_libp2p_pubsub_tpu.perf.sweep import PUBS_PER_ROUND, build_bench
-
-    mp = pytest.MonkeyPatch()
-    calls = {}
-
-    def capture(name):
-        kernel = getattr(fr, name)
-
-        def wrapped(*args, **kwargs):
-            calls[name] = (kernel, args, kwargs)
-            return kernel(*args, **kwargs)
-
-        mp.setattr(fr, name, wrapped)
-
-    try:
-        mp.setenv("PUBSUB_FUSED", "1")
-        st, step, _, _ = build_bench(
-            BENCH_N, BENCH_M, heartbeat_every=1, rounds_per_phase=1,
-            devices=jax.devices()[:1])
-        capture("edge_exchange")
-        capture("fused_delivery")
-        pubs = (jnp.zeros((PUBS_PER_ROUND,), jnp.int32),
-                jnp.zeros((PUBS_PER_ROUND,), jnp.int32),
-                jnp.ones((PUBS_PER_ROUND,), bool))
-        jax.eval_shape(step, st, *pubs)
-    finally:
-        mp.undo()
-    assert set(calls) == {"edge_exchange", "fused_delivery"}
-    return calls
-
-
-@pytest.mark.parametrize("name", ["edge_exchange", "fused_delivery"])
-def test_fused_round_kernel_compiles(one_chip, fused_calls, name):
-    """ops/fused_round.py: Mosaic accepts both kernels at the bench shape
-    (the step derives interpret mode from the backend, so on a TPU the
-    switch runs exactly this compiled form)."""
-    kernel, args, kwargs = fused_calls[name]
-    is_array = lambda v: hasattr(v, "shape") and hasattr(v, "dtype")  # noqa: E731
-    static = {k: v for k, v in kwargs.items() if not is_array(v)}
-    traced = {k: v for k, v in kwargs.items() if is_array(v)}
-    assert kwargs["block"] == 400 and static.pop("interpret") is True
-    compiled = kernel.lower(
-        *[_on(one_chip, a) if is_array(a) else a for a in args],
-        **_on(one_chip, traced), **static, interpret=False,
-    ).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
-
-
-def test_pallas_delivery_refusal_is_pinned(one_chip):
-    """ops/pallas_delivery.py at block 2000: Mosaic refuses the
-    word<->bit shape cast the packed layout needs (not a local repair —
-    the cast IS the kernel's design)."""
-    from go_libp2p_pubsub_tpu import graph
-    from go_libp2p_pubsub_tpu.ops import pallas_delivery as pd
-    from go_libp2p_pubsub_tpu.state import Net
-
-    n, m, k, w = BENCH_N, BENCH_M, 16, 2
-    net = Net.build(graph.ring_lattice(n, d=8), graph.subscribe_all(n, 1))
-    u32, i32 = jnp.uint32, jnp.int32
-    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    args = (s((n, w), u32), s((n, m), jnp.int8), s((n, k * w), u32),
-            s((n, w), u32), s((n, m), i32), s((m,), i32), s((w,), u32),
-            s((), i32))
-    with pytest.raises(Exception, match="unsupported shape cast") as ei:
-        pd.delivery_round_banded.lower(
-            *args, block=2000, m=m, offsets=net.band_off,
-            revs=net.band_rev, interpret=False).compile()
-    assert "infer-vector-layout" in str(ei.value)
-    assert "vector<2000x64xi32>) -> vector<2000x2x32xi32>" in str(ei.value)
-
-
-@pytest.mark.parametrize("block, block_rows, refusal", [
-    # the blocks models/common.py picks for this net (PUBSUB_PALLAS_BLOCK
-    # default 2000): rank-1 index blocks must be multiples of 128
-    (2000, 2000, "rank 1 block shapes"),
-    # an aligned edge block (N=100,000 has no 128-multiple divisor, so
-    # the row phase spans the whole array): the whole-array gather
-    # sources sit in pl.ANY, which a TPU kernel cannot load from
-    (2560, BENCH_N, "Loads are only allowed on VMEM and SMEM references"),
-])
-def test_pallas_csr_refusal_is_pinned(one_chip, block, block_rows, refusal):
-    """ops/pallas_csr.py csr_delivery at the E of a CSR bench net: the
-    TPU lowering refuses it (past both, the unstructured in-VMEM gather
-    is refused too — "Shape mismatch in input, indices and output")."""
-    from go_libp2p_pubsub_tpu import graph
-    from go_libp2p_pubsub_tpu.ops import pallas_csr as pcsr
-    from go_libp2p_pubsub_tpu.state import Net
-
-    n, m, w = BENCH_N, BENCH_M, 2
-    net = Net.build(graph.ring_lattice(n, d=8), graph.subscribe_all(n, 1),
-                    edge_layout="csr", fused=True)
-    e, cap = net.n_edges, net.max_degree
-    assert e == 16 * n and pcsr.pallas_csr_supported(e, block, cap)
-    u32, i32 = jnp.uint32, jnp.int32
-    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    args = (s((n, w), u32), s((e, w), u32), s((e, w), u32), s((n, w), u32),
-            s((n, w), u32), s((n, m), i32), s((1, w), u32), s((), i32),
-            s((e,), i32), s((e,), i32), s((e,), i32), s((e,), bool),
-            s((n,), i32), s((n,), bool))
-    kernel = jax.jit(functools.partial(
-        pcsr.csr_delivery, cap=cap, block=block, block_rows=block_rows,
-        interpret=False))
-    with pytest.raises(Exception, match=refusal):
-        kernel.lower(*args).compile()
 
 
 # ---------------------------------------------------------------------------

@@ -3,9 +3,8 @@ function at toy size on the CPU (the four default phases on one device,
 the ``--chips 4`` comparison on four virtual devices), the script refuses
 to pass without a TPU, and a failed check fails the run. Plus the
 fallbacks this path must never grow back: N-halving, an "error" line with
-exit 0, an off-chip bench, interpret-mode kernels on a TPU backend, a
-silent one-device placement, a cache directory set over the standard
-variable.
+exit 0, an off-chip bench, a silent one-device placement, a cache
+directory set over the standard variable.
 
 Nothing here is a chip run: sizes are toy, the backend is the CPU.
 """
@@ -28,7 +27,6 @@ sys.path.insert(0, ROOT)
 import chip_smoke as cs  # noqa: E402
 
 from go_libp2p_pubsub_tpu import compile_cache  # noqa: E402
-from go_libp2p_pubsub_tpu.models import common  # noqa: E402
 from go_libp2p_pubsub_tpu.perf import sweep  # noqa: E402
 
 
@@ -250,35 +248,3 @@ def test_cache_dir_is_left_alone_when_the_variable_is_set(monkeypatch):
                 == os.path.join(ROOT, ".jax_cache"))
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-# ---------------------------------------------------------------------------
-# Pallas switches: compiled on a TPU backend, and never silently dropped
-
-
-def test_no_interpret_mode_kernel_on_a_tpu_backend(monkeypatch):
-    assert common._pallas_interpret() is True      # this CPU backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert common._pallas_interpret() is False
-
-
-@pytest.mark.parametrize("switch, layout, reason", [
-    ("USE_PALLAS", "dense", "PUBSUB_PALLAS=1 but the banded kernel"),
-    ("USE_PALLAS_CSR", "csr", "PUBSUB_PALLAS_CSR=1 but the CSR kernels"),
-])
-def test_unusable_kernel_raises_instead_of_taking_the_xla_path(
-        monkeypatch, switch, layout, reason):
-    from go_libp2p_pubsub_tpu import graph
-    from go_libp2p_pubsub_tpu.models.floodsub import floodsub_step
-    from go_libp2p_pubsub_tpu.state import Net, SimState
-
-    n = 64
-    net = Net.build(graph.ring_lattice(n, d=2), graph.subscribe_all(n, 1),
-                    edge_layout=layout, fused=layout == "csr")
-    st = SimState.init(n, 64, seed=0, k=net.max_degree, n_edges=net.n_edges)
-    monkeypatch.setattr(common, switch, True)
-    monkeypatch.setenv("PUBSUB_PALLAS_BLOCK", "3")   # tiles nothing here
-    pubs = (jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
-            jnp.ones((2,), bool))
-    with pytest.raises(ValueError, match=reason):
-        floodsub_step.__wrapped__(net, st, *pubs)

@@ -38,6 +38,7 @@ import numpy as np
 import pytest
 
 from go_libp2p_pubsub_tpu import checkpoint, driver, graph
+from go_libp2p_pubsub_tpu import topo as topo_mod
 from go_libp2p_pubsub_tpu.chaos.faults import ChaosConfig
 from go_libp2p_pubsub_tpu.config import (
     GossipSubParams,
@@ -211,9 +212,18 @@ def _run_floodsub(net, rounds=6):
     return canon(net, st)
 
 
-@pytest.mark.parametrize("topo_kind", ["ragged", "banded"])
+def powerlaw_topo(n=N):
+    """A heavy-tailed degree draw: most rows far under the K=16 cap."""
+    el = topo_mod.powerlaw(n, exponent=2.2, d_min=2, max_degree=16, seed=0)
+    return topo_mod.build_nets(el, graph.subscribe_all(n, 1),
+                               max_degree=16)[0]
+
+
+@pytest.mark.parametrize("topo_kind", ["ragged", "banded", "powerlaw"])
 def test_floodsub_parity(topo_kind):
-    topo = ragged_topo() if topo_kind == "ragged" else graph.ring_lattice(N, d=4)
+    topo = {"ragged": ragged_topo,
+            "banded": lambda: graph.ring_lattice(N, d=4),
+            "powerlaw": powerlaw_topo}[topo_kind]()
     subs = graph.subscribe_all(N, 1)
     net_d = Net.build(topo, subs)
     net_c = Net.build(topo, subs, edge_layout="csr")

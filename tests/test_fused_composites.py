@@ -1,30 +1,20 @@
-"""Fused CSR plane (round 21, docs/DESIGN.md §21): the Pallas kernels
-of ops/pallas_csr.py and the restructured XLA composite behind
-``cfg.fused``.
+"""The fused composites (round 21, docs/DESIGN.md §21): the XLA forms
+behind ``cfg.fused`` / ``Net.fused``.
 
 Pins the §21 contracts:
 
-  * ``csr_delivery`` (three pallas_calls: edge phase / row phase / edge
-    commit) is BIT-EXACT vs the XLA composite chain
-    (peer/edge/owner gathers + ops/csr.segment_or_scan + the
-    finish_delivery_flat commit algebra) in interpret mode — on ragged,
-    banded and power-law topologies, chaos link-deny masks on and off;
-  * ``select_topk_pallas`` equals the rank_desc pairwise form
-    (including the traced masked-width k) bit for bit;
+  * what ``models/common.delivery_round`` commits on a CSR-resident
+    ``fused=True`` net equals a piecewise reference of the flat chain
+    (peer/edge/owner gathers + ops/csr.segment_or_scan + the commit
+    algebra) on ragged, banded and power-law topologies, link-deny
+    masks on and off;
   * the fused composite pieces are exact recompositions: the
     capacity-bounded segmented scan equals the log2(E)
     associative_scan form on random ragged segments, and the
     sort-composite rank equals the pairwise count — ties, signed
     zeros, masks, keyed and unkeyed;
   * fused-vs-unfused FULL STATE TREES are bit-exact for all four
-    engines (gossipsub, gossipsub_phase r∈{1,8}, floodsub, randomsub);
-  * the PUBSUB_PALLAS_CSR hook in models/common.delivery_round returns
-    the same (Delivery, RoundInfo) as the composite path.
-
-The Pallas kernels run in interpret mode only (the Mosaic caveat —
-see the module docstring of ops/pallas_csr.py); the composite is the
-shipping TPU form and the one `make cost-audit`'s fusion contract
-prices.
+    engines (gossipsub, gossipsub_phase r∈{1,8}, floodsub, randomsub).
 """
 
 from __future__ import annotations
@@ -41,9 +31,8 @@ from go_libp2p_pubsub_tpu.models.floodsub import floodsub_step
 from go_libp2p_pubsub_tpu.models.randomsub import make_randomsub_step
 from go_libp2p_pubsub_tpu.ops import bitset
 from go_libp2p_pubsub_tpu.ops import csr as csrops
-from go_libp2p_pubsub_tpu.ops import pallas_csr as pcsr
 from go_libp2p_pubsub_tpu.ops import select
-from go_libp2p_pubsub_tpu.state import Net, SimState
+from go_libp2p_pubsub_tpu.state import Delivery, MsgTable, Net, SimState
 
 M = 32
 W = bitset.n_words(M)
@@ -72,8 +61,8 @@ def _net(kind: str) -> Net:
 
 
 def _rand_planes(net: Net, rng):
-    """Arbitrary word planes — the kernels are pure bit algebra, so
-    parity must hold for ANY inputs, not just reachable states."""
+    """Arbitrary word planes — the commit is pure bit algebra, so it
+    must hold for ANY inputs, not just reachable states."""
     n, k = net.nbr.shape
     e = net.n_edges
     u32 = lambda shape: jnp.asarray(
@@ -91,8 +80,8 @@ def _rand_planes(net: Net, rng):
 
 
 def _composite_reference(net: Net, p: dict, tick, link_ok_e=None):
-    """The exact XLA chain the kernels replace, piecewise (the same ops
-    models/common.delivery_round + finish_delivery_flat compose)."""
+    """The flat chain, piecewise (the ops models/common.delivery_round
+    + finish_delivery_flat compose)."""
     fwd_e = net.peer_gather_flat(p["fwd"])
     echo_e = net.edge_gather_flat(p["fe_e"])
     mask_e = net.pack_edges(p["edge_mask"])
@@ -123,61 +112,40 @@ def _composite_reference(net: Net, p: dict, tick, link_ok_e=None):
     }
 
 
-def _blocks(net: Net):
-    e, cap = net.n_edges, net.max_degree
-    block = common._pick_div(e, cap, 256)
-    block_rows = common._pick_div(net.n_peers, 1, 256)
-    assert block is not None and block_rows is not None
-    assert pcsr.pallas_csr_supported(e, block, cap), (e, block, cap)
-    return block, block_rows
-
-
 @pytest.mark.parametrize("kind", ["ragged", "banded", "powerlaw"])
 @pytest.mark.parametrize("chaos", [False, True])
-def test_csr_delivery_kernel_bit_exact(kind, chaos):
+def test_csr_delivery_commit_matches_reference(kind, chaos):
     net = _net(kind)
+    n, k = net.nbr.shape
     rng = np.random.default_rng(
         {"ragged": 1, "banded": 2, "powerlaw": 3}[kind] * 2 + int(chaos))
-    block, block_rows = _blocks(net)
     for trial in range(2):
         p = _rand_planes(net, rng)
-        link_ok = (jnp.asarray(rng.random(net.n_edges) < 0.7)
-                   if chaos else None)
+        # not-mine is the one plane delivery_round derives itself: one
+        # origin (or none) a slot, read back through the same helper
+        origin = jnp.asarray(rng.integers(-1, n, size=(M,)), jnp.int32)
+        msgs = MsgTable.empty(M).replace(origin=origin, valid=p["valid"])
+        p["not_mine"] = ~common.origin_msg_words(net, msgs)
+        edge_mask, link_ok_e = p["edge_mask"], None
+        if chaos:
+            # a link-deny plane enters the round as an AND into the
+            # [N, K, W] edge mask; the reference takes it flat
+            link_ok = jnp.asarray(rng.random((n, k)) < 0.7)
+            link_ok_e = net.pack_edges(link_ok)
+            edge_mask = jnp.where(link_ok[:, :, None], edge_mask,
+                                  jnp.uint32(0))
         tick = jnp.int32(7 + trial)
-        want = _composite_reference(net, p, tick, link_ok)
-        got = pcsr.csr_delivery(
-            p["fwd"], p["fe_e"], net.pack_edges(p["edge_mask"]),
-            p["not_mine"], p["have"], p["first_round"],
-            bitset.pack(p["valid"])[None, :], tick,
-            net.csr_col, net.csr_row, net.csr_eperm, net.csr_seg_start,
-            net.csr_row_last, net.csr_row_nonempty,
-            cap=net.max_degree, block=block, block_rows=block_rows,
-            interpret=True, link_ok_e=link_ok,
-        )
-        for key in want:
+        want = _composite_reference(net, p, tick, link_ok_e)
+        dlv = Delivery(have=p["have"], fwd=p["fwd"],
+                       first_round=p["first_round"], fe_words=p["fe_e"])
+        got, info = common.delivery_round(net, msgs, dlv, edge_mask, tick)
+        for key, arr in [("have", got.have), ("fwd", got.fwd),
+                         ("first_round", got.first_round),
+                         ("fe", got.fe_words), ("trans_e", info.trans),
+                         ("new", info.new_words)]:
             np.testing.assert_array_equal(
-                np.asarray(got[key]), np.asarray(want[key]),
+                np.asarray(arr), np.asarray(want[key]),
                 err_msg=f"{kind} chaos={chaos} trial={trial} {key}")
-
-
-def test_select_topk_pallas_bit_exact():
-    rng = np.random.default_rng(5)
-    r, k = 64, 16
-    for trial in range(3):
-        # quantized values force ties; random mask; per-row traced k
-        values = jnp.asarray(
-            rng.integers(0, 4, size=(r, k)).astype(np.float32))
-        mask = jnp.asarray(rng.random((r, k)) < 0.7)
-        noise = jnp.asarray(
-            rng.integers(0, 3, size=(r, k)).astype(np.float32) / 2.0)
-        k_arr = jnp.asarray(rng.integers(0, k + 1, size=(r,)), jnp.int32)
-        primary = jnp.where(mask, values, jnp.float32(-jnp.inf))
-        rank = select._rank_desc_pairwise(primary, noise)
-        want = (rank < k_arr[:, None]) & mask
-        got = pcsr.select_topk_pallas(values, mask, k_arr, noise,
-                                      block=16, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
-                                      err_msg=f"trial={trial}")
 
 
 # ---------------------------------------------------------------------------
@@ -241,44 +209,6 @@ def test_selection_kernels_fused_parity():
          select.masked_width_random(key, mask, width, 16, fused=True)),
     ]:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# ---------------------------------------------------------------------------
-# the delivery_round hook (PUBSUB_PALLAS_CSR=1 on a fused Net)
-
-
-def test_delivery_round_pallas_csr_hook(monkeypatch):
-    net = _net("banded")
-    st = SimState.init(net.n_peers, M, seed=0, k=net.max_degree,
-                       n_edges=net.n_edges)
-    rng = np.random.default_rng(23)
-
-    def run(use_pallas):
-        monkeypatch.setattr(common, "USE_PALLAS_CSR", use_pallas)
-        s = st
-        out = []
-        for t in range(3):
-            po = jnp.asarray(rng.integers(0, net.n_peers, size=(2,)),
-                             jnp.int32)
-            # fresh rng per path would desync draws — reseed instead
-            raw = floodsub_step.__wrapped__
-            s2 = raw(net, s, po, jnp.zeros((2,), jnp.int32),
-                     jnp.ones((2,), bool))
-            out.append(s2)
-            s = s2
-        return out
-
-    rng = np.random.default_rng(23)
-    a = run(False)
-    rng = np.random.default_rng(23)
-    b = run(True)
-    for sa, sb in zip(a, b):
-        la, lb = jtu.tree_leaves(sa), jtu.tree_leaves(sb)
-        assert len(la) == len(lb)
-        for x, y in zip(la, lb):
-            if jnp.issubdtype(x.dtype, jax.dtypes.prng_key):
-                x, y = jax.random.key_data(x), jax.random.key_data(y)
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 # ---------------------------------------------------------------------------

@@ -279,3 +279,24 @@ def test_static_heartbeat_matches_cond():
         if jnp.issubdtype(a.dtype, jax.dtypes.prng_key):
             a, b = jax.random.key_data(a), jax.random.key_data(b)
         assert (np.asarray(a) == np.asarray(b)).all()
+
+
+@pytest.mark.parametrize("cap", [0, 1, 2, 3, 5])
+def test_served_capped(cap):
+    """The IWANT retransmission cap on the 2-bit served counter
+    (served_hi:served_lo, saturating at 3): a slot is capped once its
+    count reaches gossip_retransmission, and a cap beyond the counter's
+    range clamps to 3."""
+    from go_libp2p_pubsub_tpu.models.gossipsub import _served_capped
+
+    rng = np.random.default_rng(cap)
+    lo = rng.integers(0, 1 << 32, size=(5, 3, 2), dtype=np.uint32)
+    hi = rng.integers(0, 1 << 32, size=(5, 3, 2), dtype=np.uint32)
+    cfg = dataclasses.replace(GossipSubConfig.build(),
+                              gossip_retransmission=cap)
+    got = bitset.unpack(
+        _served_capped(cfg, jnp.asarray(lo), jnp.asarray(hi)), 64)
+    bit = lambda w: (w[..., None] >> np.arange(32, dtype=np.uint32)) & 1
+    count = (bit(lo) + 2 * bit(hi)).reshape(5, 3, 64)
+    assert np.array_equal(np.asarray(got), count >= min(cap, 3))
+    assert {0, 1, 2, 3} == set(np.unique(count))

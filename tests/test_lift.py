@@ -89,14 +89,14 @@ def test_traced_compare_classifies_value():
     assert kinds(sites, "GossipSubConfig.gossip_threshold") == ["value"]
 
 
-def test_fused_gate_classifies_gated():
+def test_no_if_test_excuses_a_shape_site():
     sites = sites_of("""
         def step(cfg, st, use_fused):
             if use_fused:
                 return st * float(cfg.gossip_threshold)
             return st
     """)
-    assert kinds(sites, "GossipSubConfig.gossip_threshold") == ["gated"]
+    assert kinds(sites, "GossipSubConfig.gossip_threshold") == ["shape"]
 
 
 def test_tp_subscript_maps_to_topic_field():
@@ -242,7 +242,7 @@ def test_verdict_shape_wins_over_value():
     assert v["verdict"] == "SHAPE"
 
 
-def test_verdict_gated_does_not_block():
+def test_verdict_shape_in_one_arm_blocks():
     sites = sites_of("""
         def step(cfg, st, use_fused):
             if use_fused:
@@ -250,7 +250,7 @@ def test_verdict_gated_does_not_block():
             return st.scores >= cfg.gossip_threshold
     """)
     v = lift.field_verdicts(sites)["GossipSubConfig.gossip_threshold"]
-    assert v["verdict"] == "VALUE"
+    assert v["verdict"] == "SHAPE"
 
 
 def test_declared_shape_forced():

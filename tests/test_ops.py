@@ -190,17 +190,22 @@ def test_lowest_bit_matches_naive():
             assert not has[i] and idx[i] == 0, i
 
 
-def test_allocate_publishes_scatter_plane_equivalence(monkeypatch):
-    """allocate_publishes has two trace-time forms (N-gated: plane
-    selects below ~20k peers, column/word scatters above — measured
-    crossover on the real chip, see state.py docstring). They must be
-    bit-identical; this drives a full gossipsub sim under each via the
-    PUBSUB_PUB_SCATTER override and compares every state plane."""
+@pytest.mark.parametrize("wire_coalesced", [True, False])
+def test_allocate_publishes_scatter_plane_equivalence(monkeypatch,
+                                                      wire_coalesced):
+    """The publish stamp has two trace-time forms (plane selects, or
+    column/word scatters from state.SCATTER_FORM_MIN_PEERS peers on in
+    the phase engine — measured crossover on the real chip, see
+    allocate_publishes' docstring). They must be bit-identical; this
+    drives a full phase-engine sim under each by moving the crossover,
+    through both callers (the head plan's apply_to_delivery on the
+    stacked wire, allocate_publishes on the per-plane one), and compares
+    every state plane."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from go_libp2p_pubsub_tpu import graph
+    from go_libp2p_pubsub_tpu import graph, state
     from go_libp2p_pubsub_tpu.config import (
         GossipSubParams,
         PeerScoreThresholds,
@@ -208,32 +213,35 @@ def test_allocate_publishes_scatter_plane_equivalence(monkeypatch):
     from go_libp2p_pubsub_tpu.models.gossipsub import (
         GossipSubConfig,
         GossipSubState,
-        make_gossipsub_step,
+    )
+    from go_libp2p_pubsub_tpu.models.gossipsub_phase import (
+        make_gossipsub_phase_step,
     )
     from go_libp2p_pubsub_tpu.state import Net
 
-    n, m, rounds = 48, 32, 12
+    n, m, r, phases = 48, 32, 2, 5
     topo = graph.random_connect(n, 6, seed=9)
     subs = graph.subscribe_random(n, n_topics=2, topics_per_peer=2, seed=9)
     net = Net.build(topo, subs)
     cfg = GossipSubConfig.build(
-        GossipSubParams(), PeerScoreThresholds(), score_enabled=False
+        GossipSubParams(), PeerScoreThresholds(), score_enabled=False,
+        heartbeat_every=r, wire_coalesced=wire_coalesced,
     )
     rng = np.random.default_rng(9)
-    po = rng.integers(-1, n, size=(rounds, 4)).astype(np.int32)
-    pt = rng.integers(0, 2, size=(rounds, 4)).astype(np.int32)
-    pv = np.ones((rounds, 4), bool)
+    po = rng.integers(-1, n, size=(phases, r, 4)).astype(np.int32)
+    pt = rng.integers(0, 2, size=(phases, r, 4)).astype(np.int32)
+    pv = np.ones((phases, r, 4), bool)
 
-    def run(form):
-        monkeypatch.setenv("PUBSUB_PUB_SCATTER", form)
+    def run(min_peers):
+        monkeypatch.setattr(state, "SCATTER_FORM_MIN_PEERS", min_peers)
         st = GossipSubState.init(net, m, cfg, seed=9)
-        step = make_gossipsub_step(cfg, net)
-        for i in range(rounds):
+        step = make_gossipsub_phase_step(cfg, net, r)
+        for i in range(phases):
             st = step(st, jnp.asarray(po[i]), jnp.asarray(pt[i]),
-                      jnp.asarray(pv[i]))
+                      jnp.asarray(pv[i]), do_heartbeat=True)
         return st
 
-    sa, sb = run("0"), run("1")
+    sa, sb = run(n + 1), run(0)
     lb, _ = jax.tree_util.tree_flatten(sb)
     paths = jax.tree_util.tree_flatten_with_path(sa)[0]
     for (path, xa), xb in zip(paths, lb):
