@@ -15,9 +15,13 @@ static net by `Net.build` from the column histogram): the head columns
 that hold an edge move, as a short list scattered onto the columns
 themselves; where the full table is too large to be read cheaply the
 head's gather reads a COMPACT one (the head columns with the tail's
-present rows appended). Every slot comes out bit for bit as `flat[perm]`
-gives it. K0 = K is the one full gather. `_tally` counts the rows every
-gather set addresses and the rows of the table it reads, for the window's
+present rows appended). The plan addresses its table K-MAJOR over a
+lane-padded peer axis (slot (n, k) is row `k*Np + n`, `Np` = N rounded up
+to 128): the planes live N-minor on the chip, and only a minor axis that
+is a whole number of lanes merges into the gather's row axis without a
+copy loop. Every slot comes out bit for bit as `flat[perm]` gives it.
+K0 = K is the one full gather. `_tally` counts the rows every gather set
+addresses and the rows of the table it reads, for the window's
 `edge_rows_per_dispatch` and `edge_table_rows`.
 
 Topic-slot payloads ([N,S,K] per-slot bools) are moved across edges by
@@ -168,9 +172,10 @@ def edge_rows_per_dispatch(tally: list) -> float | None:
 
 def edge_table_rows(tally: list) -> int | None:
     """The largest table an edge gather of a ``tally_index_rows`` list
-    reads, in rows: N*K through the full ``edge_perm``, N*K0 + T where the
-    tiered gather's compact table engaged. ``None`` where no gather read
-    one (rolls, or every trace was a replay)."""
+    reads, in rows: N*K through the full ``edge_perm``; of a planned net
+    K*Np through the full table, K0*Np + T where the compact one engaged.
+    ``None`` where no gather read one (rolls, or every trace was a
+    replay)."""
     return max((val for kind, val in tally if kind == "table"), default=None)
 
 
@@ -286,15 +291,15 @@ def edge_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
 # (scripts/gather_law.py; PERF.md §6, PR 30). Slots are left-packed, so on
 # a graph of uneven degree the high columns hold next to no edge, yet every
 # slot of them is a row of the gather. So columns [0, K0) are gathered
-# whole (the head: N*K0 rows), and of columns [K0, K) only the T slots that
-# hold an edge move (the tail: one short gather, one scatter onto the
-# columns themselves).
+# whole (the head: K0*Np rows, Np = N rounded up to the lanes), and of
+# columns [K0, K) only the T slots that hold an edge move (the tail: T more
+# rows of the one gather, one scatter onto the columns themselves).
 #
 # Which table the head's gather reads is the plan's too (PR 32). Out of the
 # full N*K-row table a head row of 5 words costs half again what it costs
 # out of a table of its own once that table is large, so there the gather
 # reads a COMPACT table: the head columns as they are with the tail's T
-# present rows appended (one short gather), N*K0 + T rows, and its index
+# present rows appended (one short gather), K0*Np + T rows, and its index
 # plane, which lives in that space, ends in the T tail slots' sources: one
 # gather, no patch. A smaller full table costs no more than a compact one,
 # which then only adds its own steps; and a compact table that is itself
@@ -329,30 +334,43 @@ TIER_FIXED_NS = 15_000.0
 TABLE_CLIFF_ROWS = 3_500_000
 
 
+#: Lanes of a TPU tile. XLA merges ``[K, Np] -> [K*Np]`` under a tiled
+#: layout as a bitcast only where the minor axis is a whole number of them;
+#: else it goes through a 1-D buffer one word an iteration, and back (a
+#: quarter of the round at 100k peers, PERF.md §6, PR 34).
+LANES = 128
+
+
+def lane_padded(n: int) -> int:
+    """``n`` rounded up to a whole number of lanes: the plan's ``Np``."""
+    return -(-n // LANES) * LANES
+
+
 @struct.dataclass
 class Tiers:
     """The plan of a tiered edge gather (``plan_tiers``): index planes of
     one static graph, baked into the program like ``edge_perm``. K0 is
-    ``head.shape[1]``, T ``tail_dst.size``. ``head`` and ``tail_src``
-    address the table the big gather reads. ``compact``: the table
-    ``[N*K0 + T]`` of the head columns with the tail's present rows
-    appended, where slot (n, k) of a head column is row ``n*K0 + k`` and
-    the t-th present tail slot (row-major) is row ``N*K0 + t``. Else the
-    full slot table ``[N*K]``, row ``n*K + k``, as ``edge_perm`` has it."""
+    ``head.shape[0]``, Np ``head.shape[1]``, T ``tail_dst.size``. ``head``
+    and ``tail_src`` address the table the big gather reads, K-major over
+    the lane-padded peer axis. ``compact``: the table ``[K0*Np + T]`` of
+    the head columns with the tail's present rows appended, where slot
+    (n, k) of a head column is row ``k*Np + n`` and the t-th present tail
+    slot (K-major) is row ``K0*Np + t``. Else the full slot table
+    ``[K*Np]``, row ``k*Np + n``."""
 
-    head: jax.Array       # [N, K0] i32: where each head slot's partner
+    head: jax.Array       # [K0, Np] i32: where each head slot's partner
                           # sits in the table; an absent slot points at
-                          # itself
+                          # itself, a pad slot (n >= N) at row 0
     tail_src: jax.Array   # [T] i32: the same for the present tail slots
-    tail_dst: jax.Array   # [T] i32 into the tail's own n*(K-K0) + k-K0,
+    tail_dst: jax.Array   # [T] i32 into the tail's own (k-K0)*Np + n,
                           # ascending and duplicate-free: where the tail's
                           # rows go, and the rows a compact table appends
     compact: bool = struct.field(pytree_node=False, default=False)
 
     @property
     def rows(self) -> int:
-        """Rows one gather addresses by index: the head's and the tail's
-        gathers and the tail's scatter, and the T rows a compact table
+        """Rows one gather addresses by index: the big gather's (head and
+        tail), the tail's scatter, and the T rows a compact table
         appends."""
         return (self.head.size
                 + (3 if self.compact else 2) * self.tail_dst.size)
@@ -360,7 +378,7 @@ class Tiers:
     def table_rows(self, k: int) -> int:
         """Rows of the table the big gather of a ``[N, k]`` plane reads."""
         return (self.head.size + self.tail_dst.size if self.compact
-                else self.head.shape[0] * k)
+                else self.head.shape[1] * k)
 
 
 def compact_pays(full_rows, compact_rows):
@@ -382,8 +400,11 @@ def tier_cost_ns(col_fill, n: int) -> np.ndarray:
     k = col_fill.size
     tail = np.append(np.cumsum(col_fill[::-1])[::-1], 0)
     k0 = np.arange(k + 1)
-    # (at K0 = K the "compact" table is the full one: never the cheaper)
-    head_ns = np.where(compact_pays(n * k, n * k0 + tail),
+    # the tables at the size the plan gives them (at K0 = K the "compact"
+    # one is the full one: never the cheaper); the rows at the prices they
+    # were fitted at, before the peer axis was padded
+    n_pad = lane_padded(n)
+    head_ns = np.where(compact_pays(n_pad * k, n_pad * k0 + tail),
                        COMPACT_HEAD_ROW_NS, HEAD_ROW_NS)
     return (n * k0 * head_ns + tail * TAIL_ROW_NS
             + np.where(k0 < k, TIER_FIXED_NS, 0.0))
@@ -408,22 +429,28 @@ def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
         k0 = pick_k0(nbr_ok.sum(axis=0), n)
     if k0 >= k:
         return None
-    rows, cols = np.nonzero(nbr_ok[:, k0:])     # row-major: ascending
+    n_pad = lane_padded(n)
+    cols, rows = np.nonzero(nbr_ok[:, k0:].T)   # K-major: ascending
     if compact is None:
-        compact = compact_pays(n * k, n * k0 + rows.size)
-    src = perm
+        compact = compact_pays(n_pad * k, n_pad * k0 + rows.size)
+    # every slot's row in the table, by its full-space index n*K + k
+    addr = (np.arange(k, dtype=np.int32)[None, :] * n_pad
+            + np.arange(n, dtype=np.int32)[:, None])
     if compact:
-        # every slot's row in the compact table. An absent tail slot has
-        # none (-1) and nobody asks: a present slot's partner is present,
-        # an absent head slot points at itself.
-        addr = np.full((n, k), -1, np.int32)
-        addr[:, :k0] = np.arange(n * k0, dtype=np.int32).reshape(n, k0)
-        addr[rows, cols + k0] = n * k0 + np.arange(rows.size, dtype=np.int32)
-        src = addr.reshape(-1)[perm]
+        # an absent tail slot has no row (-1) and nobody asks: a present
+        # slot's partner is present, an absent head slot points at itself
+        addr[:, k0:] = -1
+        addr[rows, cols + k0] = n_pad * k0 + np.arange(rows.size,
+                                                       dtype=np.int32)
+    src = addr.reshape(-1)[perm]
+    # the pad slots point at row 0: a run of self-pointing rows is the
+    # dearest index pattern the law found
+    head = np.zeros((k0, n_pad), np.int32)
+    head[:, :n] = src[:, :k0].T
     # cast on the host: a device-side convert is one more program to compile
     i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
-    return Tiers(head=i32(src[:, :k0]), tail_src=i32(src[rows, cols + k0]),
-                 tail_dst=i32(rows * (k - k0) + cols), compact=bool(compact))
+    return Tiers(head=i32(head), tail_src=i32(src[rows, cols + k0]),
+                 tail_dst=i32(cols * n_pad + rows), compact=bool(compact))
 
 
 def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
@@ -431,29 +458,35 @@ def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
     ones included, addressing ``tiers.rows`` rows instead of N*K: an
     absent slot of the tail keeps its own entry, as its self-pointing row
     of ``edge_perm`` gives it. One parameter, ``tiers.compact``: which
-    table the head's gather reads."""
+    table the big gather reads."""
     n, k = x.shape[:2]
-    k0 = tiers.head.shape[1]
+    k0, n_pad = tiers.head.shape
     trail = x.shape[2:]
     _tally("edge", x, rows=tiers.rows, table_rows=tiers.table_rows(k))
+    # the [K, Np, ...] view: the planes live N-minor, so XLA keeps it
+    k_major = (1, 0) + tuple(range(2, x.ndim))
+    xt = jnp.pad(x, ((0, n_pad - n),) + ((0, 0),) * (x.ndim - 1)
+                 ).transpose(k_major)
+    tail = xt[k0:].reshape(((k - k0) * n_pad,) + trail)
+    onto_tail = lambda rows: tail.at[tiers.tail_dst].set(
+        rows, unique_indices=True, indices_are_sorted=True)
     if tiers.compact:
-        tail = x[:, k0:].reshape((n * (k - k0),) + trail)
         table = jnp.concatenate(
-            [x[:, :k0].reshape((n * k0,) + trail), tail[tiers.tail_dst]])
+            [xt[:k0].reshape((k0 * n_pad,) + trail), tail[tiers.tail_dst]])
         # ONE gather: the head block, then the T rows the tail slots take
         moved = table[
             jnp.concatenate([tiers.head.reshape(-1), tiers.tail_src])]
-        head = moved[:n * k0].reshape((n, k0) + trail)
-        tail = tail.at[tiers.tail_dst].set(
-            moved[n * k0:], unique_indices=True, indices_are_sorted=True)
+        tail = onto_tail(moved[k0 * n_pad:])
+        head = moved[:k0 * n_pad]
     else:
-        flat = x.reshape((n * k,) + trail)
-        head = flat[tiers.head.reshape(-1)].reshape((n, k0) + trail)
-        tail = x[:, k0:].reshape((n * (k - k0),) + trail).at[
-            tiers.tail_dst].set(flat[tiers.tail_src], unique_indices=True,
-                                indices_are_sorted=True)
-    return jnp.concatenate(
-        [head, tail.reshape((n, k - k0) + trail)], axis=1)
+        # the head's gather gives its block whole: no slice of a joined one
+        table = xt.reshape((k * n_pad,) + trail)
+        tail = onto_tail(table[tiers.tail_src])
+        head = table[tiers.head.reshape(-1)]
+    out = jnp.concatenate(
+        [head.reshape((k0, n_pad) + trail),
+         tail.reshape((k - k0, n_pad) + trail)], axis=0)
+    return out.transpose(k_major)[:n]
 
 
 def detect_banded(

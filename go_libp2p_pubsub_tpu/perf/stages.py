@@ -205,7 +205,7 @@ class TracedWindow:
     edge_rows_per_dispatch: float | None = None
     #: rows of the largest table an edge gather of the window reads
     #: (``ops/edges.edge_table_rows``): N*K through the full ``edge_perm``,
-    #: N*K0 + T where the tiered gather's compact table engaged; ``None``
+    #: K*Np or K0*Np + T through a plan's full or compact table; ``None``
     #: for rolls and replays
     edge_table_rows: int | None = None
     _stages: dict | None = None

@@ -2,8 +2,8 @@
 ``ops/edges.edge_permute`` costs on the chip, by rows, by table size, by
 row width and by K, on the benchmark's own random graphs; what a scatter
 of a short row list costs; and what a tiered gather made of them costs
-whole. PR 30's first chip call and PR 32's, and the source of the three
-constants ``ops/edges.pick_k0`` prices a graph with.
+whole. PR 30's first chip call, PR 32's and PR 34's, and the source of the
+three constants ``ops/edges.pick_k0`` prices a graph with.
 
     python scripts/gather_law.py [--n 100000] [--d 10] [--reps 7]
         [--graph random_connect|subnet_connect] [--k0 20 24 28]
@@ -31,11 +31,16 @@ Cases (``rows_out`` gathered from a ``rows_table``-row table, W words):
   scatter   the tail's rows scattered onto the tail columns
   tierA     head from its compact table + patches + tail, joined
   tierB     head from the full table + tail, joined (PR 30's engine)
-  compact   ``ops/edges.edge_permute_tiered`` with ``compact=True``: ONE
-            gather of N*K0 + T rows out of the compact table (the head
-            columns plus the tail's T present rows appended), the last T
-            scattered onto the tail columns, joined (PR 32's engine where
-            ``ops/edges.compact_pays``; ``tierB`` is its other form)
+  compact   ONE gather of N*K0 + T rows out of the compact table (the
+            head columns plus the tail's T present rows appended), the
+            last T scattered onto the tail columns, joined, rows N-major
+            (``n*K0 + k``): PR 32's engine where ``ops/edges.compact_pays``
+            (``tierB`` was its other form)
+  kmajor    ``ops/edges.edge_permute_tiered`` as the engine runs it since
+            PR 34, compact table: the same gather with its rows K-major
+            over the lane-padded peer axis (``k*Np + n``), so that no
+            per-word relayout loop stands around it
+  kmajorB   the same out of the full ``[K*Np]`` table (``compact=False``)
   whole     ``edge_permute`` as the engine calls it ([N, K, W] in and out)
 """
 
@@ -83,8 +88,8 @@ def pad_k(perm, nbr_ok, k_new):
 
 
 def tier_indices(perm, nbr_ok, k0):
-    """Every index plane a tiered variant needs, numpy; ``compact`` is
-    the engine's own plan."""
+    """Every index plane a tiered variant needs, numpy; ``kmajor`` and
+    ``kmajorB`` are the engine's own plans."""
     from go_libp2p_pubsub_tpu.ops import edges
 
     n, k = perm.shape
@@ -95,6 +100,12 @@ def tier_indices(perm, nbr_ok, k0):
     perm_head = np.where(in_head, pn[:, :k0] * k0 + pk[:, :k0], own)
     pr, pc = np.nonzero(head_ok & ~in_head)        # head slots, tail partner
     tr, tc = np.nonzero(nbr_ok[:, k0:])
+    # PR 32's compact table, N-major: head slot (n, k) is row n*K0 + k, the
+    # t-th present tail slot (row-major) row N*K0 + t
+    addr = np.full((n, k), -1, np.int32)
+    addr[:, :k0] = own
+    addr[tr, tc + k0] = n * k0 + np.arange(tr.size, dtype=np.int32)
+    compact = addr.reshape(-1)[perm]
     return {
         "perm_head": perm_head.astype(np.int32),
         "head_full": perm[:, :k0].astype(np.int32),
@@ -102,7 +113,10 @@ def tier_indices(perm, nbr_ok, k0):
         "patch_dst": (pr * k0 + pc).astype(np.int32),
         "tail_src": perm[tr, tc + k0].astype(np.int32),
         "tail_dst": (tr * (k - k0) + tc).astype(np.int32),
-        "compact": edges.plan_tiers(perm, nbr_ok, k0, compact=True),
+        "compact_head": compact[:, :k0],
+        "compact_tail_src": compact[tr, tc + k0],
+        "kmajor": edges.plan_tiers(perm, nbr_ok, k0, compact=True),
+        "kmajorB": edges.plan_tiers(perm, nbr_ok, k0, compact=False),
     }
 
 
@@ -246,9 +260,17 @@ def main(argv=None) -> int:
                 return jnp.concatenate(
                     [head.reshape(n, k0, w), tail.reshape(n, kt, w)], axis=1)
 
-            def compact(x, head, tail_src, tail_dst):
-                return edges.edge_permute_tiered(
-                    x, edges.Tiers(head, tail_src, tail_dst, compact=True))
+            def compact(x, head, tail_src, tail_dst, k0=k0, kt=kt):
+                tail = x[:, k0:].reshape(n * kt, w)
+                table = jnp.concatenate(
+                    [x[:, :k0].reshape(n * k0, w), tail[tail_dst]])
+                moved = table[jnp.concatenate([head.reshape(-1), tail_src])]
+                tail = tail.at[tail_dst].set(
+                    moved[n * k0:], unique_indices=True,
+                    indices_are_sorted=True)
+                return jnp.concatenate(
+                    [moved[:n * k0].reshape(n, k0, w),
+                     tail.reshape(n, kt, w)], axis=1)
 
             rows_a = n * k0 + 2 * (n_t + n_p)
             rows_b = n * k0 + 2 * n_t
@@ -258,10 +280,19 @@ def main(argv=None) -> int:
                   expect=want)
             timed("tierB", w, rows_b, n * k, tier_b, x, t["head_full"],
                   t["tail_src"], t["tail_dst"], k0=k0, tail=n_t, expect=want)
-            plan = t["compact"]
-            timed("compact", w, plan.rows, plan.table_rows(k), compact, x,
-                  plan.head, plan.tail_src, plan.tail_dst, k0=k0, tail=n_t,
-                  expect=want)
+            timed("compact", w, n * k0 + 3 * n_t, n * k0 + n_t, compact, x,
+                  t["compact_head"], t["compact_tail_src"], t["tail_dst"],
+                  k0=k0, tail=n_t, expect=want)
+            for case in ("kmajor", "kmajorB"):
+                plan = t[case]
+
+                def kmajor(x, head, tail_src, tail_dst, plan=plan):
+                    return edges.edge_permute_tiered(x, plan.replace(
+                        head=head, tail_src=tail_src, tail_dst=tail_dst))
+
+                timed(case, w, plan.rows, plan.table_rows(k), kmajor, x,
+                      plan.head, plan.tail_src, plan.tail_dst, k0=k0,
+                      tail=n_t, expect=want)
 
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:

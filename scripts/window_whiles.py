@@ -1,0 +1,117 @@
+"""The relayout loops of a cell's compiled window, counted without the
+chip: the window is built as ``benchmark/run.py`` builds it, compiled for
+a DESCRIBED ``v5e:2x2`` device (nothing attached, nothing runs: counts and
+texts, never a time) and its text searched for ``while`` ops.
+
+    JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \\
+        python scripts/window_whiles.py --workload random-100k.stepped \\
+        [--text /root/scratch/window.txt] [--n-peers 3000]
+
+XLA merges or splits ``(N, K) <-> N*K`` under a tiled layout through a
+1-D ``u32[...]{0:T(1024)}`` buffer, one word an iteration, where the minor
+of the two axes is not a whole number of lanes: those loops stand right
+before and after a general gather's fusion and were a quarter of the
+round at 100k peers (PERF.md §6, PR 34). One JSON line: the ``while`` ops,
+the ops inside their bodies, and those whose tuple carries such a buffer.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+FLAT_BUFFER = re.compile(r"u32\[(\d+)\]\{0:T\(1024\)")
+
+
+def whiles(text: str) -> list[dict]:
+    """Every ``while`` op of a compiled module's text: its name, the ops
+    of its body and the 1-D tiled u32 buffers its tuple carries (words)."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = 0
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            bodies[name] += 1
+    out = []
+    for line in text.splitlines():
+        op = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*?) while\(", line)
+        if op:
+            body = re.search(r"body=%?([\w.\-]+)", line)
+            out.append({
+                "name": op.group(1),
+                "body_ops": bodies.get(body.group(1)) if body else None,
+                "flat_u32_words": [int(w) for w in
+                                   FLAT_BUFFER.findall(op.group(2))],
+            })
+    return out
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--text", help="write the compiled text here")
+    ap.add_argument("--n-peers", type=int,
+                    help="a rehearsal at another size (small planes go "
+                         "through copies: no loop on either side)")
+    args = ap.parse_args(argv)
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.harness import manifest as mf
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    manifest = mf.load_manifest(ROOT)
+    cell = mf.find_cell(manifest, args.workload)
+    config = mf.load_config(manifest, cell["config"], ROOT)
+    mix = mf.load_traffic(cell["traffic"], ROOT)
+    built = mf.load_plugin("builders", config["builder"], ROOT).build(
+        config, 1, jax.devices()[:1], n_peers=args.n_peers)
+    window = built.make_window(int(mix["unroll_phases"]))
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    on = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    rounds = int(mix["segment_phases"]) * built.rounds_per_phase
+    pubs = (rounds, int(mix["pubs_per_round"]))
+    xs = (jax.ShapeDtypeStruct(pubs, jnp.int32),
+          jax.ShapeDtypeStruct(pubs, jnp.int32),
+          jax.ShapeDtypeStruct(pubs, bool))
+    t0 = time.perf_counter()
+    text = window.lower(on(jax.eval_shape(built.fresh)), *on(xs)
+                        ).compile().as_text()
+    found = whiles(text)
+    if args.text:
+        with open(args.text, "w") as f:
+            f.write(text)
+    flat = [w for w in found if w["flat_u32_words"]]
+    print(json.dumps({
+        "workload": cell["name"], "compiled_for": str(topo.devices[0]),
+        "compile_s": time.perf_counter() - t0, "text_bytes": len(text),
+        "whiles": len(found),
+        "while_body_ops": sum(w["body_ops"] or 0 for w in found),
+        "whiles_with_flat_u32_buffer": len(flat),
+        "flat_u32_words": sorted(
+            (w for f in flat for w in f["flat_u32_words"]), reverse=True),
+        "gathers": len(re.findall(r" gather\(", text)),
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

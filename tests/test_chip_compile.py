@@ -90,6 +90,39 @@ def test_default_phase_step_compiles_at_bench_size(one_chip, bench_prng):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def test_tiered_gather_compiles_without_a_relayout_loop(one_chip):
+    """The planned edge gather at size, composed and consumed as the data
+    round does it (planes of a ``[N, K]`` mask and ``[N, W]`` words in, an
+    OR over K out). XLA merges ``[K, Np] -> [K*Np]`` as a bitcast only
+    because the plan's peer axis is a whole number of lanes; the N-major
+    order (and a K-major one over N = 100,000 = 781.25 x 128) went through
+    a 1-D ``u32[...]{0:T(1024)}`` buffer, one word an iteration, four
+    ``while`` ops in this program and a quarter of the round on the chip
+    (PERF.md §6, PR 34). No tier-1 net is large enough to show them."""
+    from go_libp2p_pubsub_tpu import graph
+    from go_libp2p_pubsub_tpu.ops import edges
+
+    words = 5
+    topo = graph.random_connect(BENCH_N, 10, seed=1)
+    tiers = edges.plan_tiers(
+        edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok), topo.nbr_ok)
+    assert tiers is not None
+    assert tiers.head.shape[1] == edges.lane_padded(BENCH_N) == 100_096
+
+    def gathered(mask, payload):
+        x = mask[:, :, None] & payload[:, None, :]
+        got = edges.edge_permute_tiered(x, tiers)
+        return jax.lax.reduce(got & mask[:, :, None], jnp.uint32(0),
+                              jax.lax.bitwise_or, (1,))
+
+    text = jax.jit(gathered).lower(
+        jax.ShapeDtypeStruct(topo.nbr.shape, jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((BENCH_N, words), jnp.uint32, sharding=one_chip),
+    ).compile().as_text()
+    assert " gather(" in text and " scatter(" in text
+    assert " while(" not in text
+
+
 # ---------------------------------------------------------------------------
 # the PJRT C-API bridge (native/pjrt_bridge.cc) against the TPU library:
 # load a real PJRT plugin, compile StableHLO exported from jax, execute

@@ -324,7 +324,7 @@ def test_gossipsub_phase_tiered_gather_parity(compact):
     assert int(full.core.tick) == 3 * r
     for k0 in (k // 2, 2):
         tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
-        assert tiers.head.shape[1] == k0 < k and tiers.tail_dst.size
+        assert tiers.head.shape[0] == k0 < k and tiers.tail_dst.size
         assert tiers.compact is compact
         assert_trees_equal(full, run(tiers), f"phase tiers K0={k0}")
 

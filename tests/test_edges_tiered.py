@@ -2,9 +2,10 @@
 ``edge_permute_tiered``, planned by ``Net.build``): bit for bit the one
 full gather ``edge_permute(x, edge_perm)`` on every slot, absent ones
 included, on UNMASKED planes, through one gather out of a compact table
-(the head columns plus the tail's present rows); planned only where the
-code can see that it pays; counted by the rows it addresses and the rows
-of the table it reads."""
+(the head columns plus the tail's present rows), addressed K-major over
+the lane-padded peer axis (row ``k*Np + n``); planned only where the code
+can see that it pays; counted by the rows it addresses and the rows of
+the table it reads."""
 
 from __future__ import annotations
 
@@ -31,6 +32,9 @@ TOPOS = {
     "random-64-d3": lambda: graph.random_connect(64, d=3, seed=1),
     "random-300-d4": lambda: graph.random_connect(300, d=4, seed=2),
     "random-97-d10": lambda: graph.random_connect(97, d=10, seed=3),
+    # the peer axis against the lanes: no pad, one peer over, two tiles
+    "random-256-d4": lambda: graph.random_connect(256, d=4, seed=6),
+    "random-129-d3": lambda: graph.random_connect(129, d=3, seed=7),
     "star": lambda: graph.star(33),
     "isolated-peer": isolated,
     "tree": lambda: graph.tree(50, branching=3),
@@ -38,7 +42,8 @@ TOPOS = {
 
 
 def planes(shape, seed=0):
-    """Unmasked random planes over ``[N, K]``: u32 words, bools, f32."""
+    """Unmasked random planes over ``[N, K]``: u32 words (the data
+    round's 5, the control head's 14), bools, f32."""
     rng = np.random.default_rng(seed)
     return {
         "u32[N,K,5]": jnp.asarray(
@@ -47,6 +52,8 @@ def planes(shape, seed=0):
         "f32[N,K]": jnp.asarray(rng.standard_normal(shape), jnp.float32),
         "u32[N,K,2,3]": jnp.asarray(
             rng.integers(0, 2**32, size=shape + (2, 3), dtype=np.uint32)),
+        "u32[N,K,14]": jnp.asarray(
+            rng.integers(0, 2**32, size=shape + (14,), dtype=np.uint32)),
     }
 
 
@@ -64,7 +71,8 @@ def test_tiered_gather_equals_the_full_gather_on_every_slot(name):
     for k0 in every_k0(k):
         for compact in (True, False):
             tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
-            assert tiers.head.shape == (n, k0) and tiers.compact is compact
+            assert tiers.head.shape == (k0, edges.lane_padded(n))
+            assert tiers.compact is compact
             for what, x in planes((n, k), seed=k0).items():
                 want = edges.edge_permute(x, jnp.asarray(perm))
                 got = jax.jit(edges.edge_permute_tiered)(x, tiers)
@@ -76,32 +84,40 @@ def test_tiered_gather_equals_the_full_gather_on_every_slot(name):
 
 @pytest.mark.parametrize("name", sorted(TOPOS))
 def test_plan_lives_in_the_compact_table(name):
-    """Every index of the plan lies inside ``[0, N*K0 + T)``; the rows the
-    table appends are exactly the present tail slots, each once; and each
-    index is the compact address of the slot's partner in ``edge_perm``."""
+    """Every index of the plan lies inside ``[0, K0*Np + T)``; the rows the
+    table appends are exactly the present tail slots, each once, K-major;
+    each index is the compact address of the slot's partner in
+    ``edge_perm``; and a pad slot points at row 0."""
     topo = TOPOS[name]()
     perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
     n, k = perm.shape
+    n_pad = edges.lane_padded(n)
+    assert n_pad % 128 == 0 and n <= n_pad < n + 128
     for k0 in every_k0(k):
         tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=True)
         dst = np.asarray(tiers.tail_dst)
         t = dst.size
-        assert tiers.table_rows(k) == n * k0 + t
+        assert tiers.table_rows(k) == n_pad * k0 + t
         assert (np.diff(dst) > 0).all()         # sorted, unique
-        present = np.zeros(n * (k - k0), bool)
+        present = np.zeros((k - k0) * n_pad, bool)
         present[dst] = True
         np.testing.assert_array_equal(
-            present.reshape(n, k - k0), topo.nbr_ok[:, k0:])
+            present.reshape(k - k0, n_pad)[:, :n], topo.nbr_ok[:, k0:].T)
+        assert not present.reshape(k - k0, n_pad)[:, n:].any()
+        head = np.asarray(tiers.head)
+        assert not head[:, n:].any()            # the pad slots: row 0
         src = np.concatenate(
-            [np.asarray(tiers.head).reshape(-1), np.asarray(tiers.tail_src)])
-        assert src.size == n * k0 + t
-        assert src.min(initial=0) >= 0 and src.max(initial=0) < n * k0 + t
-        # the compact table's rows, named by the full-space slot they hold
-        slot_of_row = np.concatenate([
-            (np.arange(n)[:, None] * k + np.arange(k0)[None, :]).reshape(-1),
-            (dst // (k - k0)) * k + k0 + dst % (k - k0)])
-        asked = np.concatenate([perm[:, :k0].reshape(-1),
-                                perm.reshape(-1)[slot_of_row[n * k0:]]])
+            [head[:, :n].reshape(-1), np.asarray(tiers.tail_src)])
+        assert src.min(initial=0) >= 0 and src.max(initial=0) < n_pad * k0 + t
+        # the compact table's rows, named by the full-space slot (n*K + k)
+        # they hold; a pad row holds none
+        slot_of_row = np.full(n_pad * k0 + t, -1)
+        slot_of_row[(np.arange(k0)[:, None] * n_pad
+                     + np.arange(n)[None, :]).reshape(-1)] = (
+            np.arange(n)[None, :] * k + np.arange(k0)[:, None]).reshape(-1)
+        slot_of_row[n_pad * k0:] = (dst % n_pad) * k + k0 + dst // n_pad
+        asked = np.concatenate([perm[:, :k0].T.reshape(-1),
+                                perm.reshape(-1)[slot_of_row[n_pad * k0:]]])
         np.testing.assert_array_equal(slot_of_row[src], asked)
 
 
@@ -110,35 +126,38 @@ def test_plan_lives_in_the_compact_table(name):
 def test_rows_are_what_the_program_addresses(name, compact):
     """``Tiers.rows`` and ``table_rows`` against the traced program: the
     gathers' output rows plus the scatter's update rows, and the operand
-    of the big gather. The full-table form keeps its indices in
-    ``edge_perm``'s own space."""
+    of the big gather. The full-table form keeps ``edge_perm``'s indices,
+    re-addressed K-major."""
     topo = TOPOS[name]()
     perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
     n, k = perm.shape
+    n_pad = edges.lane_padded(n)
     x = planes((n, k))["u32[N,K,5]"]
     for k0 in every_k0(k):
         tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
         tail = int(topo.nbr_ok[:, k0:].sum())
-        assert tiers.rows == n * k0 + (3 if compact else 2) * tail
+        assert tiers.rows == n_pad * k0 + (3 if compact else 2) * tail
         jaxpr = jax.make_jaxpr(edges.edge_permute_tiered)(x, tiers)
         gathers = [e for e in jaxpr.eqns if e.primitive.name == "gather"]
         scatters = [e for e in jaxpr.eqns if e.primitive.name == "scatter"]
-        # (jax drops the full form's head gather of no rows at K0 = 0)
-        assert len(gathers) == 2 - (k0 == 0 and not compact)
         assert len(scatters) == 1
         addressed = (sum(e.outvars[0].aval.shape[0] for e in gathers)
                      + sum(e.invars[2].aval.shape[0] for e in scatters))
         assert addressed == tiers.rows
-        if compact:     # the table's appended rows first, then the big one
-            short, big = gathers
-            assert big.outvars[0].aval.shape[0] == n * k0 + tail
-            want = n * k0 + tail
-        else:           # the head's, then the tail's, out of one table
-            big, short = gathers[0], gathers[-1]
-            assert k0 == 0 or big.outvars[0].aval.shape[0] == n * k0
-            assert short.invars[0].aval.shape[0] == n * k
-            np.testing.assert_array_equal(tiers.head, perm[:, :k0])
-            want = n * k
+        if compact:     # the table's appended rows first, then ONE gather
+            short, big = gathers    # that moves the head and the tail
+            assert big.outvars[0].aval.shape[0] == n_pad * k0 + tail
+            want = n_pad * k0 + tail
+        else:           # the tail's, then the head's, out of one table
+            # (jax drops the head gather of no rows at K0 = 0)
+            assert len(gathers) == 2 - (k0 == 0)
+            short, big = gathers[0], gathers[-1]
+            assert k0 == 0 or big.outvars[0].aval.shape[0] == n_pad * k0
+            assert short.invars[0].aval.shape[0] == n_pad * k
+            np.testing.assert_array_equal(
+                np.asarray(tiers.head)[:, :n].T,
+                (perm % k * n_pad + perm // k)[:, :k0])
+            want = n_pad * k
         assert short.outvars[0].aval.shape[0] == tail
         assert big.invars[0].aval.shape[0] == tiers.table_rows(k) == want
 
@@ -149,7 +168,7 @@ def test_built_net_gathers_through_its_plan():
     topo = graph.random_connect(3000, d=4, seed=5)
     net = Net.build(topo, graph.subscribe_all(3000, 1))
     assert net.tiers is not None
-    k0 = net.tiers.head.shape[1]
+    k0 = net.tiers.head.shape[0]
     assert 0 < k0 < net.max_degree
     assert k0 == edges.pick_k0(topo.nbr_ok.sum(axis=0), 3000)
     for what, x in planes(topo.nbr.shape).items():
@@ -217,19 +236,21 @@ def test_tally_records_the_plan_rows():
         jax.eval_shape(net.edge_gather, x)
         jax.eval_shape(net.replace(tiers=None).edge_gather, x)
         jax.eval_shape(net.peer_gather, x[:, 0])
-    k0 = net.tiers.head.shape[1]
+    k0, n_pad = net.tiers.head.shape
+    assert n_pad == edges.lane_padded(n) > n
     tail = int(topo.nbr_ok[:, k0:].sum())
     assert not net.tiers.compact        # a table of 3000 * K rows is small
     compact = net.replace(tiers=edges.plan_tiers(
         np.asarray(net.edge_perm), topo.nbr_ok, compact=True))
     with edges.tally_index_rows(rows):
         jax.eval_shape(compact.edge_gather, x)
-    assert rows == [("edge", n * k0 + 2 * tail), ("table", n * k),
+    assert rows == [("edge", n_pad * k0 + 2 * tail), ("table", n_pad * k),
                     ("edge", n * k), ("table", n * k), ("peer", n * k),
-                    ("edge", n * k0 + 3 * tail), ("table", n * k0 + tail)]
-    assert net.tiers.rows == n * k0 + 2 * tail < n * k
-    assert edges.edge_table_rows(rows) == n * k
-    assert edges.edge_table_rows(rows[-2:]) == n * k0 + tail < n * k
+                    ("edge", n_pad * k0 + 3 * tail),
+                    ("table", n_pad * k0 + tail)]
+    assert net.tiers.rows == n_pad * k0 + 2 * tail < n * k
+    assert edges.edge_table_rows(rows) == n_pad * k
+    assert edges.edge_table_rows(rows[-2:]) == n_pad * k0 + tail < n * k
     # a tiered gather is still ONE gather set (hlo-audit, cost model)
     assert sets == ["edge", "edge", "peer"]
     banded = Net.build(graph.ring_lattice(64, d=4), graph.subscribe_all(64, 1))
@@ -243,9 +264,10 @@ def test_tally_records_the_plan_rows():
 
 def compact_pays(col_fill, n, k0):
     """The table rule, spelt out: the full table lies beyond the cliff,
-    the compact one this side of it."""
-    return (n * k0 + int(np.sum(col_fill[k0:]))
-            <= edges.TABLE_CLIFF_ROWS < n * len(col_fill))
+    the compact one this side of it, both at their padded size."""
+    n_pad = edges.lane_padded(n)
+    return (n_pad * k0 + int(np.sum(col_fill[k0:]))
+            <= edges.TABLE_CLIFF_ROWS < n_pad * len(col_fill))
 
 
 def cost(col_fill, n, k0):
@@ -287,7 +309,7 @@ def test_pick_k0_is_the_least_cost_on_a_hand_made_histogram(col_fill, n, why):
     plan = edges.plan_tiers(perm, ok)
     assert (plan is None) == (k0 == k)
     if plan is not None:
-        assert plan.head.shape[1] == k0
+        assert plan.head.shape == (k0, edges.lane_padded(n))
         assert plan.compact == compact_pays(col_fill, n, k0)
 
 
@@ -309,10 +331,11 @@ def test_a_traced_window_notes_the_table_its_gathers_read(form):
         n, k = topo.nbr.shape
         tiers = edges.plan_tiers(np.asarray(net.edge_perm), topo.nbr_ok,
                                  compact=True)
+        k0, n_pad = tiers.head.shape
         assert tiers.table_rows(k) == int(
-            n * tiers.head.shape[1]
-            + topo.nbr_ok[:, tiers.head.shape[1]:].sum()) < n * k
-        want = tiers.table_rows(k) if form == "compact" else n * k
+            n_pad * k0 + topo.nbr_ok[:, k0:].sum()) < n * k
+        want = {"compact": tiers.table_rows(k), "tiered": n_pad * k,
+                "full": n * k}[form]
         net = net.replace(tiers={"compact": tiers, "tiered": net.tiers,
                                  "full": None}[form])
 
