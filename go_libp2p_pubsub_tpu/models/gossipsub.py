@@ -154,6 +154,9 @@ class GossipSubConfig:
     # peer gater + validation pipeline model (validation.go front-end queue;
     # 0 capacity = unbounded, gater inert without throttle pressure)
     gater_enabled: bool = False
+    # the gater's quiet period in HEARTBEATS (ticks_for(quiet, heartbeat_
+    # interval)); ``last_throttle`` and ``tick`` count delivery ROUNDS, so
+    # the gate compares against ``gater_quiet_rounds``
     gater_quiet_ticks: int = 60
     validation_capacity: int = 0  # accepted validations per peer per round
     # async validation latency in rounds (survey §7 hard-part (c)): receipts
@@ -376,6 +379,11 @@ class GossipSubConfig:
         """FanoutTTL on the clock ``tick`` counts in: delivery rounds
         (``fanout_ttl_ticks`` heartbeats of ``heartbeat_every`` rounds)."""
         return self.fanout_ttl_ticks * self.heartbeat_every
+
+    @property
+    def gater_quiet_rounds(self) -> int:
+        """The gater's quiet period on the clock ``tick`` counts in."""
+        return self.gater_quiet_ticks * self.heartbeat_every
 
     def validation_timed_out(self, topic: int) -> bool:
         """True when this topic's async verdict can never land inside the
@@ -1718,7 +1726,8 @@ def prepare_step_consts(
     if cfg.score_enabled:
         assert score_params is not None
         score_params.validate()
-        tpa = TopicParamsArrays.build(score_params, net.n_topics, heartbeat_interval)
+        tpa = TopicParamsArrays.build(score_params, net.n_topics,
+                                      heartbeat_interval, cfg.heartbeat_every)
     else:
         score_params = PeerScoreParams(topics={}, skip_app_specific=True)
         tpa = TopicParamsArrays.build(score_params, net.n_topics)
@@ -1948,7 +1957,7 @@ def accept_gates(cfg: GossipSubConfig, net_l: Net, st: GossipSubState,
         # (heartbeat consumes fold_in(key, tick) directly)
         gkey = jax.random.fold_in(jax.random.fold_in(key, tick), 0x6A7E)
         acc_msg = acc_ok & (
-            gater_accept(st.gater, net_l, gater_params, cfg.gater_quiet_ticks,
+            gater_accept(st.gater, net_l, gater_params, cfg.gater_quiet_rounds,
                          tick, gkey)
             | net_l.direct
         )

@@ -95,6 +95,8 @@ Known deviations vs the per-round step, all bounded in PARITY.md:
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,62 +169,79 @@ class _AccStack:
     construction (pinned by tests/test_phase_stacked.py)."""
 
     def __init__(self, specs, n: int, w: int, stacked: bool):
-        # specs: (name, lanes, keep_masked); lanes=1 packs an [N, W]
-        # plane, lanes=k an [N, k, W] plane
+        # specs: (name, lanes, keep_masked, part); lanes=1 packs an
+        # [N, W] plane, lanes=k an [N, k, W] plane. Planes of one
+        # ``part`` (perf/stages.PARTS; None: the honest planes every
+        # scored build folds) share one buffer and are folded under that
+        # part's scope, so the trace can tell what the P3 / P4 and the
+        # gater planes cost; a build with one group folds as it always did
         self.specs = tuple(specs)
         self.stacked = stacked
-        self.offs = {}
-        off = 0
-        for name, lanes, _ in self.specs:
-            self.offs[name] = (off, lanes)
-            off += lanes
-        self.c = off
+        self.offs = {}      # name -> (part, first lane in its group, lanes)
+        self.groups = {}    # part -> [(name, lanes, keep_masked)]
+        for name, lanes, masked, part in self.specs:
+            group = self.groups.setdefault(part, [])
+            self.offs[name] = (part, sum(ln for _, ln, _ in group), lanes)
+            group.append((name, lanes, masked))
         if stacked:
-            self.buf = jnp.zeros((n, off, w), jnp.uint32) if off else None
+            self.bufs = {
+                part: jnp.zeros((n, sum(ln for _, ln, _ in group), w),
+                                jnp.uint32)
+                for part, group in self.groups.items()
+            }
         else:
             self.planes = {
                 name: jnp.zeros((n, w) if lanes == 1 else (n, lanes, w),
                                 jnp.uint32)
-                for name, lanes, _ in self.specs
+                for name, lanes, _, _ in self.specs
             }
+
+    @staticmethod
+    def _scope(part):
+        return stages.part(part) if part else contextlib.nullcontext()
 
     def __contains__(self, name: str) -> bool:
         return name in self.offs
 
     def or_(self, updates: dict) -> "_AccStack":
-        """OR the sub-round's updates in — one wide op when stacked.
-        Every live plane must have an update (all accumulation sites run
-        every sub-round)."""
+        """OR the sub-round's updates in — one wide op a group when
+        stacked. Every live plane must have an update (all accumulation
+        sites run every sub-round)."""
         if self.stacked:
-            if self.buf is not None:
-                n, _, w = self.buf.shape
-                upd = jnp.concatenate(
-                    [updates[name].reshape(n, lanes, w)
-                     for name, lanes, _ in self.specs], axis=1)
-                self.buf = self.buf | upd
+            for part, group in self.groups.items():
+                with self._scope(part):
+                    n, _, w = self.bufs[part].shape
+                    upd = jnp.concatenate(
+                        [updates[name].reshape(n, lanes, w)
+                         for name, lanes, _ in group], axis=1)
+                    self.bufs[part] = self.bufs[part] | upd
         else:
-            for name, _, _ in self.specs:
-                self.planes[name] = self.planes[name] | updates[name]
+            for name, _, _, part in self.specs:
+                with self._scope(part):
+                    self.planes[name] = self.planes[name] | updates[name]
         return self
 
     def keep(self, keep_w: jax.Array) -> "_AccStack":
         """AND the recycled-slot keep mask into every keep-masked plane —
-        one wide op when stacked (planes that must survive recycling,
-        e.g. the exact-trace dup plane, ride an all-ones lane mask)."""
+        one wide op a group when stacked (planes that must survive
+        recycling, e.g. the exact-trace dup plane, ride an all-ones lane
+        mask)."""
         if self.stacked:
-            if self.buf is not None:
-                lane_masked = jnp.asarray(
-                    [m for _, lanes, m in self.specs for _ in range(lanes)],
-                    bool)
-                mask = jnp.where(
-                    lane_masked[:, None], keep_w[None, :],
-                    jnp.uint32(0xFFFFFFFF))
-                self.buf = self.buf & mask[None]
+            for part, group in self.groups.items():
+                with self._scope(part):
+                    lane_masked = jnp.asarray(
+                        [m for _, lanes, m in group for _ in range(lanes)],
+                        bool)
+                    mask = jnp.where(
+                        lane_masked[:, None], keep_w[None, :],
+                        jnp.uint32(0xFFFFFFFF))
+                    self.bufs[part] = self.bufs[part] & mask[None]
         else:
-            for name, lanes, masked in self.specs:
+            for name, lanes, masked, part in self.specs:
                 if masked:
                     km = keep_w[None, :] if lanes == 1 else keep_w[None, None, :]
-                    self.planes[name] = self.planes[name] & km
+                    with self._scope(part):
+                        self.planes[name] = self.planes[name] & km
         return self
 
     def get(self, name: str, default=None):
@@ -230,10 +249,10 @@ class _AccStack:
             return default
         if not self.stacked:
             return self.planes[name]
-        off, lanes = self.offs[name]
+        part, off, lanes = self.offs[name]
         if lanes == 1:
-            return self.buf[:, off, :]
-        return self.buf[:, off : off + lanes, :]
+            return self.bufs[part][:, off, :]
+        return self.bufs[part][:, off : off + lanes, :]
 
 
 def make_gossipsub_phase_step(
@@ -518,9 +537,11 @@ def make_gossipsub_phase_step(
         st2 = handle_ihave(cfg, net_l, st2, joined_msg_words(net_l, core.msgs),
                            acc_ok, ihave_in_raw, thr=thr)
         if consts.sender_fwd_ok is not None:
-            iwant_resp = jnp.where(
-                consts.sender_fwd_ok[:, :, None], iwant_resp, jnp.uint32(0)
-            )
+            with stages.part("attrib"):
+                iwant_resp = jnp.where(
+                    consts.sender_fwd_ok[:, :, None], iwant_resp,
+                    jnp.uint32(0)
+                )
         # adversary data plane: an active drop/censor attacker withholds
         # its IWANT service too (the responses ride sub-round 0, so the
         # head tick's activity window applies) — receiver-side nbr-view
@@ -611,18 +632,19 @@ def make_gossipsub_phase_step(
         # NON-keep-masked lane — see the dup_trace comment below.
         acc_specs = []
         if plane_score:
-            acc_specs += [("new", 1, True), ("recv", 1, True)]
+            acc_specs += [("new", 1, True, None), ("recv", 1, True, None)]
         if plane_score or cfg.gater_enabled:
-            acc_specs += [("accepted", 1, True)]
+            acc_specs += [("accepted", 1, True, None)]
         if plane_score and p4_live:
-            acc_specs += [("trans", k_dim, True)]
+            acc_specs += [("trans", k_dim, True, "attrib")]
         if plane_score and p3_live:
-            acc_specs += [("mcw", k_dim, True)]
+            acc_specs += [("mcw", k_dim, True, "attrib")]
         if cfg.gater_enabled:
-            acc_specs += [("dup", k_dim, True), ("rejw", k_dim, True),
-                          ("ignw", k_dim, True)]
+            acc_specs += [("dup", k_dim, True, "gater"),
+                          ("rejw", k_dim, True, "gater"),
+                          ("ignw", k_dim, True, "gater")]
         if cfg.trace_exact:
-            acc_specs += [("dupt", k_dim, False)]
+            acc_specs += [("dupt", k_dim, False, None)]
         accs = _AccStack(acc_specs, n_peers, w, stacked=cfg.wire_coalesced)
         if count_score:
             zsc = jnp.zeros((n_peers, s_slots, k_dim), jnp.float32)
@@ -718,9 +740,10 @@ def make_gossipsub_phase_step(
             if adv_self is not None:
                 # adversary behavior vector: marked peers run control but
                 # never transmit message data (sybilSquatter analogue)
-                send = jnp.where(
-                    adv_self[:, None, None], jnp.uint32(0), send
-                )
+                with stages.part("attrib"):
+                    send = jnp.where(
+                        adv_self[:, None, None], jnp.uint32(0), send
+                    )
             if adv is not None and adv.data_plane:
                 # scheduled drop/censor attackers mask their OWN rows
                 # before the one edge gather (sender-side — the phase
@@ -787,9 +810,10 @@ def make_gossipsub_phase_step(
                 else bitset.pack(msgs.valid)
             )
             if cfg.validation_capacity > 0:
-                dlv, info, accepted_new, n_thr = apply_validation_throttle(
-                    dlv, info, cfg.validation_capacity, m, valid_w_i
-                )
+                with stages.part("attrib"):
+                    dlv, info, accepted_new, n_thr = apply_validation_throttle(
+                        dlv, info, cfg.validation_capacity, m, valid_w_i
+                    )
             else:
                 accepted_new = info.new_words
                 n_thr = None
@@ -808,11 +832,12 @@ def make_gossipsub_phase_step(
             if cfg.score_enabled and (p3_live or count_score):
                 # P3 window gate at this arrival's own tick (score.go:
                 # 944-974 markDuplicateMessageDelivery window check)
-                msg_window = wrt[jnp.clip(msgs.topic, 0)]
-                within_i = bitset.pack(
-                    (dlv.first_round >= 0)
-                    & ((tick_i - dlv.first_round) <= msg_window[None, :])
-                )
+                with stages.part("attrib"):
+                    msg_window = wrt[jnp.clip(msgs.topic, 0)]
+                    within_i = bitset.pack(
+                        (dlv.first_round >= 0)
+                        & ((tick_i - dlv.first_round) <= msg_window[None, :])
+                    )
             if count_score:
                 valid3 = valid_w_i[None, None, :]
                 mesh_w = info.trans & valid3 & within_i[:, None, :]
@@ -827,32 +852,34 @@ def make_gossipsub_phase_step(
                 fmd_counts = fmd_counts + per_slot_counts(fa_w, slotw)
                 imd_counts = imd_counts + per_slot_counts(inv_w, slotw)
             elif plane_score and p3_live:
-                mcw_i = info.trans & within_i[:, None, :]
-                if val_delay > 0:
-                    # duplicates arriving while the message sits in the
-                    # validation pipeline (score.go:712-718); the fresh
-                    # first arrival earns credit at its verdict instead
-                    pend_post = bitset.word_or_reduce(dlv.pending, axis=1)
-                    fa_i = dlv.fe_words & info.recv_new_words[:, None, :]
-                    mcw_i = mcw_i | (
-                        info.trans & pend_post[:, None, :] & ~fa_i
-                    )
+                with stages.part("attrib"):
+                    mcw_i = info.trans & within_i[:, None, :]
+                    if val_delay > 0:
+                        # duplicates arriving while the message sits in
+                        # the validation pipeline (score.go:712-718); the
+                        # fresh first arrival earns credit at its verdict
+                        pend_post = bitset.word_or_reduce(dlv.pending, axis=1)
+                        fa_i = dlv.fe_words & info.recv_new_words[:, None, :]
+                        mcw_i = mcw_i | (
+                            info.trans & pend_post[:, None, :] & ~fa_i
+                        )
                 acc_upd["mcw"] = mcw_i
             if cfg.gater_enabled:
-                acc_upd["dup"] = info.trans & pre_have[:, None, :]
-                ign_w_i = (
-                    plan.ignored_words[i] if plan is not None
-                    else bitset.pack(msgs.ignored)
-                )
-                acc_upd["rejw"] = (
-                    info.trans & ~(valid_w_i | ign_w_i)[None, None, :]
-                )
-                acc_upd["ignw"] = info.trans & ign_w_i[None, None, :]
-                n_validated_acc = n_validated_acc + bitset.popcount(
-                    accepted_new, axis=-1
-                )
-                if n_thr is not None:
-                    n_throttled_acc = n_throttled_acc + n_thr
+                with stages.part("gater"):
+                    acc_upd["dup"] = info.trans & pre_have[:, None, :]
+                    ign_w_i = (
+                        plan.ignored_words[i] if plan is not None
+                        else bitset.pack(msgs.ignored)
+                    )
+                    acc_upd["rejw"] = (
+                        info.trans & ~(valid_w_i | ign_w_i)[None, None, :]
+                    )
+                    acc_upd["ignw"] = info.trans & ign_w_i[None, None, :]
+                    n_validated_acc = n_validated_acc + bitset.popcount(
+                        accepted_new, axis=-1
+                    )
+                    if n_thr is not None:
+                        n_throttled_acc = n_throttled_acc + n_thr
             accs = accs.or_(acc_upd)
             if cfg.count_events:
                 for k in cnt:
@@ -1033,21 +1060,26 @@ def make_gossipsub_phase_step(
             )
         gater_state = st2.gater
         if cfg.gater_enabled:
-            valid_w_end = bitset.pack(msgs.valid)
-            first_arrival = (
-                dlv.fe_words & accs.get("accepted")[:, None, :]
-                & valid_w_end[None, None, :]
-            )
-            deliver_inc = bitset.popcount(first_arrival, axis=-1).astype(jnp.float32)
-            gater_state = gater_on_round(
-                gater_state, n_validated_acc, n_throttled_acc, deliver_inc,
-                bitset.popcount(accs.get("dup"), axis=-1).astype(jnp.float32),
-                bitset.popcount(accs.get("rejw"), axis=-1).astype(jnp.float32),
-                tick_last,
-                ignore_inc=bitset.popcount(
-                    accs.get("ignw"), axis=-1
-                ).astype(jnp.float32),
-            )
+            with stages.part("gater"):
+                valid_w_end = bitset.pack(msgs.valid)
+                first_arrival = (
+                    dlv.fe_words & accs.get("accepted")[:, None, :]
+                    & valid_w_end[None, None, :]
+                )
+                deliver_inc = bitset.popcount(
+                    first_arrival, axis=-1).astype(jnp.float32)
+                gater_state = gater_on_round(
+                    gater_state, n_validated_acc, n_throttled_acc,
+                    deliver_inc,
+                    bitset.popcount(
+                        accs.get("dup"), axis=-1).astype(jnp.float32),
+                    bitset.popcount(
+                        accs.get("rejw"), axis=-1).astype(jnp.float32),
+                    tick_last,
+                    ignore_inc=bitset.popcount(
+                        accs.get("ignw"), axis=-1
+                    ).astype(jnp.float32),
+                )
         if cfg.count_events:
             # accumulate_round_events consumes only the scalar counters;
             # the plane fields are placeholders (DCE'd when unaccumulated)

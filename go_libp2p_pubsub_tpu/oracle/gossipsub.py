@@ -122,7 +122,10 @@ class OracleGossipSub:
         self._gossip_suppress = set()  # (i, k): congested outbound links
         # v1.1 composed plane
         if self.score_params is not None:
-            self.oscore = [OracleScore(self.score_params) for _ in range(n)]
+            self.oscore = [
+                OracleScore(self.score_params,
+                            heartbeat_every=self.cfg.heartbeat_every)
+                for _ in range(n)]
             self.scores = [dict() for _ in range(n)]  # k -> memoized score
             # IWANT promises at the reference granularity: one random msg
             # per IWANT batch, any number outstanding per edge
@@ -401,13 +404,15 @@ class OracleGossipSub:
                     n_rpc += 1
 
         def _window_rounds(topic) -> int:
-            # same tick conversion as TopicParamsArrays.build (engine.py)
+            # same conversion as TopicParamsArrays.build (engine.py):
+            # heartbeats x rounds a heartbeat, less one
             tp = (self.score_params.topics.get(topic)
                   if self.score_params else None)
             if tp is None:
                 return 0
             w = tp.mesh_message_deliveries_window
-            return ticks_for(w, 1.0) - 1 if w >= 1.0 else 0
+            return (ticks_for(w, 1.0) * self.cfg.heartbeat_every - 1
+                    if w >= 1.0 else 0)
 
         def _attribute(i, slot, ks, first: bool):
             """Score attribution for one round's arrivals of `slot` at i:

@@ -30,6 +30,9 @@ class TStats:
 class OracleScore:
     params: PeerScoreParams
     heartbeat_interval: float = 1.0
+    #: rounds a heartbeat: ``mesh_time`` counts rounds, so the P3
+    #: activation is heartbeats times this (score/engine.py builds it so)
+    heartbeat_every: int = 1
     stats: dict = field(default_factory=dict)   # (nbr, topic) -> TStats
     bp: dict = field(default_factory=dict)      # nbr -> behaviour penalty
 
@@ -111,7 +114,7 @@ class OracleScore:
                 ts.mesh_time = tick - ts.graft_tick
                 if ts.mesh_time > ticks_for(
                     tp.mesh_message_deliveries_activation, self.heartbeat_interval
-                ):
+                ) * self.heartbeat_every:
                     ts.mmd_active = True
         for p in list(self.bp):
             self.bp[p] = dec(self.bp[p], self.params.behaviour_penalty_decay)

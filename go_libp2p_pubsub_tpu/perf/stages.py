@@ -59,6 +59,17 @@ same compiled text.
                 ``gossipsub.update_fanout_on_publish``, ``fanout_carry_
                 words[_packed]``, the packed form's pack / unpack, and the
                 heartbeat's fanout maintenance and fanout gossip blocks
+  attrib        what a v1.1 build pays to ATTRIBUTE deliveries beyond P1 /
+                P2 / P7: the ``[N,K,W]`` ``trans`` (P4) and ``mcw`` (P3)
+                planes' folds in the phase engine's ``_AccStack``, the P3
+                window gate of every sub-round, ``apply_validation_
+                throttle``, the static adversary's data-plane masks, and
+                the P3 / P3b / P4 terms of ``score.engine`` (``on_
+                deliveries``, ``apply_delivery_counts``, ``compute_
+                scores``)
+  gater         the peer gater: the ``dup`` / ``rejw`` / ``ignw`` planes'
+                composition and folds, ``score.gater.gater_on_round`` with
+                its popcounts, ``gater_accept`` and ``gater_decay``
 
 Known limits. A fusion carries one ``op_name``, its root's: a fusion
 that spans two stages is booked to the root's. A tracer carries no
@@ -84,7 +95,12 @@ from typing import Any
 #: RENAMES A SCOPE BUMPS THIS. (The ``gsx.fanout`` part came without a
 #: bump: it is traced only where ``fanout_slots`` > 0, and the PR that
 #: brought it changed those programs' FanoutTTL constant, so no
-#: executable from before it has their key.)
+#: executable from before it has their key. ``gsx.attrib`` and
+#: ``gsx.gater`` likewise: they are read in builds with live P3 / P4
+#: weights, a gater, a validation queue or an adversary vector, whose P3
+#: constants the same PR moved onto the clock of rounds; in an honest
+#: build the score terms' scope stands around weightless arithmetic, and
+#: an executable cached before it simply lacks the name nobody reads.)
 VERSION = 1
 
 PREFIX = "gs."
@@ -92,7 +108,7 @@ STAGES = ("control_head", "pub_plan", "data_round", "edge_gather", "deliver",
           "score", "heartbeat", "phase_tail")
 UNSCOPED = "unscoped"
 PART_PREFIX = "gsx."
-PARTS = ("fanout",)
+PARTS = ("fanout", "attrib", "gater")
 
 _SCOPE_RE = re.compile(re.escape(PREFIX) + r"([a-z_]+)")
 _PART_RE = re.compile(re.escape(PART_PREFIX) + r"([a-z_]+)")

@@ -46,6 +46,8 @@ def bench_score_params(config: str, n_topics: int):
 
     if config == "sybil":
         # deficit penalties on: the sybils are what scoring must catch
+        # (the benchmark's sybil-50k differs: validation capacity 32 not
+        # 8, time_in_mesh_cap 4, origins uniform over sybils too)
         tp = TopicScoreParams(
             mesh_message_deliveries_weight=-0.5,
             mesh_message_deliveries_threshold=4.0,

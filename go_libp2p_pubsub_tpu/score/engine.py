@@ -11,11 +11,14 @@ Time is integer ticks; durations are converted with ticks_for at
 TopicParamsArrays build time. The P3 "mesh delivery window" becomes
 window_rounds (default 0: only same-round-as-validation duplicates count,
 matching the reference's 10ms window vs 1s heartbeat scale — survey §7
-hard-part (e)).
+hard-part (e)). The P3 window and activation are compared against
+``tick``, which counts delivery ROUNDS, so both are built in rounds:
+heartbeats times ``heartbeat_every`` (the same at ``heartbeat_every`` 1).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import jax
@@ -47,15 +50,18 @@ class TopicParamsArrays:
     decay3: np.ndarray
     cap3: np.ndarray
     thr3: np.ndarray
-    window_rounds: np.ndarray     # [T] i32
-    activation_ticks: np.ndarray  # [T] i32
+    window_rounds: np.ndarray     # [T] i32, delivery rounds
+    activation_ticks: np.ndarray  # [T] i32, delivery rounds too
     w3b: np.ndarray
     decay3b: np.ndarray
     w4: np.ndarray
     decay4: np.ndarray
 
     @classmethod
-    def build(cls, params: PeerScoreParams, n_topics: int, heartbeat_interval: float = 1.0):
+    def build(cls, params: PeerScoreParams, n_topics: int, heartbeat_interval: float = 1.0,
+              heartbeat_every: int = 1):
+        he = int(heartbeat_every)
+
         def arr(fn, dtype=np.float32):
             out = np.zeros((n_topics,), dtype)
             for t, tp in params.topics.items():
@@ -80,14 +86,24 @@ class TopicParamsArrays:
             decay3=arr(lambda p: p.mesh_message_deliveries_decay),
             cap3=arr(lambda p: p.mesh_message_deliveries_cap),
             thr3=arr(lambda p: p.mesh_message_deliveries_threshold),
+            # both are read against ``tick - first_round`` / ``tick -
+            # graft_tick``, which count ROUNDS: heartbeats x rounds a
+            # heartbeat (the window keeps its "less one" at the round's
+            # grain: an arrival w rounds after the first is inside)
             window_rounds=arr(
-                lambda p: ticks_for(p.mesh_message_deliveries_window, heartbeat_interval) - 1
+                lambda p: ticks_for(p.mesh_message_deliveries_window, heartbeat_interval) * he - 1
                 if p.mesh_message_deliveries_window >= heartbeat_interval
                 else 0,
                 np.int32,
             ),
+            # (a topic whose P3 and P3b are both weightless has no reader
+            # of the activation latch, and its row keeps the heartbeat
+            # count it had: the honest cells' windows keep their text)
             activation_ticks=arr(
-                lambda p: ticks_for(p.mesh_message_deliveries_activation, heartbeat_interval), np.int32
+                lambda p: ticks_for(p.mesh_message_deliveries_activation, heartbeat_interval)
+                * (he if p.mesh_message_deliveries_weight != 0.0
+                   or p.mesh_failure_penalty_weight != 0.0 else 1),
+                np.int32,
             ),
             w3b=arr(lambda p: p.mesh_failure_penalty_weight),
             decay3b=arr(lambda p: p.mesh_failure_penalty_decay),
@@ -134,6 +150,19 @@ class ScoreState:
         )
 
 
+def _attrib(tp: dict, *weights: str):
+    """``stages.part("attrib")`` around a P3 / P3b / P4 term, where one of
+    the gathered ``weights`` of ``tp`` can be non-zero: a concrete plane is
+    looked at while the step is traced, a traced one (the lifted build's)
+    counts as live. Weightless terms (an honest net's) get no scope, so
+    such a program carries no part."""
+    for name in weights:
+        w = tp[name]
+        if isinstance(w, jax.core.Tracer) or np.any(jax.device_get(w) != 0.0):
+            return stages.part("attrib")
+    return contextlib.nullcontext()
+
+
 # ---------------------------------------------------------------------------
 # P6: IP colocation
 
@@ -178,14 +207,15 @@ def compute_scores(
     # P2 (score.go:288-289)
     topic = topic + st.fmd * e(tp["w2"])
 
-    # P3: deficit^2 when active and below threshold (score.go:292-298)
-    deficit = e(tp["thr3"]) - st.mmd
-    p3 = jnp.where(st.mmd_active & (deficit > 0), deficit * deficit, 0.0)
-    topic = topic + p3 * e(tp["w3"])
+    with _attrib(tp, "w3", "w3b", "w4"):
+        # P3: deficit^2 when active and below threshold (score.go:292-298)
+        deficit = e(tp["thr3"]) - st.mmd
+        p3 = jnp.where(st.mmd_active & (deficit > 0), deficit * deficit, 0.0)
+        topic = topic + p3 * e(tp["w3"])
 
-    # P3b + P4 (score.go:302-308)
-    topic = topic + st.mfp * e(tp["w3b"])
-    topic = topic + st.imd * st.imd * e(tp["w4"])
+        # P3b + P4 (score.go:302-308)
+        topic = topic + st.mfp * e(tp["w3b"])
+        topic = topic + st.imd * st.imd * e(tp["w4"])
 
     score = jnp.sum(topic * e(tp["topic_weight"]), axis=1)  # [N,K]
 
@@ -417,50 +447,52 @@ def on_deliveries(
     # duplicates within the window; only on mesh edges, only valid msgs.
     # The window gate requires a set first_round (a message still awaiting
     # its verdict has first_round = -1, which must not pass the compare).
-    if mesh_credit_words is not None:
-        # phase mode (gossipsub_phase.py): the caller evaluated the window
-        # gate per sub-round against each arrival's own tick and OR-folded
-        # the result (exact — every (edge,msg) pair transmits at most once,
-        # so the fold loses no multiplicity); the pending-duplicate credit
-        # is likewise folded in per sub-round. Only the valid mask and the
-        # verdict-time first-arrival credit apply at phase end.
-        mesh_credit = (
-            (mesh_credit_words & valid_w[None, None, :]) | first_arrival
-        )
-    else:
-        msg_window = window_rounds_t[t]  # [M]
-        within_w = bitset.pack(
-            (first_round >= 0) & ((tick - first_round) <= msg_window[None, :])
-        )  # [N,W]
-        mesh_credit = trans_words & valid_w[None, None, :] & within_w[:, None, :]
-    if mesh_credit_words is None and pending_words is not None:
-        # async pipeline (DeliverMessage's drec.peers loop, score.go:712-718):
-        #  * the first-arrival edge earns its mesh credit at the verdict —
-        #    its physical transmission happened rounds ago, so trans can't
-        #    supply it;
-        #  * duplicates arriving while the message is pending are in the
-        #    delivery record and credited unconditionally (credited here at
-        #    arrival; the count matches, only the decay instant differs).
-        #    The fresh first arrival itself is excluded — it gets credit at
-        #    its own verdict via the first branch.
-        exclude_first = (
-            fe_words & recv_new_words[:, None, :]
-            if recv_new_words is not None else jnp.uint32(0)
-        )
-        pend_dup = (
-            trans_words & pending_words[:, None, :] & valid_w[None, None, :]
-            & ~exclude_first
-        )
-        mesh_credit = mesh_credit | pend_dup | first_arrival
-    mmd_inc = _psc(mesh_credit, slotw) * in_mesh.astype(jnp.float32)
-    mmd = jnp.minimum(st.mmd + mmd_inc, e(tp["cap3"]))
+    with _attrib(tp, "w3", "w3b"):
+        if mesh_credit_words is not None:
+            # phase mode (gossipsub_phase.py): the caller evaluated the window
+            # gate per sub-round against each arrival's own tick and OR-folded
+            # the result (exact — every (edge,msg) pair transmits at most once,
+            # so the fold loses no multiplicity); the pending-duplicate credit
+            # is likewise folded in per sub-round. Only the valid mask and the
+            # verdict-time first-arrival credit apply at phase end.
+            mesh_credit = (
+                (mesh_credit_words & valid_w[None, None, :]) | first_arrival
+            )
+        else:
+            msg_window = window_rounds_t[t]  # [M]
+            within_w = bitset.pack(
+                (first_round >= 0) & ((tick - first_round) <= msg_window[None, :])
+            )  # [N,W]
+            mesh_credit = trans_words & valid_w[None, None, :] & within_w[:, None, :]
+        if mesh_credit_words is None and pending_words is not None:
+            # async pipeline (DeliverMessage's drec.peers loop, score.go:712-718):
+            #  * the first-arrival edge earns its mesh credit at the verdict —
+            #    its physical transmission happened rounds ago, so trans can't
+            #    supply it;
+            #  * duplicates arriving while the message is pending are in the
+            #    delivery record and credited unconditionally (credited here at
+            #    arrival; the count matches, only the decay instant differs).
+            #    The fresh first arrival itself is excluded — it gets credit at
+            #    its own verdict via the first branch.
+            exclude_first = (
+                fe_words & recv_new_words[:, None, :]
+                if recv_new_words is not None else jnp.uint32(0)
+            )
+            pend_dup = (
+                trans_words & pending_words[:, None, :] & valid_w[None, None, :]
+                & ~exclude_first
+            )
+            mesh_credit = mesh_credit | pend_dup | first_arrival
+        mmd_inc = _psc(mesh_credit, slotw) * in_mesh.astype(jnp.float32)
+        mmd = jnp.minimum(st.mmd + mmd_inc, e(tp["cap3"]))
 
     # -- P4 penalty for rejected messages -----------------------------------
-    penalize_w = ~valid_w
-    if msg_ignored is not None:
-        penalize_w = penalize_w & ~bitset.pack(msg_ignored)
-    invalid_arrival = trans_words & penalize_w[None, None, :]
-    imd = st.imd + _psc(invalid_arrival, slotw)
+    with _attrib(tp, "w4"):
+        penalize_w = ~valid_w
+        if msg_ignored is not None:
+            penalize_w = penalize_w & ~bitset.pack(msg_ignored)
+        invalid_arrival = trans_words & penalize_w[None, None, :]
+        imd = st.imd + _psc(invalid_arrival, slotw)
 
     # unscored slots track nothing (getTopicStats, score.go:881-884)
     scored = e(tp["scored"])
@@ -490,10 +522,11 @@ def apply_delivery_counts(
     late (caps are sized in the hundreds — parity rows cover it)."""
     e = lambda a: a[..., None]
     fmd = jnp.minimum(st.fmd + fmd_counts, e(tp["cap2"]))
-    mmd = jnp.minimum(
-        st.mmd + mmd_counts * in_mesh.astype(jnp.float32), e(tp["cap3"])
-    )
-    imd = st.imd + imd_counts
+    with _attrib(tp, "w3", "w3b", "w4"):
+        mmd = jnp.minimum(
+            st.mmd + mmd_counts * in_mesh.astype(jnp.float32), e(tp["cap3"])
+        )
+        imd = st.imd + imd_counts
     scored = e(tp["scored"])
     return st.replace(
         fmd=jnp.where(scored, fmd, st.fmd),

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from ..config import PeerGaterParams
+from ..perf import stages
 from ..state import Net
 
 
@@ -51,6 +52,7 @@ def same_source_matrix(net: Net) -> jax.Array:
     return same.astype(jnp.float32)
 
 
+@stages.part("gater")
 def gater_decay(gs: GaterState, params: PeerGaterParams) -> GaterState:
     """Per-decay-interval counter decay (peer_gater.go:219-259)."""
     dtz = params.decay_to_zero
@@ -69,6 +71,7 @@ def gater_decay(gs: GaterState, params: PeerGaterParams) -> GaterState:
     )
 
 
+@stages.part("gater")
 def gater_accept(
     gs: GaterState,
     net: Net,
@@ -111,6 +114,7 @@ def gater_accept(
     return calm[:, None] | accept
 
 
+@stages.part("gater")
 def gater_on_round(
     gs: GaterState,
     n_validated: jax.Array,   # [N] i32 — receipts entering validation

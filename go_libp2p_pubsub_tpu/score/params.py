@@ -174,6 +174,7 @@ class ScoreParams:
         thresholds: PeerScoreThresholds | None = None,
         n_topics: int = 1,
         heartbeat_interval: float = 1.0,
+        heartbeat_every: int = 1,
     ) -> "ScoreParams":
         """Build the plane from the SAME host structs the static path
         consumes — the [T] rows go through TopicParamsArrays.build, so
@@ -181,7 +182,7 @@ class ScoreParams:
         ``thresholds=None`` builds the v1.0 all-zero threshold plane
         (what GossipSubConfig.build records without thresholds)."""
         tpa = TopicParamsArrays.build(score_params, n_topics,
-                                      heartbeat_interval)
+                                      heartbeat_interval, heartbeat_every)
         kw = {name: jnp.asarray(getattr(tpa, name))
               for name in TOPIC_ROW_FIELDS}
         for f in PEER_SCALAR_FIELDS:
@@ -201,7 +202,8 @@ class ScoreParams:
         plane reproduces the static build bit for bit. (THRESHOLD_FIELDS
         are the GossipSubConfig field names, so the cfg duck-types as
         build()'s thresholds source.)"""
-        return cls.build(score_params, cfg, n_topics, heartbeat_interval)
+        return cls.build(score_params, cfg, n_topics, heartbeat_interval,
+                         cfg.heartbeat_every)
 
     def gather(self, my_topics: jax.Array) -> dict:
         """The per-(peer, slot) [N, S] views — the exact

@@ -5,19 +5,23 @@ texts, never a time) and its text searched for ``while`` ops.
 
     JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \\
         python scripts/window_whiles.py --workload random-100k.stepped \\
-        [--text /root/scratch/window.txt] [--n-peers 3000]
+        [--text /root/scratch/window.txt] [--n-peers 3000] [--lower-only]
 
 XLA merges or splits ``(N, K) <-> N*K`` under a tiled layout through a
 1-D ``u32[...]{0:T(1024)}`` buffer, one word an iteration, where the minor
 of the two axes is not a whole number of lanes: those loops stand right
 before and after a general gather's fusion and were a quarter of the
 round at 100k peers (PERF.md §6, PR 34). One JSON line: the ``while`` ops,
-the ops inside their bodies, and those whose tuple carries such a buffer.
+the ops inside their bodies, and those whose tuple carries such a buffer,
+after the sha256 of the window's LOWERED text (StableHLO, constants and
+all: two commits whose windows lower to one text run one program).
+``--lower-only`` stops there (seconds, where the compile takes a minute).
 """
 
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import os
 import re
@@ -64,6 +68,9 @@ def main(argv=None) -> int:
     ap.add_argument("--n-peers", type=int,
                     help="a rehearsal at another size (small planes go "
                          "through copies: no loop on either side)")
+    ap.add_argument("--lower-only", action="store_true",
+                    help="print the lowered text's sha256 and compile "
+                         "nothing")
     args = ap.parse_args(argv)
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -93,8 +100,14 @@ def main(argv=None) -> int:
           jax.ShapeDtypeStruct(pubs, jnp.int32),
           jax.ShapeDtypeStruct(pubs, bool))
     t0 = time.perf_counter()
-    text = window.lower(on(jax.eval_shape(built.fresh)), *on(xs)
-                        ).compile().as_text()
+    lowered = window.lower(on(jax.eval_shape(built.fresh)), *on(xs))
+    sha = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    if args.lower_only:
+        print(json.dumps({"workload": cell["name"], "n_peers": built.n_peers,
+                          "lowered_sha256": sha,
+                          "lower_s": time.perf_counter() - t0}))
+        return 0
+    text = lowered.compile().as_text()
     found = whiles(text)
     if args.text:
         with open(args.text, "w") as f:
@@ -102,6 +115,7 @@ def main(argv=None) -> int:
     flat = [w for w in found if w["flat_u32_words"]]
     print(json.dumps({
         "workload": cell["name"], "compiled_for": str(topo.devices[0]),
+        "lowered_sha256": sha,
         "compile_s": time.perf_counter() - t0, "text_bytes": len(text),
         "whiles": len(found),
         "while_body_ops": sum(w["body_ops"] or 0 for w in found),

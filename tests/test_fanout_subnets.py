@@ -321,7 +321,7 @@ def test_part_of_and_the_stages_do_not_see_each_other(op_name, part):
     assert stages.part_of(op_name) == part
     assert stages.stage_of("jit(f)/gs.heartbeat/gsx.fanout/add") == "heartbeat"
     assert stages.stage_of("jit(f)/gsx.fanout/add") == stages.UNSCOPED
-    assert stages.PARTS == ("fanout",)
+    assert stages.PARTS == ("fanout", "attrib", "gater")
     with pytest.raises(ValueError, match="no part"):
         stages.part("gossip")
 

@@ -49,6 +49,10 @@ MAX_H = 14
 
 
 def _sybil_setup():
+    # the benchmark's sybil-50k (benchmark/configs/sybil-50k.json) differs:
+    # gater + validation capacity 32 live, time_in_mesh_cap 4, origins
+    # uniform over sybils too, heartbeat every 8 rounds (honest origins,
+    # no gater and a per-round heartbeat here)
     topo = graph.random_connect(N, d=DEG, seed=5)
     subs = graph.subscribe_all(N, 1)
     rng = np.random.default_rng(2)
