@@ -129,15 +129,20 @@ def _jit_window(run, donate: bool):
     ``perf.stages.VERSION``) and noted in the stage registry while its
     Python body is traced, so once per trace and never per dispatch. The
     same trace counts the rows its edge gathers address by index
-    (``ops/edges.tally_index_rows``), per step call, and the rows of the
-    table they read."""
+    (``ops/edges.tally_index_rows``), per step call, the rows and the
+    tile-rows of the table they read, and the calls that crossed in column
+    slices."""
     def window(*args, **kwargs):
         rows: list = []
         with edges.tally_index_rows(rows):
             out = run(*args, **kwargs)
-        stages.note_window(jitted, args, kwargs,
-                           edge_rows=edges.edge_rows_per_dispatch(rows),
-                           table_rows=edges.edge_table_rows(rows))
+        stages.note_window(
+            jitted, args, kwargs,
+            edge_rows_per_dispatch=edges.edge_rows_per_dispatch(rows),
+            edge_table_rows=edges.edge_table_rows(rows),
+            edge_table_tile_rows=edges.edge_table_rows(rows, "tile_rows"),
+            edge_sliced_calls_per_dispatch=edges.edge_rows_per_dispatch(
+                rows, "sliced"))
         return out
     window.__name__ = window.__qualname__ = stages.window_name()
     jitted = jax.jit(window, donate_argnums=0 if donate else ())

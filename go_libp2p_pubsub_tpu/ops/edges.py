@@ -20,9 +20,13 @@ lane-padded peer axis (slot (n, k) is row `k*Np + n`, `Np` = N rounded up
 to 128): the planes live N-minor on the chip, and only a minor axis that
 is a whole number of lanes merges into the gather's row axis without a
 copy loop. Every slot comes out bit for bit as `flat[perm]` gives it.
-K0 = K is the one full gather. `_tally` counts the rows every gather set
-addresses and the rows of the table it reads, for the window's
-`edge_rows_per_dispatch` and `edge_table_rows`.
+K0 = K is the one full gather. A plane wider than one sublane tile of
+words crosses in tile-wide column slices, one gather a slice, where only
+the slices' tables are small enough to be read cheaply (`word_slices`).
+`_tally` counts the rows every gather set addresses, the rows and tile-rows
+of the table it reads and whether it was sliced, for the window's
+`edge_rows_per_dispatch`, `edge_table_rows`, `edge_table_tile_rows` and
+`edge_sliced_calls_per_dispatch`.
 
 Topic-slot payloads ([N,S,K] per-slot bools) are moved across edges by
 packing the S axis into *topic-id bit positions* of uint32 words (T bits
@@ -35,6 +39,7 @@ per-topic control messages).
 from __future__ import annotations
 
 import contextlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -71,19 +76,24 @@ class TallyCacheHit(RuntimeError):
     error instead of a zero."""
 
 
-def _tally(kind: str, moved=None, rows: int = 0,
-           table_rows: int = 0) -> None:
+def _tally(kind: str, moved=None, rows: int = 0, table_rows: int = 0,
+           tile_rows: int = 0, sliced: bool = False) -> None:
     """One cross-peer gather SET: ``moved`` is the tensor it moves,
     ``rows`` the rows it addresses by index (a gather's output rows plus
     a scatter's rows; 0 for rolls, which address none), ``table_rows``
-    the rows of the table its largest gather reads (an entry of its own,
-    ``("table", n)``, and none for rolls)."""
+    the rows of the table its largest gather reads and ``tile_rows`` the
+    same in the unit the chip charges (``tile_rows``; entries of their
+    own, ``("table", n)`` and ``("tile_rows", n)``, and none for rolls),
+    ``sliced`` whether ``word_slices`` split the call (``("sliced", 1)``)."""
     if _TALLY is not None:
         _TALLY.append(kind)
     if _ROWS_TALLY is not None:
         _ROWS_TALLY.append((kind, int(rows)))
         if table_rows:
             _ROWS_TALLY.append(("table", int(table_rows)))
+            _ROWS_TALLY.append(("tile_rows", int(tile_rows)))
+        if sliced:
+            _ROWS_TALLY.append(("sliced", 1))
     if _BYTES_TALLY is not None:
         nbytes = None
         if moved is not None and hasattr(moved, "size"):
@@ -150,9 +160,10 @@ def mark_dispatch(key=None) -> None:
         _ROWS_TALLY.append(("dispatch", key))
 
 
-def edge_rows_per_dispatch(tally: list) -> float | None:
+def edge_rows_per_dispatch(tally: list, of: str = "edge") -> float | None:
     """Mean ``"edge"`` rows per step call of a ``tally_index_rows`` list
-    cut by ``mark_dispatch``. A jitted step is traced once per key: a
+    cut by ``mark_dispatch`` (``of="sliced"``: the calls ``word_slices``
+    split). A jitted step is traced once per key: a
     later call with the same key replays the cached jaxpr and tallies
     nothing, so it counts what the first call with its key counted.
     ``None`` where no call tallied anything (no window body marked a
@@ -162,7 +173,7 @@ def edge_rows_per_dispatch(tally: list) -> float | None:
         if kind == "dispatch":
             calls.append([val, None])
         elif calls:
-            calls[-1][1] = (calls[-1][1] or 0) + (val if kind == "edge" else 0)
+            calls[-1][1] = (calls[-1][1] or 0) + (val if kind == of else 0)
     for key, rows in calls:
         if rows is not None:
             first.setdefault(key, rows)
@@ -170,13 +181,14 @@ def edge_rows_per_dispatch(tally: list) -> float | None:
     return sum(rows) / len(rows) if rows else None
 
 
-def edge_table_rows(tally: list) -> int | None:
+def edge_table_rows(tally: list, of: str = "table") -> int | None:
     """The largest table an edge gather of a ``tally_index_rows`` list
     reads, in rows: N*K through the full ``edge_perm``; of a planned net
-    K*Np through the full table, K0*Np + T where the compact one engaged.
+    K*Np through the full table, K0*Np + T where the compact one engaged
+    (``of="tile_rows"``: in tile-rows, at the width it is read in).
     ``None`` where no gather read one (rolls, or every trace was a
     replay)."""
-    return max((val for kind, val in tally if kind == "table"), default=None)
+    return max((val for kind, val in tally if kind == of), default=None)
 
 
 def tally_step(step, state, args=(), kwargs=None, *, net=None,
@@ -280,7 +292,8 @@ def involution_wf(nbr: jax.Array, rev: jax.Array, nbr_ok: jax.Array,
 def edge_permute(x: jax.Array, perm: jax.Array) -> jax.Array:
     """x[N, K, ...] -> x[nbr[j,k], rev[j,k], ...] as a flat row gather."""
     n, k = perm.shape
-    _tally("edge", x, rows=n * k, table_rows=n * k)
+    _tally("edge", x, rows=n * k, table_rows=n * k,
+           tile_rows=tile_rows(n * k, math.prod(x.shape[2:])))
     flat = x.reshape((n * k,) + x.shape[2:])
     return flat[perm.reshape(-1)].reshape(x.shape)
 
@@ -320,17 +333,39 @@ COMPACT_HEAD_ROW_NS = 8.1
 TAIL_ROW_NS = 113.0
 TIER_FIXED_NS = 15_000.0
 
-#: The largest table a gather was measured to read at the law's low price
-#: (same script; my chip runs). Of 5 words a row: full tables of 0.36, 0.88,
-#: 1.75 and 2.7 M rows give their K0 = 24 head rows up as cheaply as a
-#: compact one does, whose extra steps only add 0.2-0.9 ms (PR 32, `compact`
-#: against `tierB` at 10k, 25k, 50k, 75k peers), and 3.5 M rows out of 3.5 M
-#: cost 5.3 ns each (PR 30, graph seed 2); the full table of 4.1 M rows
-#: charges a head row 7.7 ns against 5.1 (PR 30), the whole gather 28.6 ms
-#: against the compact form's 21.8 (PR 32); and a compact table of 4.62 M
-#: rows read whole (``eth2-100k``'s graph, 6 words) costs 19 ns a row, 88.4
-#: ms against 52.0 out of the full 6.5 M-row table (PR 32). So the compact
-#: table pays where it brings the table from beyond this size to within it.
+#: Where the gather's price doubles, in TILE-ROWS: a table's rows times the
+#: sublane tiles a row of its words pads to (``tile_rows``; 32 B a tile-row).
+#: It is where the padded table stops fitting the chip's fast memory space
+#: beside the indices and whatever else is live (``S(1)`` on the gather's
+#: operands in the compiled text; ``scripts/window_whiles.py``). Same script,
+#: the whole tiered gather K-major, my chip runs, PR 36
+#: (``docs/data/gather_law_v5e_*_pr36.json``), nothing else live:
+#: - flat in the width inside one tile: a compact table of 2,512,481 rows
+#:   gives 15.37 / 15.74 / 16.02 / 15.00 ms at 5 / 6 / 7 / 8 words, and
+#:   44.32 / 45.26 / 46.67 ms at 9 / 13 / 16 (two tiles, 5.02 M tile-rows);
+#: - one tile: compact tables of 2.71, 2.91, 3.11, 3.32 and 3,515,070 rows
+#:   give a row up at 5.8-6.4 ns at 5 and 8 words, those of 3,765,605 and
+#:   4,016,456 rows at 9.4-10.1;
+#: - two tiles (13 words): 2.42, 3.01 and 3,515,382 tile-rows read at
+#:   9.4-9.6 ns a row (1.55 x the one-tile price, where two slices cost
+#:   2.0-2.3 x: under the cliff a wide plane crosses whole), 4,016,332 and
+#:   every larger one at 17.7-18.4.
+#: So alone the cliff lies between 3.52 and 3.77 M tile-rows at either width.
+#: Inside a window it comes sooner: ``sybil-50k``'s 13-word table of 3,503,360
+#: tile-rows is read from HBM at 22 ns a row with five more K-wide planes live
+#: (PERF.md §5), ``random-100k``'s 5-word tables of 2.51 M from the fast
+#: space. The constant is the round number under both: no table up to it was
+#: read slowly alone, the smallest a window read slowly lies 0.1 % over it
+#: (smaller ones go to HBM for their neighbours' sake, as four of that cell's
+#: eight sub-round tables do: no rule of sizes sees that). Earlier points, in
+#: rows of one tile (PR 30, 32): full tables of 0.36-2.7 M rows as cheap as a
+#: compact one; 3.5 M rows out of 3.5 M at 5.3 ns; the full table of 4.1 M
+#: rows 7.7 ns a head row against 5.1; a compact table of 4.62 M rows read
+#: whole (``eth2-100k``'s graph, 6 words) 19 ns a row, 88.4 ms against 52.0
+#: out of the full 6.5 M-row table. So the compact table pays where it brings
+#: the table from beyond the cliff to within it (``compact_pays``), and a
+#: plane wider than a tile crosses in tile-wide slices where that brings each
+#: slice's table within it (``word_slices``).
 TABLE_CLIFF_ROWS = 3_500_000
 
 
@@ -339,6 +374,11 @@ TABLE_CLIFF_ROWS = 3_500_000
 #: else it goes through a 1-D buffer one word an iteration, and back (a
 #: quarter of the round at 100k peers, PERF.md §6, PR 34).
 LANES = 128
+
+#: Words of a sublane tile: the table lies words-major on the chip, a row
+#: pads to whole tiles of them, and the gather's price is flat in the width
+#: inside one: 5 to 8 words cost the same (above).
+TILE_WORDS = 8
 
 
 def lane_padded(n: int) -> int:
@@ -366,6 +406,8 @@ class Tiers:
                           # ascending and duplicate-free: where the tail's
                           # rows go, and the rows a compact table appends
     compact: bool = struct.field(pytree_node=False, default=False)
+    #: the cliff ``word_slices`` holds a wide plane's table against
+    cliff: int = struct.field(pytree_node=False, default=TABLE_CLIFF_ROWS)
 
     @property
     def rows(self) -> int:
@@ -381,11 +423,29 @@ class Tiers:
                 else self.head.shape[1] * k)
 
 
-def compact_pays(full_rows, compact_rows):
+def tile_rows(rows: int, words: int) -> int:
+    """What a table of ``rows`` rows of ``words`` words weighs against the
+    cliff: its rows times the sublane tiles a row pads to."""
+    return rows * -(-words // TILE_WORDS)
+
+
+def compact_pays(full_rows, compact_rows, cliff=TABLE_CLIFF_ROWS):
     """Whether the big gather should read the compact table of
     ``compact_rows`` rows and not the full one of ``full_rows``, by the
-    law above (elementwise over arrays)."""
-    return (compact_rows <= TABLE_CLIFF_ROWS) & (TABLE_CLIFF_ROWS < full_rows)
+    law above (elementwise over arrays; both one tile wide)."""
+    return (compact_rows <= cliff) & (cliff < full_rows)
+
+
+def word_slices(table_rows: int, words: int,
+                cliff: int = TABLE_CLIFF_ROWS) -> list[tuple[int, int]]:
+    """The column slices ``[lo, hi)`` a plane of ``words`` words a row
+    crosses in, out of a table of ``table_rows`` rows: one tile of words a
+    slice where the whole table lies beyond the cliff and a slice's table
+    does not, else the plane whole (always, up to one tile of words)."""
+    if tile_rows(table_rows, words) > cliff >= table_rows:
+        return [(lo, min(lo + TILE_WORDS, words))
+                for lo in range(0, words, TILE_WORDS)]
+    return [(0, words)]
 
 
 def tier_cost_ns(col_fill, n: int) -> np.ndarray:
@@ -419,11 +479,13 @@ def pick_k0(col_fill, n: int) -> int:
 
 
 def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
-               compact: bool | None = None) -> Tiers | None:
+               compact: bool | None = None,
+               cliff: int = TABLE_CLIFF_ROWS) -> Tiers | None:
     """Plan the tiered gather of one static graph, on the host. ``None``
-    is K0 = K: the one full gather, today's program. ``k0`` and
-    ``compact`` are for the tests; ``Net.build`` lets ``pick_k0`` and
-    ``compact_pays`` derive them from the graph."""
+    is K0 = K: the one full gather, today's program. ``k0``, ``compact``
+    and ``cliff`` are for the tests and the law's script; ``Net.build``
+    lets ``pick_k0`` and ``compact_pays`` derive the first two from the
+    graph and leaves the cliff where the chip put it."""
     n, k = perm.shape
     if k0 is None:
         k0 = pick_k0(nbr_ok.sum(axis=0), n)
@@ -432,7 +494,7 @@ def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
     n_pad = lane_padded(n)
     cols, rows = np.nonzero(nbr_ok[:, k0:].T)   # K-major: ascending
     if compact is None:
-        compact = compact_pays(n_pad * k, n_pad * k0 + rows.size)
+        compact = compact_pays(n_pad * k, n_pad * k0 + rows.size, cliff)
     # every slot's row in the table, by its full-space index n*K + k
     addr = (np.arange(k, dtype=np.int32)[None, :] * n_pad
             + np.arange(n, dtype=np.int32)[:, None])
@@ -450,19 +512,36 @@ def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
     # cast on the host: a device-side convert is one more program to compile
     i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
     return Tiers(head=i32(head), tail_src=i32(src[rows, cols + k0]),
-                 tail_dst=i32(cols * n_pad + rows), compact=bool(compact))
+                 tail_dst=i32(cols * n_pad + rows), compact=bool(compact),
+                 cliff=int(cliff))
 
 
 def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
     """``edge_permute(x, edge_perm)`` bit for bit on every slot, absent
     ones included, addressing ``tiers.rows`` rows instead of N*K: an
     absent slot of the tail keeps its own entry, as its self-pointing row
-    of ``edge_perm`` gives it. One parameter, ``tiers.compact``: which
-    table the big gather reads."""
+    of ``edge_perm`` gives it. Two parameters the plan and the plane give:
+    ``tiers.compact``, which table the big gather reads, and
+    ``word_slices``, whether a ``[N, K, w]`` plane wider than a tile
+    crosses in column slices, each out of a table of its own through the
+    same index plane. The tail stays one scatter."""
     n, k = x.shape[:2]
     k0, n_pad = tiers.head.shape
     trail = x.shape[2:]
-    _tally("edge", x, rows=tiers.rows, table_rows=tiers.table_rows(k))
+    table_rows, words = tiers.table_rows(k), math.prod(trail)
+    # no plane of more than one trailing axis is a tile wide
+    cuts = (word_slices(table_rows, words, tiers.cliff) if len(trail) == 1
+            else [(0, words)])
+    whole = len(cuts) == 1
+    # every slice gathers (and appends its rows); the scatter is one
+    _tally("edge", x, table_rows=table_rows, sliced=not whole,
+           rows=tiers.rows
+           + (len(cuts) - 1) * (tiers.rows - tiers.tail_dst.size),
+           tile_rows=tile_rows(table_rows, max(hi - lo for lo, hi in cuts)))
+    # a table a slice; and the slices' rows side by side again
+    cols = lambda a: [a] if whole else [a[..., lo:hi] for lo, hi in cuts]
+    join = lambda parts: (parts[0] if whole
+                          else jnp.concatenate(parts, axis=-1))
     # the [K, Np, ...] view: the planes live N-minor, so XLA keeps it
     k_major = (1, 0) + tuple(range(2, x.ndim))
     xt = jnp.pad(x, ((0, n_pad - n),) + ((0, 0),) * (x.ndim - 1)
@@ -471,18 +550,19 @@ def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
     onto_tail = lambda rows: tail.at[tiers.tail_dst].set(
         rows, unique_indices=True, indices_are_sorted=True)
     if tiers.compact:
-        table = jnp.concatenate(
-            [xt[:k0].reshape((k0 * n_pad,) + trail), tail[tiers.tail_dst]])
-        # ONE gather: the head block, then the T rows the tail slots take
-        moved = table[
-            jnp.concatenate([tiers.head.reshape(-1), tiers.tail_src])]
+        tables = [jnp.concatenate([h, t[tiers.tail_dst]]) for h, t in zip(
+            cols(xt[:k0].reshape((k0 * n_pad,) + trail)), cols(tail))]
+        # ONE gather a table: the head block, then the T rows the tail
+        # slots take
+        index = jnp.concatenate([tiers.head.reshape(-1), tiers.tail_src])
+        moved = join([table[index] for table in tables])
         tail = onto_tail(moved[k0 * n_pad:])
         head = moved[:k0 * n_pad]
     else:
         # the head's gather gives its block whole: no slice of a joined one
-        table = xt.reshape((k * n_pad,) + trail)
-        tail = onto_tail(table[tiers.tail_src])
-        head = table[tiers.head.reshape(-1)]
+        tables = cols(xt.reshape((k * n_pad,) + trail))
+        tail = onto_tail(join([table[tiers.tail_src] for table in tables]))
+        head = join([table[tiers.head.reshape(-1)] for table in tables])
     out = jnp.concatenate(
         [head.reshape((k0, n_pad) + trail),
          tail.reshape((k - k0, n_pad) + trail)], axis=0)

@@ -224,6 +224,13 @@ class TracedWindow:
     #: K*Np or K0*Np + T through a plan's full or compact table; ``None``
     #: for rolls and replays
     edge_table_rows: int | None = None
+    #: the same in tile-rows, the unit the chip charges: rows times the
+    #: sublane tiles a row of the widest slice read pads to
+    #: (``ops/edges.tile_rows``)
+    edge_table_tile_rows: int | None = None
+    #: edge gathers of ONE step call that crossed in column slices
+    #: (``ops/edges.word_slices``); 0 for rolls, ``None`` for replays
+    edge_sliced_calls_per_dispatch: float | None = None
     _stages: dict | None = None
     _parts: dict | None = None
 
@@ -259,12 +266,10 @@ KEPT_WINDOWS = 16
 _WINDOWS: collections.deque = collections.deque(maxlen=KEPT_WINDOWS)
 
 
-def note_window(jitted, args: tuple, kwargs: dict,
-                edge_rows: float | None = None,
-                table_rows: int | None = None) -> None:
+def note_window(jitted, args: tuple, kwargs: dict, **counted) -> None:
     """Called from inside a window's traced Python body: note its
-    signature once, with the edge rows its trace counted per step call
-    and the rows of the table its edge gathers read.
+    signature once, with what its trace counted of its edge gathers
+    (``counted``: the ``edge_*`` fields of ``TracedWindow``).
     Outside a trace (``jax.disable_jit``) nothing reaches the device as a
     module and nothing is noted."""
     import jax
@@ -287,7 +292,7 @@ def note_window(jitted, args: tuple, kwargs: dict,
                 and w.sharded == sharded):
             return          # a retrace of what is noted (``stages`` lowers)
     _WINDOWS.append(TracedWindow(jitted, "jit_" + jitted.__name__,
-                                 signature, sharded, edge_rows, table_rows))
+                                 signature, sharded, **counted))
 
 
 def traced_windows() -> list:

@@ -2,8 +2,9 @@
 ``ops/edges.edge_permute`` costs on the chip, by rows, by table size, by
 row width and by K, on the benchmark's own random graphs; what a scatter
 of a short row list costs; and what a tiered gather made of them costs
-whole. PR 30's first chip call, PR 32's and PR 34's, and the source of the
-three constants ``ops/edges.pick_k0`` prices a graph with.
+whole. PR 30's first chip call, PR 32's, PR 34's and PR 36's, and the source
+of the constants ``ops/edges.pick_k0`` and ``word_slices`` price a graph
+and a plane with.
 
     python scripts/gather_law.py [--n 100000] [--d 10] [--reps 7]
         [--graph random_connect|subnet_connect] [--k0 20 24 28]
@@ -40,7 +41,11 @@ Cases (``rows_out`` gathered from a ``rows_table``-row table, W words):
             PR 34, compact table: the same gather with its rows K-major
             over the lane-padded peer axis (``k*Np + n``), so that no
             per-word relayout loop stands around it
-  kmajorB   the same out of the full ``[K*Np]`` table (``compact=False``)
+  kmajorB   the same out of the full ``[K*Np]`` table (``compact=False``);
+            both cross a plane of any width WHOLE (a plan with no cliff)
+  kmajorS, kmajorBS   the same two with every plane wider than a tile
+            crossing in tile-wide column slices (``ops/edges.word_slices``
+            with the cliff at the table's own size; widths over a tile only)
   whole     ``edge_permute`` as the engine calls it ([N, K, W] in and out)
 """
 
@@ -115,8 +120,10 @@ def tier_indices(perm, nbr_ok, k0):
         "tail_dst": (tr * (k - k0) + tc).astype(np.int32),
         "compact_head": compact[:, :k0],
         "compact_tail_src": compact[tr, tc + k0],
-        "kmajor": edges.plan_tiers(perm, nbr_ok, k0, compact=True),
-        "kmajorB": edges.plan_tiers(perm, nbr_ok, k0, compact=False),
+        **{case + form: plan.replace(cliff=cliff)
+           for case, compact in (("kmajor", True), ("kmajorB", False))
+           for plan in [edges.plan_tiers(perm, nbr_ok, k0, compact=compact)]
+           for form, cliff in (("", 0), ("S", plan.table_rows(k)))},
     }
 
 
@@ -283,8 +290,12 @@ def main(argv=None) -> int:
             timed("compact", w, n * k0 + 3 * n_t, n * k0 + n_t, compact, x,
                   t["compact_head"], t["compact_tail_src"], t["tail_dst"],
                   k0=k0, tail=n_t, expect=want)
-            for case in ("kmajor", "kmajorB"):
+            for case in ("kmajor", "kmajorB", "kmajorS", "kmajorBS"):
                 plan = t[case]
+                slices = len(edges.word_slices(plan.table_rows(k), w,
+                                               plan.cliff))
+                if case.endswith("S") and slices == 1:
+                    continue
 
                 def kmajor(x, head, tail_src, tail_dst, plan=plan):
                     return edges.edge_permute_tiered(x, plan.replace(
@@ -292,7 +303,7 @@ def main(argv=None) -> int:
 
                 timed(case, w, plan.rows, plan.table_rows(k), kmajor, x,
                       plan.head, plan.tail_src, plan.tail_dst, k0=k0,
-                      tail=n_t, expect=want)
+                      tail=n_t, slices=slices, expect=want)
 
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:

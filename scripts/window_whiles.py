@@ -16,11 +16,18 @@ the ops inside their bodies, and those whose tuple carries such a buffer,
 after the sha256 of the window's LOWERED text (StableHLO, constants and
 all: two commits whose windows lower to one text run one program).
 ``--lower-only`` stops there (seconds, where the compile takes a minute).
+``edge_gathers`` counts the gathers under ``gs.edge_gather`` by the stage
+they serve, the rows they give, the rows of their table and the words of a
+row, with the memory space XLA gave table, indices and output (``S(1)`` is
+the fast one: PERF.md §5 item 5): a plane that crossed in column slices
+(``ops/edges.word_slices``) shows as one gather a slice, each of at most a
+tile of words, not as one of the whole width.
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
 import hashlib
 import json
 import os
@@ -59,6 +66,46 @@ def whiles(text: str) -> list[dict]:
                                    FLAT_BUFFER.findall(op.group(2))],
             })
     return out
+
+
+def edge_gathers(text: str) -> list[dict]:
+    """The gathers of a compiled module's text that stand under
+    ``gs.edge_gather``, equal ones counted together: the stage around the
+    scope, output rows, table rows, words a row, which of table, indices
+    (the fusion's operands) and output (its result) lie in ``S(1)``, and
+    the fusions that hold them (two gathers in one multi-output fusion
+    keep both tables live at once)."""
+    name = r"%?([\w.\-]+)"
+    lines = text.splitlines()
+    defs = {m.group(1): line.split(" = ", 1)[1] for line in lines
+            if (m := re.match(rf"^\s+(?:ROOT )?{name} = ", line))}
+    callers = {m.group(2): m.group(1) for line in lines
+               if (m := re.search(rf" = (\S+) fusion\(.* calls={name}", line))}
+
+    def fast(op):    # the operand's parameter, through the fusion's own ops
+        while " parameter(" not in defs[op]:
+            op = re.search(rf" [\w\-]+\({name}", defs[op]).group(1)
+        return "S(1)" in defs[op].split(" ")[0]
+
+    found, fusions, comp = collections.Counter(), {}, None
+    for line in lines:
+        head = re.match(rf"^(?:ENTRY )?{name} \(.*\{{\s*$", line)
+        if head:
+            comp = head.group(1)
+        op = re.search(rf" = u32\[(\d+),(\d+)\]\S* gather\({name}, {name}\)",
+                       line)
+        stage = re.search(r"gs\.(\w+)/gs\.edge_gather/", line)
+        if op and stage:
+            rows, words, table, index = op.groups()
+            table_rows = re.match(r"u32\[(\d+),", defs[table]).group(1)
+            key = (stage.group(1), int(rows), int(table_rows), int(words),
+                   fast(table), fast(index), "S(1)" in callers.get(comp, ""))
+            found[key] += 1
+            fusions.setdefault(key, set()).add(comp)
+    return [dict(zip(("stage", "rows", "table_rows", "words", "table_fast",
+                      "indices_fast", "output_fast"), key), count=n,
+                 fusions=len(fusions[key]))
+            for key, n in sorted(found.items(), key=str)]
 
 
 def main(argv=None) -> int:
@@ -123,6 +170,7 @@ def main(argv=None) -> int:
         "flat_u32_words": sorted(
             (w for f in flat for w in f["flat_u32_words"]), reverse=True),
         "gathers": len(re.findall(r" gather\(", text)),
+        "edge_gathers": edge_gathers(text),
     }))
     return 0
 

@@ -90,19 +90,32 @@ def test_default_phase_step_compiles_at_bench_size(one_chip, bench_prng):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def test_tiered_gather_compiles_without_a_relayout_loop(one_chip):
+@pytest.mark.parametrize("words", [5, 13])
+def test_tiered_gather_compiles_without_a_relayout_loop(one_chip, words):
     """The planned edge gather at size, composed and consumed as the data
     round does it (planes of a ``[N, K]`` mask and ``[N, W]`` words in, an
-    OR over K out). XLA merges ``[K, Np] -> [K*Np]`` as a bitcast only
+    OR over K out), at the sub-rounds' width and the control head's. XLA
+    merges ``[K, Np] -> [K*Np]`` as a bitcast only
     because the plan's peer axis is a whole number of lanes; the N-major
     order (and a K-major one over N = 100,000 = 781.25 x 128) went through
     a 1-D ``u32[...]{0:T(1024)}`` buffer, one word an iteration, four
     ``while`` ops in this program and a quarter of the round on the chip
-    (PERF.md §6, PR 34). No tier-1 net is large enough to show them."""
+    (PERF.md §6, PR 34). No tier-1 net is large enough to show them.
+
+    The 13 words are two sublane tiles, and the compact table of 2.51 M
+    rows read that wide lies beyond the cliff: they cross as TWO gather
+    fusions of 8 and 5 words, each out of a table of its own (one fusion
+    over both would hold the whole 160 MB again; PERF.md §6, PR 36)."""
+    import os
+    import sys
+
     from go_libp2p_pubsub_tpu import graph
     from go_libp2p_pubsub_tpu.ops import edges
 
-    words = 5
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "scripts"))
+    import window_whiles
+
     topo = graph.random_connect(BENCH_N, 10, seed=1)
     tiers = edges.plan_tiers(
         edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok), topo.nbr_ok)
@@ -111,7 +124,9 @@ def test_tiered_gather_compiles_without_a_relayout_loop(one_chip):
 
     def gathered(mask, payload):
         x = mask[:, :, None] & payload[:, None, :]
-        got = edges.edge_permute_tiered(x, tiers)
+        with jax.named_scope("gs.data_round"), \
+                jax.named_scope("gs.edge_gather"):
+            got = edges.edge_permute_tiered(x, tiers)
         return jax.lax.reduce(got & mask[:, :, None], jnp.uint32(0),
                               jax.lax.bitwise_or, (1,))
 
@@ -121,6 +136,10 @@ def test_tiered_gather_compiles_without_a_relayout_loop(one_chip):
     ).compile().as_text()
     assert " gather(" in text and " scatter(" in text
     assert " while(" not in text
+    rows = tiers.table_rows(topo.nbr.shape[1])
+    big = [(g["words"], g["count"], g["fusions"])
+           for g in window_whiles.edge_gathers(text) if g["rows"] == rows]
+    assert big == {5: [(5, 1, 1)], 13: [(5, 1, 1), (8, 1, 1)]}[words]
 
 
 # ---------------------------------------------------------------------------

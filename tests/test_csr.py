@@ -329,6 +329,45 @@ def test_gossipsub_phase_tiered_gather_parity(compact):
         assert_trees_equal(full, run(tiers), f"phase tiers K0={k0}")
 
 
+@pytest.mark.parametrize("compact", [False, True])
+def test_gossipsub_phase_sliced_control_head_parity(compact):
+    """A scored phase window whose control head's stacked plane (12 words
+    at 128 message slots: two tiles) crosses in column slices, the cliff
+    forced down to its table's size, leaves the same full state tree as
+    the same plan crossing it whole: the slicing does not reach
+    ``control_unpack``'s word offsets."""
+    from go_libp2p_pubsub_tpu.ops import edges
+
+    r, m = 4, 128
+    topo = ragged_topo()
+    subs = graph.subscribe_all(N, 1)
+    sp = default_peer_score_params(1)
+    po, pt, pv = publish_schedule(3 * r)
+    base = Net.build(topo, subs)
+    k = base.max_degree
+    whole = edges.plan_tiers(np.asarray(base.edge_perm), topo.nbr_ok, k // 2,
+                             compact=compact)
+
+    def run(tiers):
+        net = base.replace(tiers=tiers)
+        cfg = _gossip_cfg("dense", heartbeat_every=r)
+        st = GossipSubState.init(net, m, cfg, score_params=sp, seed=0)
+        step = make_gossipsub_phase_step(cfg, net, r, score_params=sp)
+        tally: list = []
+        with edges.tally_index_rows(tally):
+            for p in range(3):
+                st = step(st, po[p * r:(p + 1) * r], pt[:r], pv[:r],
+                          do_heartbeat=True)
+        return st, sum(v for kind, v in tally if kind == "sliced")
+
+    full, none_sliced = run(whole)
+    got, one_sliced = run(whole.replace(cliff=whole.table_rows(k)))
+    assert int(full.core.tick) == 3 * r
+    # the one trace of the step: the control head's call, no sub-round's
+    assert (none_sliced, one_sliced) == (0, 1)
+    assert_trees_equal(full, got, f"phase sliced compact={compact}")
+
+
 def test_scanned_window_parity():
     """driver.make_scan over a CSR step == the dense python loop — the
     scanned window carries the sparse exchange inside one program."""

@@ -4,8 +4,10 @@ full gather ``edge_permute(x, edge_perm)`` on every slot, absent ones
 included, on UNMASKED planes, through one gather out of a compact table
 (the head columns plus the tail's present rows), addressed K-major over
 the lane-padded peer axis (row ``k*Np + n``); planned only where the code
-can see that it pays; counted by the rows it addresses and the rows of
-the table it reads."""
+can see that it pays; a plane wider than one sublane tile of words in
+tile-wide column slices where only the slices' tables lie under the cliff
+(``word_slices``); counted by the rows it addresses, the rows and
+tile-rows of the table it reads and the calls that were sliced."""
 
 from __future__ import annotations
 
@@ -82,8 +84,102 @@ def test_tiered_gather_equals_the_full_gather_on_every_slot(name):
                     err_msg=f"{what} K0={k0} compact={compact}")
 
 
+def sliced(tiers, k):
+    """The same plan with the cliff at its table's own size: every plane
+    wider than a tile crosses in slices."""
+    return tiers.replace(cliff=tiers.table_rows(k))
+
+
+@pytest.mark.parametrize("compact", [True, False])
+@pytest.mark.parametrize("w,slices", [(8, 1), (9, 2), (13, 2), (17, 3)])
+def test_sliced_gather_equals_the_full_gather_on_every_slot(w, slices,
+                                                            compact):
+    for name in ("random-97-d10", "random-129-d3", "isolated-peer"):
+        topo = TOPOS[name]()
+        perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
+        n, k = perm.shape
+        rng = np.random.default_rng(w)
+        x = jnp.asarray(
+            rng.integers(0, 2**32, size=(n, k, w), dtype=np.uint32))
+        want = edges.edge_permute(x, jnp.asarray(perm))
+        for k0 in every_k0(k):
+            tiers = edges.plan_tiers(perm, topo.nbr_ok, k0, compact=compact)
+            rows = tiers.table_rows(k)
+            assert len(edges.word_slices(rows, w, tiers.cliff)) == 1
+            tiers = sliced(tiers, k)
+            assert len(edges.word_slices(rows, w, tiers.cliff)) == slices
+            got = jax.jit(edges.edge_permute_tiered)(x, tiers)
+            np.testing.assert_array_equal(
+                np.asarray(got), np.asarray(want),
+                err_msg=f"{name} K0={k0} compact={compact}")
+
+
+WHOLE = lambda w: [(0, w)]
+TILES = lambda w: [(lo, min(lo + 8, w)) for lo in range(0, w, 8)]
+
+
+@pytest.mark.parametrize("rows,w,want,why", [
+    (2_512_481, 13, TILES, "random-100k's control head out of the compact "
+     "table: 5.02 M tile-rows, a slice 2.51 M"),
+    (2_512_481, 5, WHOLE, "its sub-round gathers: one tile"),
+    (1_751_680, 13, TILES, "sybil-50k's control head out of the full "
+     "table: 3,503,360 tile-rows, a slice 1.75 M"),
+    (1_751_680, 5, WHOLE, "its sub-round gathers"),
+    (6_506_240, 17, WHOLE, "eth2-100k's control head: a slice of the full "
+     "table is itself beyond the cliff"),
+    (6_506_240, 6, WHOLE, "its sub-round gathers"),
+    (364_032, 6, WHOLE, "random-10k-t8's control head: one tile"),
+    (364_032, 13, WHOLE, "a small table read two tiles wide: 0.73 M "
+     "tile-rows, under the cliff"),
+    (1_750_000, 16, WHOLE, "two tiles to the tile-row under the cliff"),
+    (1_750_001, 16, TILES, "and one tile-row over it"),
+    (3_500_000, 9, TILES, "a slice's table on the cliff itself"),
+    (3_500_001, 9, WHOLE, "and one row beyond it"),
+    (10**9, 8, WHOLE, "up to one tile of words nothing splits"),
+    (10**9, 1, WHOLE, "a plane of single words"),
+])
+def test_word_slices_by_hand(rows, w, want, why):
+    assert edges.TILE_WORDS == 8 and edges.TABLE_CLIFF_ROWS == 3_500_000
+    assert edges.word_slices(rows, w) == want(w), why
+    assert edges.tile_rows(rows, w) == rows * -(-w // 8)
+    # the cliff is an argument for the tests: none, and at the table's size
+    assert edges.word_slices(rows, w, cliff=0) == WHOLE(w)
+    assert edges.word_slices(rows, w, cliff=rows) == TILES(w)
+
+
+@pytest.mark.parametrize("compact", [True, False])
+def test_a_wide_plane_crosses_in_two_gathers_and_one_scatter(compact):
+    """The jaxpr of a planned net's gather: a 13-word plane beyond the
+    cliff crosses in two big gathers of at most a tile of words, each out
+    of a table of its own through the same indices, and ONE scatter of
+    all 13; a 5-word plane, and a 13-word one under the cliff, in one."""
+    topo = graph.random_connect(3000, d=4, seed=5)
+    net = Net.build(topo, graph.subscribe_all(3000, 1))
+    n, k = topo.nbr.shape
+    tiers = edges.plan_tiers(np.asarray(net.edge_perm), topo.nbr_ok,
+                             compact=compact)
+    k0, n_pad = tiers.head.shape
+    tail = int(topo.nbr_ok[:, k0:].sum())
+    big_rows = n_pad * k0 + (tail if compact else 0)
+
+    def big(w, tiers):
+        x = jnp.zeros((n, k, w), jnp.uint32)
+        eqns = jax.make_jaxpr(net.replace(tiers=tiers).edge_gather)(x).eqns
+        (scatter,) = [e for e in eqns if e.primitive.name == "scatter"]
+        assert scatter.invars[2].aval.shape == (tail, w)
+        return [(e.invars[0].aval.shape, e.outvars[0].aval.shape[1])
+                for e in eqns if e.primitive.name == "gather"
+                and e.outvars[0].aval.shape[0] == big_rows]
+
+    table = (tiers.table_rows(k),)
+    assert big(5, tiers) == big(5, sliced(tiers, k)) == [(table + (5,), 5)]
+    assert big(13, tiers) == [(table + (13,), 13)]
+    assert big(13, sliced(tiers, k)) == [(table + (8,), 8), (table + (5,), 5)]
+
+
 @pytest.mark.parametrize("name", sorted(TOPOS))
 def test_plan_lives_in_the_compact_table(name):
+
     """Every index of the plan lies inside ``[0, K0*Np + T)``; the rows the
     table appends are exactly the present tail slots, each once, K-major;
     each index is the compact address of the slot's partner in
@@ -244,13 +340,45 @@ def test_tally_records_the_plan_rows():
         np.asarray(net.edge_perm), topo.nbr_ok, compact=True))
     with edges.tally_index_rows(rows):
         jax.eval_shape(compact.edge_gather, x)
+    # five words are one tile: a table's tile-rows are its rows
     assert rows == [("edge", n_pad * k0 + 2 * tail), ("table", n_pad * k),
-                    ("edge", n * k), ("table", n * k), ("peer", n * k),
+                    ("tile_rows", n_pad * k),
+                    ("edge", n * k), ("table", n * k), ("tile_rows", n * k),
+                    ("peer", n * k),
                     ("edge", n_pad * k0 + 3 * tail),
-                    ("table", n_pad * k0 + tail)]
+                    ("table", n_pad * k0 + tail),
+                    ("tile_rows", n_pad * k0 + tail)]
     assert net.tiers.rows == n_pad * k0 + 2 * tail < n * k
     assert edges.edge_table_rows(rows) == n_pad * k
-    assert edges.edge_table_rows(rows[-2:]) == n_pad * k0 + tail < n * k
+    assert edges.edge_table_rows(rows[-3:]) == n_pad * k0 + tail < n * k
+    assert edges.edge_table_rows(rows, "tile_rows") == n_pad * k
+    # 13 words are two tiles, whichever gather reads them; sliced, every
+    # slice gathers (and appends its rows), the scatter stays one, and
+    # the tables are a tile wide
+    wide = jnp.zeros((n, k, 13), jnp.uint32)
+    rows.clear()
+    with edges.tally_index_rows(rows):
+        edges.mark_dispatch()
+        jax.eval_shape(net.edge_gather, wide)
+        jax.eval_shape(net.replace(tiers=None).edge_gather, wide)
+        edges.mark_dispatch()
+        for plan in (net.tiers, compact.tiers):
+            jax.eval_shape(net.replace(tiers=sliced(plan, k)).edge_gather,
+                           wide)
+    assert rows == [
+        ("dispatch", None),
+        ("edge", n_pad * k0 + 2 * tail), ("table", n_pad * k),
+        ("tile_rows", 2 * n_pad * k),
+        ("edge", n * k), ("table", n * k), ("tile_rows", 2 * n * k),
+        ("dispatch", None),
+        ("edge", 2 * (n_pad * k0 + tail) + tail), ("table", n_pad * k),
+        ("tile_rows", n_pad * k), ("sliced", 1),
+        ("edge", 2 * (n_pad * k0 + 2 * tail) + tail),
+        ("table", n_pad * k0 + tail), ("tile_rows", n_pad * k0 + tail),
+        ("sliced", 1)]
+    assert edges.edge_table_rows(rows, "tile_rows") == 2 * n_pad * k
+    assert edges.edge_rows_per_dispatch(rows[:7], "sliced") == 0.0
+    assert edges.edge_rows_per_dispatch(rows[7:], "sliced") == 2.0
     # a tiered gather is still ONE gather set (hlo-audit, cost model)
     assert sets == ["edge", "edge", "peer"]
     banded = Net.build(graph.ring_lattice(64, d=4), graph.subscribe_all(64, 1))
@@ -260,6 +388,7 @@ def test_tally_records_the_plan_rows():
         jax.eval_shape(banded.peer_gather, jnp.zeros((64,), jnp.uint32))
     assert rows == [("edge", 0), ("peer", 0)]   # rolls address no row
     assert edges.edge_table_rows(rows) is None  # and read no table
+    assert edges.edge_table_rows(rows, "tile_rows") is None
 
 
 def compact_pays(col_fill, n, k0):
@@ -313,14 +442,18 @@ def test_pick_k0_is_the_least_cost_on_a_hand_made_histogram(col_fill, n, why):
         assert plan.compact == compact_pays(col_fill, n, k0)
 
 
-@pytest.mark.parametrize("form", ["compact", "tiered", "full", "rolls"])
+@pytest.mark.parametrize("form", ["compact", "tiered", "full", "rolls",
+                                  "sliced", "sliced-compact"])
 def test_a_traced_window_notes_the_table_its_gathers_read(form):
     """``driver``'s windows keep ``edge_table_rows`` beside the rows
-    addressed: how a reader of a traced run sees that the compact table
-    engaged."""
+    addressed, how a reader of a traced run sees that the compact table
+    engaged, and the same in tile-rows beside the sliced calls of a step:
+    how it sees that a wide plane crossed in slices (the window's plane
+    is 9 words, two tiles)."""
     from go_libp2p_pubsub_tpu import driver
     from go_libp2p_pubsub_tpu.perf import stages
 
+    tiles, sliced_calls = 2, 0.0
     if form == "rolls":
         topo = graph.ring_lattice(64, d=4)
         net = Net.build(topo, graph.subscribe_all(64, 1))
@@ -335,15 +468,22 @@ def test_a_traced_window_notes_the_table_its_gathers_read(form):
         assert tiers.table_rows(k) == int(
             n_pad * k0 + topo.nbr_ok[:, k0:].sum()) < n * k
         want = {"compact": tiers.table_rows(k), "tiered": n_pad * k,
-                "full": n * k}[form]
-        net = net.replace(tiers={"compact": tiers, "tiered": net.tiers,
-                                 "full": None}[form])
+                "full": n * k, "sliced": n_pad * k,
+                "sliced-compact": tiers.table_rows(k)}[form]
+        net = net.replace(tiers={
+            "compact": tiers, "tiered": net.tiers, "full": None,
+            "sliced": sliced(net.tiers, k),
+            "sliced-compact": sliced(tiers, k)}[form])
+        if form.startswith("sliced"):
+            tiles, sliced_calls = 1, 1.0
 
     def step(st, x):
         return net.edge_gather(st) ^ x
 
     win = driver.make_window(step, donate=False)
-    x = jnp.zeros(topo.nbr.shape + (2,), jnp.uint32)
+    x = jnp.zeros(topo.nbr.shape + (9,), jnp.uint32)
     jax.eval_shape(win, x, (jnp.stack([x, x]),))
     (entry,) = [w for w in stages.traced_windows() if w.jitted is win]
     assert entry.edge_table_rows == want
+    assert entry.edge_table_tile_rows == (want and tiles * want)
+    assert entry.edge_sliced_calls_per_dispatch == sliced_calls
