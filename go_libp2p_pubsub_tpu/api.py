@@ -1148,6 +1148,13 @@ class Network:
     def _publish(self, node: Node, topic: Topic, data: bytes) -> bytes:
         if not self.started:
             raise APIError("publish before start()")
+        if not node.up:
+            # a stopped process publishes nothing (upstream has no such
+            # event), and the engines take nothing of a down origin's
+            # publish either (the engines' pub_holder gate)
+            raise APIError(
+                f"publish on node {node.idx}, which is disconnected: a "
+                "node that is down publishes nothing (reconnect() first)")
         msg = rpc_pb2.Message(data=data, topic=topic.name)
         if self.sign_policy in (SignPolicy.STRICT_SIGN, SignPolicy.LAX_SIGN):
             # author override (WithMessageAuthor, pubsub.go:372-383): the
@@ -1197,6 +1204,19 @@ class Network:
             if not sub.cancelled:
                 sub._push(msg)
         return mid
+
+    def _next_publish(self):
+        """The next queued publish whose origin is still up, or None. One
+        whose node went down after it was queued is dropped here, before
+        the engine sees it: the stopped process never sent it."""
+        while self._pub_queue:
+            entry = self._pub_queue.popleft()
+            if self.nodes[entry[0]].up:
+                return entry
+            _log.warning("publish of node %d dropped: the node went down "
+                         "before the round that would have carried it",
+                         entry[0])
+        return None
 
     # -- peer exchange (host-side pxConnect) ------------------------------
 
@@ -1576,9 +1596,10 @@ class Network:
             pv = np.zeros(self.pub_width, np.int8)  # VERDICT_* codes
             batch = []
             for j in range(self.pub_width):
-                if not self._pub_queue:
+                entry = self._next_publish()
+                if entry is None:
                     break
-                origin, tid, verdict, msg, mid = self._pub_queue.popleft()
+                origin, tid, verdict, msg, mid = entry
                 po[j], pt[j], pv[j] = origin, tid, verdict
                 batch.append((msg, mid))
 
@@ -1663,9 +1684,10 @@ class Network:
             if flat >= cap:
                 break
             for j in range(self.pub_width):
-                if not self._pub_queue or flat >= cap:
+                entry = None if flat >= cap else self._next_publish()
+                if entry is None:
                     break
-                origin, tid, verdict, msg, mid = self._pub_queue.popleft()
+                origin, tid, verdict, msg, mid = entry
                 po[i, j], pt[i, j], pv[i, j] = origin, tid, verdict
                 batch.append((flat, msg, mid))
                 flat += 1

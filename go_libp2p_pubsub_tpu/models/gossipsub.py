@@ -1804,6 +1804,11 @@ def apply_peer_transitions(cfg: GossipSubConfig, net: Net, st: GossipSubState,
         score0 = clear_mesh_status(score0, down_nbr)
         clear_mask = (down_nbr & (st.scores >= 0)) | down_tr[:, None]
         score0 = clear_edges(score0, clear_mask)
+        # a RETAINED neighbour's first-delivery counter is reset too
+        # (removePeer zeroes firstMessageDeliveries where it keeps the
+        # stats, so a returning peer cannot cash in old credit)
+        score0 = score0.replace(
+            fmd=jnp.where(down_nbr[:, None, :], 0.0, score0.fmd))
     # a crashing node loses all soft state: seen-cache, forward set,
     # receipt history (it will re-receive after restart), mcache
     dlv0 = st.core.dlv.replace(
@@ -1855,6 +1860,14 @@ def apply_peer_transitions(cfg: GossipSubConfig, net: Net, st: GossipSubState,
         peerhave=jnp.where(down_edge, 0, st.peerhave),
         iasked=jnp.where(down_edge, 0, st.iasked),
         promise_mid=jnp.where(down_edge, -1, st.promise_mid),
+        # the backoff a crashing node holds of OTHERS goes with its router
+        # (a restarted process has an empty gs.backoff); its neighbours'
+        # entries for it stay: the map is keyed by peer id and RemovePeer
+        # leaves it to the lazy clear (gossipsub.go:545-562, 1596 ff.)
+        backoff_present=jnp.where(
+            down_tr[:, None, None], False, st.backoff_present),
+        backoff_expire=jnp.where(
+            down_tr[:, None, None], 0, st.backoff_expire),
         score=score0,
         up=eff_next,
     )
@@ -2451,12 +2464,23 @@ def make_gossipsub_step(
         ev_prev = st.core.events if telemetry is not None else None
         # ---- peer lifecycle transitions (dynamic_peers only) ------------
         if dynamic_peers:
-            st, live = apply_peer_transitions(cfg, net, st, up_next, tp_r)
+            # the churn part (perf/stages.py): the transitions, the traced
+            # liveness views, and the publish gate: a publish whose origin
+            # is down does not happen (its slot is allocated, nobody holds
+            # it: state.allocate_publishes), and the fanout update and the
+            # publish count read it as padding
+            with stages.part("churn"):
+                st, live = apply_peer_transitions(cfg, net, st, up_next, tp_r)
+                net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = (
+                    live_step_views(cfg, net, st, live, consts))
+                pub_holder = jnp.where(
+                    st.up[jnp.clip(pub_origin, 0)], pub_origin, -1)
         else:
-            live = None
-        net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = live_step_views(
-            cfg, net, st, live, consts
-        )
+            net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = live_step_views(
+                cfg, net, st, None, consts
+            )
+            pub_holder = None
+        pub_origin_held = pub_origin if pub_holder is None else pub_holder
 
         core = st.core
         tick = core.tick
@@ -2741,7 +2765,7 @@ def make_gossipsub_step(
         # 7. publishes + slot-recycle cleanup
         msgs, dlv, _slots, is_pub, keep_words, pub_words = allocate_publishes(
             core.msgs, dlv, tick, pub_origin, pub_topic, pub_valid,
-            stacked_clears=cfg.wire_coalesced,
+            stacked_clears=cfg.wire_coalesced, pub_holder=pub_holder,
         )
         # recycled-slot clearing must precede the put: the fresh publishes
         # land on exactly the recycled slots, and clearing after the OR
@@ -2772,7 +2796,7 @@ def make_gossipsub_step(
         # 7b. fanout slots for publishes to unjoined topics
         if cfg.fanout_slots > 0:
             st2 = update_fanout_on_publish(
-                cfg, net_l, st2, pub_origin, pub_topic,
+                cfg, net_l, st2, pub_origin_held, pub_topic,
                 jax.random.fold_in(jax.random.fold_in(core.key, tick), 0xFA40),
                 nbr_sub_words_l, thr=thr, msh=msh,
             )
@@ -2799,7 +2823,7 @@ def make_gossipsub_step(
 
         if cfg.count_events:
             events = accumulate_round_events(
-                events, info, jnp.sum(is_pub.astype(jnp.int32))
+                events, info, jnp.sum((pub_origin_held >= 0).astype(jnp.int32))
             )
             if router is not None:
                 if router.idontwant_eligible:

@@ -434,12 +434,24 @@ def make_gossipsub_phase_step(
         # ---- control head (once per phase) ------------------------------
         stage("control_head")
         if dynamic_peers:
-            st, live = apply_peer_transitions(cfg, net, st, up_next, tp_r)
+            # the churn part: what a dynamic build pays beyond a static
+            # one at the head (the transitions, the traced liveness views)
+            with stages.part("churn"):
+                st, live = apply_peer_transitions(cfg, net, st, up_next, tp_r)
+                net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = (
+                    live_step_views(cfg, net, st, live, consts))
+                # the publish gate: a publish whose origin is down (as
+                # this head left ``up``) does not happen. Its slot is
+                # allocated as the ring says and nobody holds it
+                # (state.PhasePubPlan); the fanout update and the publish
+                # count read its entry as padding
+                pub_holder = jnp.where(
+                    st.up[jnp.clip(pub_origin, 0)], pub_origin, -1)
         else:
-            live = None
-        net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = live_step_views(
-            cfg, net, st, live, consts
-        )
+            net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = live_step_views(
+                cfg, net, st, None, consts
+            )
+            pub_holder = None
         core = st.core
         tick0 = core.tick
         m = core.msgs.capacity
@@ -666,9 +678,10 @@ def make_gossipsub_phase_step(
         # — the dominant launch swarm at the 12.5k shard)
         plan = (
             PhasePubPlan(msgs, n_peers, tick0, pub_origin, pub_topic,
-                         pub_valid)
+                         pub_valid, pub_holder=pub_holder)
             if cfg.wire_coalesced else None
         )
+        pub_origin_held = pub_origin if pub_holder is None else pub_holder
 
         # membership word planes: on NARROW topic universes (T <= 8) the
         # planes are carried incrementally — a sub-round changes the
@@ -910,6 +923,8 @@ def make_gossipsub_phase_step(
                     allocate_publishes(
                         msgs, dlv, tick_i, pub_origin[i], pub_topic[i],
                         pub_valid[i], scatter_form=scatter_form,
+                        pub_holder=(None if pub_holder is None
+                                    else pub_holder[i]),
                     )
             # incremental membership-plane maintenance (narrow universes):
             # recycled columns clear, then each publish ORs its one-hot
@@ -1007,13 +1022,14 @@ def make_gossipsub_phase_step(
             # dup lane is deliberately NOT cleared — see its comment)
             accs = accs.keep(keep_w)
             if cfg.count_events:
-                n_pub = n_pub + jnp.sum(is_pub.astype(jnp.int32))
+                n_pub = n_pub + jnp.sum(
+                    (pub_origin_held[i] >= 0).astype(jnp.int32))
 
             if cfg.fanout_slots > 0:
                 fanout_st, fp_pack = update_fanout_on_publish(
                     cfg, net_l,
                     fanout_st.replace(core=fanout_st.core.replace(tick=tick_i)),
-                    pub_origin[i], pub_topic[i],
+                    pub_origin_held[i], pub_topic[i],
                     jax.random.fold_in(
                         jax.random.fold_in(core.key, tick_i), 0xFA40
                     ),

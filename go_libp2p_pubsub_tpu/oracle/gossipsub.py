@@ -12,8 +12,11 @@ node), threshold gating (gossip/publish/graylist), score-directed mesh
 maintenance incl. opportunistic grafting, IWANT promises at the
 reference's per-batch granularity (gossip_tracer.go:48-75 — one random
 message per IWANT batch, several batches outstanding per peer), fanout
-for publishes to unjoined topics (gossipsub.go:981-1002, 1517-1554), and
-the sybil adversary vector (control-plane-only peers).
+for publishes to unjoined topics (gossipsub.go:981-1002, 1517-1554), the
+sybil adversary vector (control-plane-only peers), and the peer LIFECYCLE
+(``step(publishes, up=...)``: handleDeadPeers pubsub.go:648-689, router
+RemovePeer gossipsub.go:545-562, score retention score.go:604-637),
+applied at phase heads, where ``ORACLE(h)`` batches its heartbeat.
 
 RNG parity with the vectorized engine is impossible by design (survey §7
 hard-part (d)); the oracle draws from its own `random.Random`, and parity
@@ -135,6 +138,81 @@ class OracleGossipSub:
         # (gossipsub.go:444-447 fanout + lastpub maps)
         self.fanout = [dict() for _ in range(n)]
         self.fanout_lastpub = [dict() for _ in range(n)]
+        # peer lifecycle: who is up (``step(up=...)`` moves it)
+        self.up = [True] * n
+
+    # -- peer lifecycle -------------------------------------------------------
+
+    def _fresh_peer(self, i):
+        """Peer i's soft state as ``__post_init__`` makes it: what a
+        process that crashed comes back with."""
+        self.seen[i] = set()
+        self.fwd[i] = set()
+        self.mesh[i] = {t: set() for t in self.mesh[i]}
+        self.backoff_expire[i] = {}
+        self.backoff_present[i] = set()
+        self.mcache[i] = [set() for _ in range(self.cfg.history_length)]
+        self.ihave_out[i], self.iwant_out[i] = {}, {}
+        self.graft_out[i], self.prune_out[i] = set(), set()
+        self.peerhave[i], self.iasked[i], self.served[i] = {}, {}, {}
+        self.fanout[i], self.fanout_lastpub[i] = {}, {}
+        for key in [key for key in self.first_round if key[0] == i]:
+            del self.first_round[key]
+            self.first_edge.pop(key, None)
+        for key in [key for key in self.pending if key[0] == i]:
+            del self.pending[key]
+        if self.score_params is not None:
+            self.oscore[i] = OracleScore(
+                self.score_params, heartbeat_every=self.cfg.heartbeat_every)
+            self.scores[i] = {}
+            self.promises[i] = {}
+
+    def _remove_peer(self, i, k):
+        """Peer i loses its neighbour on edge slot k, in upstream's order:
+        score removePeer (score.go:604-637: the stats go unless i scores
+        the neighbour below 0, by the heartbeat's memo as the engine reads
+        it; a retained neighbour's standing P3 deficit becomes P3b, its
+        first-delivery counter is reset and its mesh status dropped), then
+        router RemovePeer and handleDeadPeers (gossipsub.go:545-562,
+        pubsub.go:648-689): mesh, fanout, outboxes, gossip counters,
+        served counters and promises of the edge. The backoff map is keyed
+        by peer id and stays (cleared lazily)."""
+        if self.score_params is not None:
+            self.oscore[i].remove_peer(k, retain=self._score(i, k) < 0)
+        for m in self.mesh[i].values():
+            m.discard(k)
+        for f in self.fanout[i].values():
+            f.discard(k)
+        for box in (self.ihave_out[i], self.iwant_out[i], self.peerhave[i],
+                    self.iasked[i]):
+            box.pop(k, None)
+        self.graft_out[i] = {e for e in self.graft_out[i] if e[1] != k}
+        self.prune_out[i] = {e for e in self.prune_out[i] if e[1] != k}
+        for key in [key for key in self.served[i] if key[0] == k]:
+            del self.served[i][key]
+        if self.score_params is not None:
+            for key in [key for key in self.promises[i] if key[0] == k]:
+                del self.promises[i][key]
+
+    def _apply_transitions(self, up):
+        """Move ``self.up`` to ``up``: every neighbour of a departing peer
+        runs ``_remove_peer`` on its edge, the departing peer loses all its
+        soft state, a returning peer has come back with it fresh."""
+        topo = self.topo
+        gone = [i for i in range(topo.n_peers) if self.up[i] and not up[i]]
+        back = [i for i in range(topo.n_peers) if up[i] and not self.up[i]]
+        for q in gone:
+            # over the edges as they stand (a neighbour leaving at the same
+            # head still runs its own clean-up, and is wiped below)
+            for k, s, r in self._edges(q):
+                self._remove_peer(s, r)
+        for q in gone:
+            self._fresh_peer(q)
+            self.up[q] = False
+        for q in back:
+            self.up[q] = True
+        self.events[EV.REMOVE_PEER] += len(gone)
+        self.events[EV.ADD_PEER] += len(back)
 
     # -- score helpers ------------------------------------------------------
 
@@ -156,8 +234,10 @@ class OracleGossipSub:
     def _edges(self, i):
         """Valid (k, s, r): edge slot k to neighbor s whose reverse slot is r."""
         topo = self.topo
+        if not self.up[i]:
+            return
         for k in range(topo.max_degree):
-            if topo.nbr_ok[i, k]:
+            if topo.nbr_ok[i, k] and self.up[int(topo.nbr[i, k])]:
                 yield k, int(topo.nbr[i, k]), int(topo.rev[i, k])
 
     def _sample(self, pool, k):
@@ -200,6 +280,10 @@ class OracleGossipSub:
         self.cursor += 1
         self._recycle(slot)
         self.msgs[slot] = OMsg(slot, topic, origin, self.tick, valid, ignored)
+        if not self.up[origin]:
+            # a stopped process publishes nothing: the slot is taken as the
+            # ring says (the engine's table) and nobody holds the message
+            return slot
         self.seen[origin].add(slot)
         self.fwd[origin].add(slot)
         self.first_round[(origin, slot)] = self.tick
@@ -225,10 +309,16 @@ class OracleGossipSub:
 
     # -- one round ----------------------------------------------------------
 
-    def step(self, publishes=()):
+    def step(self, publishes=(), up=None):
+        """One round. ``up`` (``[N]`` bools, optional) is the liveness row:
+        it is applied at phase heads alone (ticks that are a multiple of
+        ``heartbeat_every``), before anything else of the round, as the
+        phase engine applies its row; between heads it is not read."""
         cfg, topo, subs = self.cfg, self.topo, self.subs
         n = topo.n_peers
         tick = self.tick
+        if up is not None and tick % cfg.heartbeat_every == 0:
+            self._apply_transitions(up)
 
         # 1. GRAFT/PRUNE ingest (handle_graft_prune)
         prune_resp = [set() for _ in range(n)]

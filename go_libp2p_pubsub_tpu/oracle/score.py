@@ -65,6 +65,23 @@ class OracleScore:
             ts.mfp += deficit * deficit
         ts.in_mesh = False
 
+    def remove_peer(self, p, retain: bool):
+        """removePeer (score.go:604-637). A neighbour that is not retained
+        loses its stats and its behaviour penalty. A retained one (scored
+        below 0) keeps decaying counters: its first-delivery counter is
+        reset, a standing P3 deficit becomes the sticky P3b penalty, and
+        it is in no mesh any more."""
+        for key in [key for key in self.stats if key[0] == p]:
+            if not retain:
+                del self.stats[key]
+                continue
+            self.prune(p, key[1])
+            ts = self.stats[key]
+            ts.fmd = 0.0
+            ts.graft_tick, ts.mesh_time, ts.mmd_active = -1, 0, False
+        if not retain:
+            self.bp.pop(p, None)
+
     # -- delivery attribution ----------------------------------------------
 
     def first_delivery(self, p, topic):

@@ -70,6 +70,13 @@ same compiled text.
   gater         the peer gater: the ``dup`` / ``rejw`` / ``ignw`` planes'
                 composition and folds, ``score.gater.gater_on_round`` with
                 its popcounts, ``gater_accept`` and ``gater_decay``
+  churn         what a ``dynamic_peers`` build pays at the head beyond a
+                static one: ``gossipsub.apply_peer_transitions`` (its two
+                ``[N]`` -> ``[N,K]`` liveness peer gathers, which stand
+                under ``gs.edge_gather`` too, and the dead-edge clears),
+                ``live_step_views``' traced arm, and the publish gate on
+                ``up[origin]`` (``pub_holder``, which ``state.PhasePubPlan``
+                / ``allocate_publishes`` take). A static window carries none
 
 Known limits. A fusion carries one ``op_name``, its root's: a fusion
 that spans two stages is booked to the root's. A tracer carries no
@@ -100,7 +107,10 @@ from typing import Any
 #: weights, a gater, a validation queue or an adversary vector, whose P3
 #: constants the same PR moved onto the clock of rounds; in an honest
 #: build the score terms' scope stands around weightless arithmetic, and
-#: an executable cached before it simply lacks the name nobody reads.)
+#: an executable cached before it simply lacks the name nobody reads.
+#: ``gsx.churn`` likewise: it is traced under ``dynamic_peers`` alone, and
+#: the PR that brought it put the publish gate into those programs, so no
+#: executable from before it has their key.)
 VERSION = 1
 
 PREFIX = "gs."
@@ -108,7 +118,7 @@ STAGES = ("control_head", "pub_plan", "data_round", "edge_gather", "deliver",
           "score", "heartbeat", "phase_tail")
 UNSCOPED = "unscoped"
 PART_PREFIX = "gsx."
-PARTS = ("fanout", "attrib", "gater")
+PARTS = ("fanout", "attrib", "gater", "churn")
 
 _SCOPE_RE = re.compile(re.escape(PREFIX) + r"([a-z_]+)")
 _PART_RE = re.compile(re.escape(PART_PREFIX) + r"([a-z_]+)")

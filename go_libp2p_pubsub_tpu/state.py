@@ -843,7 +843,7 @@ class PhasePubPlan:
     @stages.scope("pub_plan")
     def __init__(self, msgs: MsgTable, n_peers: int, tick0,
                  pub_origin: jax.Array, pub_topic: jax.Array,
-                 pub_valid: jax.Array):
+                 pub_valid: jax.Array, pub_holder: jax.Array | None = None):
         r, p = pub_origin.shape
         m = msgs.capacity
         # distinct slots within one sub-round keep the batched word
@@ -892,9 +892,16 @@ class PhasePubPlan:
         self.valid_words = bitset.pack(self._valid)         # [r+1, W]
         self.ignored_words = bitset.pack(self._ignored)
 
+        # the publishes somebody holds. A churn build hands in
+        # ``pub_holder`` [r, P]: ``pub_origin`` with -1 where the origin is
+        # DOWN. Such a publish keeps its slot, as the ring says, and nobody
+        # holds the message, the origin included (a stopped process
+        # publishes nothing)
+        held = (flat_pub if pub_holder is None
+                else (pub_holder >= 0).reshape(-1))
         # origin publish-bit planes, ONE batched scatter for the phase
         # (distinct slots per sub-round => distinct bits, add == or)
-        row_flat = jnp.where(flat_pub, pub_origin.reshape(-1), n_peers)
+        row_flat = jnp.where(held, pub_origin.reshape(-1), n_peers)
         self.rows = row_flat.reshape(r, p)  # [r, P], N on padding
         i_flat = jnp.arange(rp, dtype=jnp.int32) // p
         bit = jnp.uint32(1) << (sidx_flat % bitset.WORD).astype(jnp.uint32)
@@ -979,9 +986,16 @@ def allocate_publishes(
     pub_valid: jax.Array,   # [P] bool accept, or int VERDICT_* codes
     scatter_form: bool | None = None,
     stacked_clears: bool = False,
+    pub_holder: jax.Array | None = None,
 ):
     """Intern this round's publishes into table slots (rotating cursor),
     clearing recycled slots' bit columns everywhere.
+
+    ``pub_holder`` (churn builds: ``pub_origin`` with -1 where the origin
+    is DOWN) takes such a publish off the delivery state: its slot is
+    allocated as the ring says, and nobody holds the message, the origin
+    included (a stopped process publishes nothing); ``pub_words`` leaves
+    it out too.
 
     ``stacked_clears`` runs the four recycled-slot keep-ANDs (have / fwd
     / fe_words / pending) as ONE concatenated fold (bitset.masked_keep)
@@ -1033,12 +1047,15 @@ def allocate_publishes(
     reused = jnp.zeros((m,), bool).at[sidx].set(True, mode="drop")
     reused_words = bitset.pack(reused)
     keep = ~reused_words
+    # the publishes somebody holds: in a churn build, not a down origin's
+    held = is_pub if pub_holder is None else pub_holder >= 0
     if scatter_form:
         # ONE column scatter does both the recycled-column clear and the
         # origin stamp: column j of the update is -1 everywhere except
         # the publishing origin's row, which takes the tick (the
-        # composition of the plane form's clear-then-stamp pair)
-        row = jnp.where(is_pub, pub_origin, n_peers)
+        # composition of the plane form's clear-then-stamp pair); the
+        # holder's row is out of bounds (dropped) where nobody holds it
+        row = jnp.where(held, pub_origin, n_peers)
         col_vals = jnp.where(
             jnp.arange(n_peers, dtype=jnp.int32)[:, None] == row[None, :],
             jnp.broadcast_to(tick, (n_peers, sidx.shape[0])), -1,
@@ -1103,9 +1120,10 @@ def allocate_publishes(
             # first_edge stays -1 for local publishes
         )
     else:
-        pub_bits = jnp.zeros((n_peers, m), bool).at[pub_origin, sidx].set(
-            True, mode="drop"
-        )
+        pub_bits = jnp.zeros((n_peers, m), bool).at[
+            pub_origin if pub_holder is None
+            else jnp.where(held, pub_origin, n_peers), sidx
+        ].set(True, mode="drop")
         pub_words = bitset.pack(pub_bits)
         dlv = dlv.replace(
             have=dlv.have | pub_words,

@@ -142,6 +142,32 @@ def test_event_handler_churn():
     assert h.next_event() == (api.PEER_JOIN, nodes[3].peer_id)
 
 
+def test_a_disconnected_node_publishes_nothing():
+    """A stopped process publishes nothing: ``publish`` on a node that is
+    down raises, and a publish queued before its node went down is dropped
+    before the engine sees it (nobody gets it, the node itself included,
+    and it cannot gossip it once it is back)."""
+    net, nodes = _basic_net(n=8)
+    topics = [nd.join("t") for nd in nodes]
+    subs = [t.subscribe() for t in topics]
+    net.start()
+    net.run(2)
+    nodes[3].disconnect()
+    with pytest.raises(api.APIError, match="disconnected"):
+        topics[3].publish(b"from the dead")
+    nodes[3].reconnect()
+    topics[3].publish(b"queued, then down")    # local delivery at publish
+    assert subs[3].next().data == b"queued, then down"
+    nodes[3].disconnect()
+    net.run(2)
+    nodes[3].reconnect()
+    topics[0].publish(b"alive")
+    net.run(6)
+    for i, sub in enumerate(subs):
+        got = [m.data for m in iter(sub.next, None)]
+        assert got == [b"alive"], (i, got)
+
+
 def test_blacklist_disconnects():
     net, nodes = _basic_net(n=6)
     subs = [nd.join("t").subscribe() for nd in nodes]

@@ -28,6 +28,7 @@ from go_libp2p_pubsub_tpu.models.gossipsub import (
     no_publish as nopub,
     set_blacklist,
 )
+from go_libp2p_pubsub_tpu.models.gossipsub_phase import make_gossipsub_phase_step
 from go_libp2p_pubsub_tpu.ops import bitset
 from go_libp2p_pubsub_tpu.state import Net
 from go_libp2p_pubsub_tpu.trace.events import EV
@@ -55,7 +56,27 @@ def benign_score_params(n_topics=1):
     )
 
 
-def build(n=30, d=6, seed=0, score=False, msg_slots=32):
+#: the lifecycle runs through both engines: the per-round step, and the
+#: phase engine at r = 8 (transitions land once a phase, control acts once
+#: a phase, IWANT answers ride one round). For the phase engine one "step"
+#: of these tests is one PHASE: the publish goes into its first round and a
+#: heartbeat closes it, so a count of steps is a count of heartbeats in both
+ENGINES = ("round", "phase8")
+PHASE_R = 8
+
+
+def phase_as_step(pstep, r=PHASE_R):
+    """``step(st, po, pt, pv, up)`` over a phase step: the ``[P]`` publish
+    batch in the phase's first round, padding in the others."""
+    def step(st, po, pt, pv, up):
+        rows = lambda a, fill: jnp.concatenate(
+            [a[None], jnp.full((r - 1,) + a.shape, fill, a.dtype)])
+        return pstep(st, rows(po, -1), rows(pt, -1), rows(pv, False), up,
+                     do_heartbeat=True)
+    return step
+
+
+def build(n=30, d=6, seed=0, score=False, msg_slots=32, engine="round"):
     topo = graph.random_connect(n, d, seed=seed)
     subs = graph.subscribe_all(n, 1)
     net = Net.build(topo, subs)
@@ -68,9 +89,16 @@ def build(n=30, d=6, seed=0, score=False, msg_slots=32):
         accept_px_threshold=10.0,
         opportunistic_graft_threshold=1.0,
     )
-    cfg = GossipSubConfig.build(params, thr, score_enabled=score)
+    cfg = GossipSubConfig.build(
+        params, thr, score_enabled=score,
+        heartbeat_every=PHASE_R if engine == "phase8" else 1)
     st = GossipSubState.init(net, msg_slots, cfg, score_params=sp, seed=seed)
-    step = make_gossipsub_step(cfg, net, score_params=sp, dynamic_peers=True)
+    if engine == "phase8":
+        step = phase_as_step(make_gossipsub_phase_step(
+            cfg, net, PHASE_R, score_params=sp, dynamic_peers=True))
+    else:
+        step = make_gossipsub_step(cfg, net, score_params=sp,
+                                   dynamic_peers=True)
     return topo, net, cfg, st, step
 
 
@@ -125,8 +153,9 @@ def test_down_peer_stops_receiving_and_events_counted():
                 assert not mesh[j, :, k].any()
 
 
-def test_mesh_heals_after_peer_death():
-    topo, net, cfg, st, step = build(n=40, d=8)
+@pytest.mark.parametrize("engine", ENGINES)
+def test_mesh_heals_after_peer_death(engine):
+    topo, net, cfg, st, step = build(n=40, d=8, engine=engine)
     n = net.n_peers
     up = jnp.ones((n,), bool)
     st = run(step, st, up, 5)
@@ -143,8 +172,9 @@ def test_mesh_heals_after_peer_death():
     assert deg[:4].sum() == 0
 
 
-def test_returning_peer_rejoins_and_receives():
-    topo, net, cfg, st, step = build()
+@pytest.mark.parametrize("engine", ENGINES)
+def test_returning_peer_rejoins_and_receives(engine):
+    topo, net, cfg, st, step = build(engine=engine)
     n = net.n_peers
     up = jnp.ones((n,), bool)
     st = run(step, st, up, 5)
@@ -317,11 +347,12 @@ def test_retained_deficit_converts_to_decaying_penalty():
     assert sc_bug[0, 0] < -thr * thr / 2  # latched deficit never heals
 
 
-def test_restarting_peer_loses_soft_state():
+@pytest.mark.parametrize("engine", ENGINES)
+def test_restarting_peer_loses_soft_state(engine):
     """A crashing node restarts with an empty seen-cache/mcache (soft state
     is rebuilt from the network — survey §5 failure detection; the engine's
     down transition models the process dying)."""
-    topo, net, cfg, st, step = build()
+    topo, net, cfg, st, step = build(engine=engine)
     n = net.n_peers
     up = jnp.ones((n,), bool)
     st = run(step, st, up, 5, publishes={0: pub(n - 1)})
@@ -337,3 +368,38 @@ def test_restarting_peer_loses_soft_state():
     st = step(st, *nopub(), up)
     st = run(step, st, up, 10, publishes={2: pub(n - 1)})
     assert len(received(st, 0)) > 0
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_down_origin_publishes_nothing(engine):
+    """A stopped process publishes nothing (upstream has no such event).
+    The publish of an origin that is down takes its slot, as the ring
+    allocator says, and nobody holds the message, the origin included:
+    nothing of it enters seen-cache, forward set, mcache or first
+    receipts, so the peer cannot serve it by gossip once it is back."""
+    topo, net, cfg, st, step = build(engine=engine)
+    n = net.n_peers
+    up = jnp.ones((n,), bool)
+    st = run(step, st, up, 5)
+    down = up.at[0].set(False)
+    st = step(st, *nopub(), down)
+    ev_before = np.asarray(st.core.events)
+    # peer 0 publishes while down (slot 0); a live origin beside it (slot 1)
+    po, pt, pv = (jnp.asarray([0, n - 1, -1, -1], jnp.int32),
+                  jnp.asarray([0, 0, -1, -1], jnp.int32),
+                  jnp.asarray([True, True, False, False]))
+    st = step(st, po, pt, pv, down)
+    assert int(st.core.msgs.cursor) == 2
+    assert np.asarray(st.core.msgs.origin)[:2].tolist() == [0, n - 1]
+    assert (np.asarray(st.core.events)[EV.PUBLISH_MESSAGE]
+            - ev_before[EV.PUBLISH_MESSAGE]) == 1
+    st = run(step, st, down, 4)
+    assert all(0 not in received(st, p) for p in range(n))
+    assert sum(1 in received(st, p) for p in range(1, n)) == n - 1
+    fr = np.asarray(st.core.dlv.first_round)
+    assert (fr[:, 0] < 0).all() and fr[n - 1, 1] >= 0
+    assert not (np.asarray(st.mcache)[0] != 0).any()
+    assert not (np.asarray(st.core.dlv.fwd)[0] != 0).any()
+    # back up: it gossips nothing of what it never published
+    st = run(step, st, up, 6)
+    assert all(0 not in received(st, p) for p in range(n))

@@ -321,7 +321,7 @@ def test_part_of_and_the_stages_do_not_see_each_other(op_name, part):
     assert stages.part_of(op_name) == part
     assert stages.stage_of("jit(f)/gs.heartbeat/gsx.fanout/add") == "heartbeat"
     assert stages.stage_of("jit(f)/gsx.fanout/add") == stages.UNSCOPED
-    assert stages.PARTS == ("fanout", "attrib", "gater")
+    assert stages.PARTS == ("fanout", "attrib", "gater", "churn")
     with pytest.raises(ValueError, match="no part"):
         stages.part("gossip")
 
@@ -340,16 +340,25 @@ def test_instruction_parts_holds_only_what_is_inside_a_part():
         "and.9": "data_round"}
 
 
-@pytest.mark.parametrize("fanout_slots", [2, 0])
-def test_a_window_with_fanout_has_the_part_and_one_without_has_none(fanout_slots):
+@pytest.mark.parametrize("fanout_slots,dynamic", [(2, False), (0, False),
+                                                  (0, True)])
+def test_a_window_with_fanout_has_the_part_and_one_without_has_none(
+        fanout_slots, dynamic):
+    """A static honest window carries no part at all (``fanout``,
+    ``attrib``, ``gater``, ``churn``); a ``dynamic_peers`` build of the
+    same window carries ``churn`` and nothing else."""
     n, t, r = 64, 8, 4
     _, _, net, cfg, sp = _shape(n, t, seed=15, hb=r)
     cfg = dataclasses.replace(cfg, fanout_slots=fanout_slots)
-    phase = make_gossipsub_phase_step(cfg, net, r, score_params=sp)
+    phase = make_gossipsub_phase_step(cfg, net, r, score_params=sp,
+                                      dynamic_peers=dynamic)
     scan = driver.make_scan(phase, heartbeat_every=r, rounds_per_phase=r,
                             static_heartbeat=True)
     st = GossipSubState.init(net, 64, cfg, score_params=sp, seed=3)
-    jax.block_until_ready(scan(st, *_publishes(2 * r, n, t, seed=1)))
+    xs = _publishes(2 * r, n, t, seed=1)
+    if dynamic:
+        xs += (jnp.ones((2 * r, n), bool).at[:, 5].set(False),)
+    jax.block_until_ready(scan(st, *xs))
     (entry,) = [w for w in stages.traced_windows() if w.jitted is scan]
     stage_of, part_of = entry.stages(), entry.parts()
     assert entry.parts() is part_of                # one lowering for both
@@ -360,5 +369,11 @@ def test_a_window_with_fanout_has_the_part_and_one_without_has_none(fanout_slots
         # the part lies inside the stages its callers are
         assert {stage_of[i] for i in part_of} >= {"data_round", "heartbeat"}
         assert len(part_of) > 20
+    elif dynamic:
+        assert set(part_of.values()) == {"churn"}
+        # the transitions, the views and the publish gate at the head,
+        # the two liveness peer gathers under the gather's own stage
+        assert {stage_of[i] for i in part_of} >= {
+            "control_head", "edge_gather"}
     else:
         assert part_of == {}

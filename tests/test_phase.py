@@ -206,6 +206,82 @@ def test_phase_r1_bitexact_dynamic_peers():
     assert_states_equal(sa, sb, "r1-dyn/")
 
 
+def test_phase_r8_dynamic_peers_against_per_round():
+    """The r = 8 case beside the r = 1 one above: liveness rows constant
+    inside each phase, the phase engine at r = 8 against the per-round
+    engine (a heartbeat every 8 rounds) fed the head's row in every round.
+
+    Equal BIT FOR BIT after every phase: ``up``; the emptiness of every
+    plane of a down peer (seen-cache, forward set, mcache, mesh rows) and
+    of every edge with a down end (mesh), on both sides; the message
+    table. Equal only in what the protocol fixes, because the phase engine
+    acts on control once a phase (GRAFT / PRUNE a phase later, IWANT
+    answers one phase later: the documented control latency) and the two
+    draw their mesh candidates from different states: mesh MEMBERSHIP
+    (over live edges alone on both, and healed at the end) and ``have`` (once
+    the schedule has drained, every peer up without a break since a
+    message's birth holds it on both sides if its origin was up, and
+    nobody holds a down origin's)."""
+    he = r = 8
+    phases, quiet = 10, 4
+    net, cfg, sp, st = build(seed=29, he=he)
+    cfg = dataclasses.replace(cfg, flood_publish=False, do_px=False)
+    subs_all = graph.subscribe_all(N, T)
+    net = Net.build(graph.random_connect(N, D, seed=29), subs_all)
+    step = make_gossipsub_step(cfg, net, score_params=sp, dynamic_peers=True,
+                               static_heartbeat=True)
+    pstep = make_gossipsub_phase_step(cfg, net, r, score_params=sp,
+                                      dynamic_peers=True)
+    po, pt, pv = schedule(phases * r, seed=29)
+    po = po.at[(phases - quiet) * r:].set(-1)
+    rng = np.random.default_rng(29)
+    ups = np.ones((phases, N), bool)
+    for p in range(2, phases):                   # a few leave, some return
+        ups[p] = ups[p - 1]
+        ups[p, rng.choice(N, 3, replace=False)] ^= True
+    sa = GossipSubState.init(net, M, cfg, score_params=sp, seed=29)
+    sb = GossipSubState.init(net, M, cfg, score_params=sp, seed=29)
+    sched = heartbeat_schedule(he, 1)
+    nbr = np.clip(np.asarray(net.nbr), 0, None)
+    ok = np.asarray(net.nbr_ok)
+    for p in range(phases):
+        row = jnp.asarray(ups[p])
+        for i in range(p * r, (p + 1) * r):
+            sa = step(sa, po[i], pt[i], pv[i], row,
+                      do_heartbeat=sched[i % len(sched)])
+        sb = pstep(sb, po[p * r:(p + 1) * r], pt[p * r:(p + 1) * r],
+                   pv[p * r:(p + 1) * r], row, do_heartbeat=True)
+        live = ok & ups[p][:, None] & ups[p][nbr]
+        for s_, name in ((sa, "per-round"), (sb, "phase")):
+            assert np.array_equal(np.asarray(s_.up), ups[p]), name
+            assert not (np.asarray(s_.mesh) & ~live[:, None, :]).any(), name
+            for plane in (s_.core.dlv.have, s_.core.dlv.fwd, s_.mcache):
+                assert not np.asarray(plane)[~ups[p]].any(), name
+        for field in ("origin", "birth", "topic", "cursor"):
+            assert np.array_equal(np.asarray(getattr(sa.core.msgs, field)),
+                                  np.asarray(getattr(sb.core.msgs, field)))
+    birth = np.asarray(sb.core.msgs.birth)
+    origin = np.asarray(sb.core.msgs.origin)
+    live_m = np.flatnonzero(birth >= 0)
+    first_up = np.array([phases - np.argmax(~ups[::-1, n]) if not ups[:, n].all()
+                         else 0 for n in range(N)]) * r       # run's start
+    first_up[~ups[-1]] = phases * r
+    published = ups[birth[live_m] // r, origin[live_m]] & (
+        first_up[origin[live_m]] <= birth[live_m])
+    assert published.any() and not published.all()
+    for s_ in (sa, sb):
+        have = np.asarray(bitset.unpack(s_.core.dlv.have, M))[:, live_m]
+        through = first_up[:, None] <= birth[live_m][None, :]
+        assert have[:, published][through[:, published]].all()
+        down_origin = ~ups[birth[live_m] // r, origin[live_m]]
+        assert not have[:, down_origin].any()
+    # mesh degree heals on both sides once the last transition is a few
+    # heartbeats old (live candidates permitting)
+    for s_ in (sa, sb):
+        deg = np.asarray(s_.mesh).sum(axis=(1, 2))
+        assert (deg[ups[-1]] >= 1).all() and (deg[~ups[-1]] == 0).all()
+
+
 # ---------------------------------------------------------------------------
 # r > 1: delivery still completes; control latency is the only difference
 
