@@ -130,8 +130,8 @@ def _jit_window(run, donate: bool):
     Python body is traced, so once per trace and never per dispatch. The
     same trace counts the rows its edge gathers address by index
     (``ops/edges.tally_index_rows``), per step call, the rows and the
-    tile-rows of the table they read, and the calls that crossed in column
-    slices."""
+    tile-rows of the table they read, the calls that crossed in column
+    slices, and the rows its peer gathers address."""
     def window(*args, **kwargs):
         rows: list = []
         with edges.tally_index_rows(rows):
@@ -142,7 +142,8 @@ def _jit_window(run, donate: bool):
             edge_table_rows=edges.edge_table_rows(rows),
             edge_table_tile_rows=edges.edge_table_rows(rows, "tile_rows"),
             edge_sliced_calls_per_dispatch=edges.edge_rows_per_dispatch(
-                rows, "sliced"))
+                rows, "sliced"),
+            peer_rows_per_dispatch=edges.edge_rows_per_dispatch(rows, "peer"))
         return out
     window.__name__ = window.__qualname__ = stages.window_name()
     jitted = jax.jit(window, donate_argnums=0 if donate else ())

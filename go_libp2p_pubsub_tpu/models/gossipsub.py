@@ -1788,7 +1788,20 @@ def apply_peer_transitions(cfg: GossipSubConfig, net: Net, st: GossipSubState,
     eff_next = up_next & ~st.blacklist
     down_tr = st.up & ~eff_next
     up_tr = ~st.up & eff_next
-    down_nbr = net.peer_gather(down_tr) & net.nbr_ok
+    # both neighbour views cross the edges ONCE, as a 2-bit code (bit 0
+    # ``down_tr``, bit 1 the new ``up``): a plane constant along K comes
+    # out of the edge involution as its neighbour view (x[n,k] = v[n] =>
+    # out[j,k] = v[nbr[j,k]], whatever ``rev`` is), so the crossing takes
+    # what the net's layout gives an edge gather (plan, rolls, flat). An
+    # absent slot reads its own junk where a peer gather reads v[0]: both
+    # views are under ``nbr_ok``. The plane is two words wide (the code
+    # twice): XLA drops a unit word axis and gathers element by element,
+    # which a v5e charges 6.6 ns a row where a row gather of two words
+    # costs 5.2 (PERF.md §6, PR 38)
+    code = down_tr.astype(jnp.uint32) | (eff_next.astype(jnp.uint32) << 1)
+    code_nbr = net.edge_gather(jnp.broadcast_to(
+        code[:, None, None], net.nbr.shape + (2,)))[..., 0]
+    down_nbr = ((code_nbr & 1) != 0) & net.nbr_ok
     # every edge touching a down peer dies (both directions; a
     # restarting node comes back with fresh soft state)
     down_edge = (down_nbr | down_tr[:, None]) & net.nbr_ok
@@ -1871,7 +1884,7 @@ def apply_peer_transitions(cfg: GossipSubConfig, net: Net, st: GossipSubState,
         score=score0,
         up=eff_next,
     )
-    live = net.nbr_ok & st.up[:, None] & net.peer_gather(st.up)
+    live = net.nbr_ok & st.up[:, None] & ((code_nbr & 2) != 0)
     return st, live
 
 
@@ -2160,14 +2173,16 @@ def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
 
 
 def px_connect(cfg: GossipSubConfig, net: Net, net_l: Net, st: GossipSubState,
-               px_ok, dynamic_peers: bool) -> jax.Array:
+               px_ok, live: jax.Array | None) -> jax.Array:
     """PX connect (pxConnect gossipsub.go:861-941): a peer pruned with PX
     activates its dormant provisioned edges to peers the pruner suggested —
     the pruner's current mesh members for the topic (makePrune/getPeers
     :1814-1872; here the union over the pruner's topics, one-round-stale by
     the outbox model). The id match runs per prune-edge over the small K
     axis. `net_l` is the live view (suggestions ride live edges); `net` the
-    static topology (dormant slots live there). Returns next edge_live."""
+    static topology (dormant slots live there); `live` the peer-liveness
+    plane `apply_peer_transitions` returned (both ends up; None in a static
+    build), before `edge_live` masks it. Returns next edge_live."""
     if not cfg.do_px:
         return st.edge_live
     sugg_ids = jnp.where(
@@ -2175,8 +2190,8 @@ def px_connect(cfg: GossipSubConfig, net: Net, net_l: Net, st: GossipSubState,
     )  # [N,C] each peer's suggestion list
     sugg_g = net.peer_gather(sugg_ids)  # [N,K,C] per-edge pruner rows
     dormant_avail = net.nbr_ok & ~st.edge_live & (net.nbr >= 0)
-    if dynamic_peers:
-        dormant_avail = dormant_avail & st.up[:, None] & net.peer_gather(st.up)
+    if live is not None:
+        dormant_avail = dormant_avail & live
     act = jnp.zeros_like(dormant_avail)
     for kk in range(net.max_degree):
         hit = jnp.any(
@@ -2476,6 +2491,7 @@ def make_gossipsub_step(
                 pub_holder = jnp.where(
                     st.up[jnp.clip(pub_origin, 0)], pub_origin, -1)
         else:
+            live = None
             net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = live_step_views(
                 cfg, net, st, None, consts
             )
@@ -2537,7 +2553,7 @@ def make_gossipsub_step(
             st2 = st2.replace(choked=choke_guard(msh.Dlo, st2.mesh, st2.choked))
 
         # 1b. PX connect (see px_connect)
-        edge_live_next = px_connect(cfg, net, net_l, st, px_ok, dynamic_peers)
+        edge_live_next = px_connect(cfg, net, net_l, st, px_ok, live)
 
         joined_words = joined_msg_words(net_l, core.msgs)
         slotw = slot_topic_words(net_l, core.msgs.topic)

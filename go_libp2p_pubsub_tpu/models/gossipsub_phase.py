@@ -448,6 +448,7 @@ def make_gossipsub_phase_step(
                 pub_holder = jnp.where(
                     st.up[jnp.clip(pub_origin, 0)], pub_origin, -1)
         else:
+            live = None
             net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = live_step_views(
                 cfg, net, st, None, consts
             )
@@ -540,7 +541,7 @@ def make_gossipsub_phase_step(
         events = st.core.events
         if cfg.count_events:
             events = events.at[EV.GRAFT].add(n_graft).at[EV.PRUNE].add(n_prune)
-        edge_live_next = px_connect(cfg, net, net_l, st, px_ok, dynamic_peers)
+        edge_live_next = px_connect(cfg, net, net_l, st, px_ok, live)
         # the IWANT-service window gather rides the wire view (net_w):
         # responses on a flapped link are lost and the retransmission
         # counters don't tick (the data never arrived)

@@ -25,8 +25,8 @@ words crosses in tile-wide column slices, one gather a slice, where only
 the slices' tables are small enough to be read cheaply (`word_slices`).
 `_tally` counts the rows every gather set addresses, the rows and tile-rows
 of the table it reads and whether it was sliced, for the window's
-`edge_rows_per_dispatch`, `edge_table_rows`, `edge_table_tile_rows` and
-`edge_sliced_calls_per_dispatch`.
+`edge_rows_per_dispatch`, `edge_table_rows`, `edge_table_tile_rows`,
+`edge_sliced_calls_per_dispatch` and `peer_rows_per_dispatch`.
 
 Topic-slot payloads ([N,S,K] per-slot bools) are moved across edges by
 packing the S axis into *topic-id bit positions* of uint32 words (T bits
@@ -163,9 +163,10 @@ def mark_dispatch(key=None) -> None:
 def edge_rows_per_dispatch(tally: list, of: str = "edge") -> float | None:
     """Mean ``"edge"`` rows per step call of a ``tally_index_rows`` list
     cut by ``mark_dispatch`` (``of="sliced"``: the calls ``word_slices``
-    split). A jitted step is traced once per key: a
-    later call with the same key replays the cached jaxpr and tallies
-    nothing, so it counts what the first call with its key counted.
+    split; ``of="peer"``: the rows its peer gathers address). A jitted
+    step is traced once per key: a later call with the same key replays
+    the cached jaxpr and tallies nothing, so it counts what the first
+    call with its key counted.
     ``None`` where no call tallied anything (no window body marked a
     dispatch, or every trace was a replay)."""
     calls, first = [], {}

@@ -71,9 +71,9 @@ same compiled text.
                 composition and folds, ``score.gater.gater_on_round`` with
                 its popcounts, ``gater_accept`` and ``gater_decay``
   churn         what a ``dynamic_peers`` build pays at the head beyond a
-                static one: ``gossipsub.apply_peer_transitions`` (its two
-                ``[N]`` -> ``[N,K]`` liveness peer gathers, which stand
-                under ``gs.edge_gather`` too, and the dead-edge clears),
+                static one: ``gossipsub.apply_peer_transitions`` (the
+                liveness code's one edge gather, which stands under
+                ``gs.edge_gather`` too, and the dead-edge clears),
                 ``live_step_views``' traced arm, and the publish gate on
                 ``up[origin]`` (``pub_holder``, which ``state.PhasePubPlan``
                 / ``allocate_publishes`` take). A static window carries none
@@ -241,6 +241,11 @@ class TracedWindow:
     #: edge gathers of ONE step call that crossed in column slices
     #: (``ops/edges.word_slices``); 0 for rolls, ``None`` for replays
     edge_sliced_calls_per_dispatch: float | None = None
+    #: rows the PEER gathers of ONE step call address by index
+    #: (``Net.peer_gather``: all N*K slots of ``v[nbr]`` a call; 0 for
+    #: rolls, ``None`` for replays): 0 where every neighbour view of the
+    #: step crosses as an edge gather
+    peer_rows_per_dispatch: float | None = None
     _stages: dict | None = None
     _parts: dict | None = None
 

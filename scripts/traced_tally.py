@@ -1,13 +1,13 @@
 """``benchmark/tools/traced.py`` (same arguments), then what the program
 counted while it traced the window: the rows its edge gathers address a
-step call, the rows and tile-rows of the table they read and the calls a
-step that crossed in column slices (``perf.stages``'s
-``edge_rows_per_dispatch`` / ``edge_table_rows`` /
-``edge_table_tile_rows`` / ``edge_sliced_calls_per_dispatch``; ``null`` on
-a commit without the counter), and the edge gathers of the window as
-this machine's compiler built it, with the memory space of each one's
-table, indices and output (``window_whiles.edge_gathers``). One line on
-stderr a window."""
+step call, the rows and tile-rows of the table they read, the calls a
+step that crossed in column slices and the rows its peer gathers address
+(``perf.stages``'s ``edge_rows_per_dispatch`` / ``edge_table_rows`` /
+``edge_table_tile_rows`` / ``edge_sliced_calls_per_dispatch`` /
+``peer_rows_per_dispatch``; ``null`` on a commit without the counter), and
+the edge gathers of the window as this machine's compiler built it, with
+the memory space of each one's table, indices and output
+(``window_whiles.edge_gathers``). One line on stderr a window."""
 
 from __future__ import annotations
 
@@ -34,7 +34,8 @@ def main(argv=None) -> int:
             name: getattr(w, name, None)
             for name in ("edge_rows_per_dispatch", "edge_table_rows",
                          "edge_table_tile_rows",
-                         "edge_sliced_calls_per_dispatch")},
+                         "edge_sliced_calls_per_dispatch",
+                         "peer_rows_per_dispatch")},
             "edge_gathers": window_whiles.edge_gathers(text)}),
             file=sys.stderr)
     return rc

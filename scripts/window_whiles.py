@@ -71,7 +71,8 @@ def whiles(text: str) -> list[dict]:
 def edge_gathers(text: str) -> list[dict]:
     """The gathers of a compiled module's text that stand under
     ``gs.edge_gather``, equal ones counted together: the stage around the
-    scope, output rows, table rows, words a row, which of table, indices
+    scope (a part may stand between the two), output rows, table rows,
+    words a row (a one-word plane's gather is 1-D), which of table, indices
     (the fusion's operands) and output (its result) lie in ``S(1)``, and
     the fusions that hold them (two gathers in one multi-output fusion
     keep both tables live at once)."""
@@ -92,13 +93,13 @@ def edge_gathers(text: str) -> list[dict]:
         head = re.match(rf"^(?:ENTRY )?{name} \(.*\{{\s*$", line)
         if head:
             comp = head.group(1)
-        op = re.search(rf" = u32\[(\d+),(\d+)\]\S* gather\({name}, {name}\)",
-                       line)
-        stage = re.search(r"gs\.(\w+)/gs\.edge_gather/", line)
+        op = re.search(
+            rf" = u32\[(\d+)(?:,(\d+))?\]\S* gather\({name}, {name}\)", line)
+        stage = re.search(r"gs\.(\w+)/(?:gsx\.\w+/)?gs\.edge_gather/", line)
         if op and stage:
             rows, words, table, index = op.groups()
-            table_rows = re.match(r"u32\[(\d+),", defs[table]).group(1)
-            key = (stage.group(1), int(rows), int(table_rows), int(words),
+            table_rows = re.match(r"u32\[(\d+)", defs[table]).group(1)
+            key = (stage.group(1), int(rows), int(table_rows), int(words or 1),
                    fast(table), fast(index), "S(1)" in callers.get(comp, ""))
             found[key] += 1
             fusions.setdefault(key, set()).add(comp)

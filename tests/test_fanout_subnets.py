@@ -372,7 +372,7 @@ def test_a_window_with_fanout_has_the_part_and_one_without_has_none(
     elif dynamic:
         assert set(part_of.values()) == {"churn"}
         # the transitions, the views and the publish gate at the head,
-        # the two liveness peer gathers under the gather's own stage
+        # the liveness code's edge gather under the gather's own stage
         assert {stage_of[i] for i in part_of} >= {
             "control_head", "edge_gather"}
     else:
