@@ -374,6 +374,23 @@ def phase_api(n_nodes: int, tmp: str) -> None:
 # served path: supervisor, rolling checkpoint, resume
 
 
+def served_host_ms(seconds: float) -> dict:
+    """The served loop's host spans recorded since the recorder was cleared
+    (``perf/spans.py``): per span its count, its median and its total in
+    ms, and what the ``serve.segment`` spans leave of ``seconds``."""
+    import statistics
+
+    from go_libp2p_pubsub_tpu.perf import spans
+
+    by = {name[len("serve."):]: [1e3 * x for x in took]
+          for name, took in spans.seconds_by_name("serve.").items()}
+    out = {k: {"n": len(v), "median": round(statistics.median(v), 3),
+               "total": round(sum(v), 3)} for k, v in sorted(by.items())}
+    out["outside_segments"] = {"total": round(
+        1e3 * seconds - sum(by.get("segment", [])), 3)}
+    return out
+
+
 def phase_served(n_peers: int, segment_len: int, n_segments: int,
                  tmp: str, devices) -> None:
     """``serve.Supervisor`` over the bench net, wired as
@@ -383,6 +400,7 @@ def phase_served(n_peers: int, segment_len: int, n_segments: int,
     import jax
     import jax.numpy as jnp
 
+    from go_libp2p_pubsub_tpu.perf import spans
     from go_libp2p_pubsub_tpu.perf.sweep import bench_cell, bench_schedule
     from go_libp2p_pubsub_tpu.serve import (
         ServiceConfig,
@@ -422,7 +440,9 @@ def phase_served(n_peers: int, segment_len: int, n_segments: int,
                 f"window compiles {rep.window_compiles}")
         return rep, round(time.perf_counter() - t0, 3)
 
+    spans.clear()
     control, control_s = run(os.path.join(tmp, "control"), total, True)
+    host_ms = served_host_ms(control.seconds)
     want = state_digest(control.states)
     del control
 
@@ -445,6 +465,7 @@ def phase_served(n_peers: int, segment_len: int, n_segments: int,
          window_compiles=resumed.window_compiles, digest=got,
          seconds={"uninterrupted": control_s, "first_half": first_s,
                   "resumed_half": resumed_s},
+         host_ms_uninterrupted=host_ms,
          peak_bytes_in_use=peak_bytes(devices[0]))
 
 

@@ -30,9 +30,13 @@ def enable_persistent_cache(cache_dir: str | None = None) -> bool:
         return False
     import jax
 
+    from .perf import spans
+
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir",
                           cache_dir or DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # every compile, cache hit and miss from here on is on record
+    spans.watch_compiles()
     return True

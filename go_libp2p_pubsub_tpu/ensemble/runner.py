@@ -46,6 +46,8 @@ from __future__ import annotations
 import dataclasses
 import time
 
+from ..perf import spans
+
 
 @dataclasses.dataclass
 class EnsembleRun:
@@ -265,20 +267,19 @@ class WindowRunner:
         consts = tuple(consts)
         before = self._cache_size()
         oks, obs = [], []
-        t0 = time.perf_counter()
-        for g in range(D // seg):
-            xs = self.stack_args(make_args, g * seg, (g + 1) * seg)
-            dseg = (due[g * cpseg:(g + 1) * cpseg]
-                    if due is not None else None)
-            states, ys = self.window(states, xs, dseg, consts)
-            if "ok" in ys:
-                oks.append(ys["ok"])
-            if "obs" in ys:
-                obs.append(ys["obs"])
-            if on_segment is not None and g + 1 < D // seg:
-                on_segment(g, states)
-        jax.block_until_ready(states)
-        dt = time.perf_counter() - t0
+        with spans.span("ensemble.run") as ran:
+            for g in range(D // seg):
+                xs = self.stack_args(make_args, g * seg, (g + 1) * seg)
+                dseg = (due[g * cpseg:(g + 1) * cpseg]
+                        if due is not None else None)
+                states, ys = self.window(states, xs, dseg, consts)
+                if "ok" in ys:
+                    oks.append(ys["ok"])
+                if "obs" in ys:
+                    obs.append(ys["obs"])
+                if on_segment is not None and g + 1 < D // seg:
+                    on_segment(g, states)
+            jax.block_until_ready(states)
         after = self._cache_size()
         import numpy as _np
 
@@ -299,7 +300,7 @@ class WindowRunner:
             rounds=D * self.rounds_per_phase,
             compiles=(-1 if before is None or after is None
                       else after - before),
-            seconds=dt,
+            seconds=ran.seconds,
             dispatches=D // seg,
             invariant_report=report,
             observations=observations,

@@ -61,7 +61,7 @@ from ..ops.select import (
     select_random_mask,
     select_topk_mask,
 )
-from ..perf import stages
+from ..perf import spans, stages
 from ..routers import (
     RouterConfig,
     choke_decide,
@@ -482,6 +482,7 @@ class GossipSubState:
     inflight: jax.Array | None = None    # [N,K,L,W] u32 ([E,L,W] flat)
 
     @classmethod
+    @spans.span("setup.state_init")
     def init(
         cls,
         net: Net,

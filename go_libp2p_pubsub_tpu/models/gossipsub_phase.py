@@ -104,7 +104,7 @@ import numpy as np
 from .. import state as state_mod
 from ..chaos import faults as chaos_faults
 from ..ops import bitset
-from ..perf import stages
+from ..perf import spans, stages
 from ..score.engine import (
     apply_delivery_counts,
     on_deliveries,
@@ -255,6 +255,7 @@ class _AccStack:
         return self.bufs[part][:, off : off + lanes, :]
 
 
+@spans.span("setup.step_build")
 def make_gossipsub_phase_step(
     cfg: GossipSubConfig,
     net: Net,

@@ -78,6 +78,11 @@ same compiled text.
                 ``up[origin]`` (``pub_holder``, which ``state.PhasePubPlan``
                 / ``allocate_publishes`` take). A static window carries none
 
+Host spans. ``host_scope(name)`` is the profiler annotation
+``gs.host.<name>`` a host span of ``perf/spans.py`` lies under in the host
+plane of a running trace: spelled here with the other names, called from
+there alone.
+
 Known limits. A fusion carries one ``op_name``, its root's: a fusion
 that spans two stages is booked to the root's. A tracer carries no
 device assignment, so ``traced_windows()`` can only lower for one
@@ -119,6 +124,7 @@ STAGES = ("control_head", "pub_plan", "data_round", "edge_gather", "deliver",
 UNSCOPED = "unscoped"
 PART_PREFIX = "gsx."
 PARTS = ("fanout", "attrib", "gater", "churn")
+HOST_PREFIX = PREFIX + "host."
 
 _SCOPE_RE = re.compile(re.escape(PREFIX) + r"([a-z_]+)")
 _PART_RE = re.compile(re.escape(PART_PREFIX) + r"([a-z_]+)")
@@ -144,6 +150,16 @@ def part(name: str):
     import jax
 
     return jax.named_scope(PART_PREFIX + name)
+
+
+def host_scope(name: str):
+    """``jax.profiler.TraceAnnotation`` of one host span: under a running
+    profiler the span lies in the host plane of the same trace as the
+    device ops, on one clock. ``perf.spans.span`` is its one caller and
+    checks the name."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(HOST_PREFIX + name)
 
 
 class Cursor(contextlib.ExitStack):

@@ -42,6 +42,7 @@ supervised run's final state tree is bit-exact vs the bare window.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import logging
@@ -58,10 +59,15 @@ from .. import checkpoint as _ckpt
 from ..ensemble.runner import WindowRunner
 from ..oracle import invariants as _oinv
 from ..oracle.probes import HealthConfig, make_health_probe
+from ..perf import spans
 from .faults import TransientDispatchError
 from .store import CheckpointStore, RetentionPolicy, write_json_atomic
 
 _log = logging.getLogger(__name__)
+
+#: the host spans a report row gives under ``host_ms`` (``serve.<name>``)
+ROW_SPANS = ("stack_args", "dispatch", "probe_readback", "ev_drain",
+             "checkpoint_save", "heartbeat_write", "report_row")
 
 try:  # the real-dispatch-failure class worth retrying, when available
     from jax.errors import JaxRuntimeError as _JaxRuntimeError
@@ -315,6 +321,7 @@ class Supervisor:
         self._degradations: list = []
         self._bundles: list = []
         self._rows: list | None = None  # report rows (lazy jsonl load)
+        self._host_ms = dict.fromkeys(ROW_SPANS, 0.0)
 
     # -- window plumbing ------------------------------------------------
 
@@ -586,18 +593,19 @@ class Supervisor:
         return os.path.join(self.root, "HEARTBEAT.json")
 
     def _heartbeat(self, dispatch: int, status: str) -> None:
-        write_json_atomic(self.heartbeat_path, {
-            "status": status,
-            "dispatch": int(dispatch),
-            "total_dispatches": int(self.svc.n_dispatches),
-            "tick": int(dispatch) * self.svc.rounds_per_dispatch,
-            "segments_run": self._segments_run,
-            "recoveries": self._recoveries,
-            "retries": self._retries,
-            "degradations": list(self._degradations),
-            "pid": os.getpid(),
-            "updated_at": time.time(),
-        })
+        with self._span("serve.heartbeat_write"):
+            write_json_atomic(self.heartbeat_path, {
+                "status": status,
+                "dispatch": int(dispatch),
+                "total_dispatches": int(self.svc.n_dispatches),
+                "tick": int(dispatch) * self.svc.rounds_per_dispatch,
+                "segments_run": self._segments_run,
+                "recoveries": self._recoveries,
+                "retries": self._retries,
+                "degradations": list(self._degradations),
+                "pid": os.getpid(),
+                "updated_at": time.time(),
+            })
 
     def _report_paths(self):
         if self.svc.report_name is None:
@@ -605,7 +613,30 @@ class Supervisor:
         base = os.path.join(self.root, self.svc.report_name)
         return base + ".jsonl", base + ".html"
 
+    @contextlib.contextmanager
+    def _span(self, name: str, **attrs):
+        """A host span of ``perf.spans`` whose milliseconds also add to
+        the next report row's ``host_ms``, where the row names it."""
+        with spans.span(name, **attrs) as sp:
+            yield sp
+        key = name[len("serve."):]
+        if key in self._host_ms:
+            self._host_ms[key] += 1e3 * sp.seconds
+
     def _report_row(self, row: dict) -> None:
+        """``row`` gains ``host_ms``: the host milliseconds by span since
+        the last row. ``stack_args``, ``dispatch``, ``probe_readback``,
+        ``ev_drain`` and ``checkpoint_save`` lie inside the row's
+        ``seconds`` (the ``serve.segment`` span around them); ``stack_args``
+        of a first segment, ``heartbeat_write`` and ``report_row`` lie
+        outside it, and ``report_row`` is the write of the row BEFORE this
+        one (a row cannot time its own write)."""
+        row["host_ms"] = {k: round(v, 3) for k, v in self._host_ms.items()}
+        self._host_ms = dict.fromkeys(ROW_SPANS, 0.0)
+        with self._span("serve.report_row"):
+            self._write_row(row)
+
+    def _write_row(self, row: dict) -> None:
         jsonl, html = self._report_paths()
         if jsonl is None:
             return
@@ -638,6 +669,7 @@ class Supervisor:
         self._segments_run = 0
         self._recoveries = 0
         self._retries = 0
+        self._host_ms = dict.fromkeys(ROW_SPANS, 0.0)
         t0 = time.perf_counter()
         resumed_from = None
         states, start = self.template_fn(), 0
@@ -645,7 +677,8 @@ class Supervisor:
                                    np.int64)
                      if svc.drain_event_counters else None)
         if not fresh:
-            st, entry = self.store.restore_latest(self.template_fn())
+            with self._span("serve.restore"):
+                st, entry = self.store.restore_latest(self.template_fn())
             if st is not None:
                 states = st
                 start = int(entry.get("meta", {}).get(
@@ -679,94 +712,104 @@ class Supervisor:
             runner = self._runner_for(L)
             xs = xs_cache.pop(start, None)
             if xs is None:
-                xs = runner.stack_args(self.make_args, start, start + L)
+                with self._span("serve.stack_args"):
+                    xs = runner.stack_args(self.make_args, start, start + L)
             due, ticks = self._segment_due(start, L)
-            t_seg = time.perf_counter()
-            out, ys, retries, degraded = self._dispatch_retrying(
-                seg, start, L, states, xs, due)
-            self._retries += retries
-            if degraded:
-                # shape changed (or observers dropped): rebuild the
-                # segment from an intact state on the new ladder rung
-                states = out if out is not None else self._state_at(start)
-                xs_cache.clear()
-                continue
-            states = out
-            # double-buffer: assemble the NEXT segment's xs while the
-            # device is still executing this one (dispatch is async)
-            nxt = start + L
-            if nxt < total:
-                Ln = min(self._seg_len, total - nxt)
-                xs_cache[nxt] = runner.stack_args(self.make_args, nxt,
-                                                  nxt + Ln)
-            # injected silent corruption lands before the probe reads
-            if self.faults is not None and self.faults.wants_corruption(seg):
-                states = self.faults.corrupt_state(
-                    states, seg,
-                    self.faults.resolved_dispatch(L), L)
-            # the segment's one host sync: probe + verdict readback
-            probe_fail = []
-            if self._probe is not None:
-                pm = np.asarray(self._probe(states, prev_events))
-                flat = pm.reshape(-1, pm.shape[-1])
-                probe_fail = [self._probe_names[k]
-                              for k in np.nonzero(~flat.all(axis=0))[0]]
-            window_report = None
-            if self.invariants is not None and ys and "ok" in ys:
-                window_report = self.invariants.report(ys["ok"],
-                                                       ticks=ticks)
-            inv_bad = (window_report is not None
-                       and not window_report.all_ok)
-            if probe_fail or inv_bad:
-                self._recoveries += 1
-                n = recov_per_segment.get(start, 0) + 1
-                recov_per_segment[start] = n
-                bundle = self._rollback_replay(
-                    seg, start, L, states, probe_fail, window_report)
-                _log.warning(
-                    "segment %d unhealthy (%s) — rolled back; replay "
-                    "localized first violating dispatch %s (bundle %s)",
-                    seg, probe_fail or "invariants",
-                    bundle["first_bad_dispatch"], bundle["path"])
-                if n > svc.max_recoveries_per_segment:
-                    self._heartbeat(start, "halted")
-                    what = bundle["replay_failures"] or probe_fail
-                    raise ServiceHalted(
-                        f"segment {seg}: {n} recoveries exceeded the "
-                        f"budget ({svc.max_recoveries_per_segment}) — "
-                        f"persistent violation ({what}); forensic "
-                        f"bundle at {bundle['path']}", bundle)
-                states = self._state_at(start)
-                prev_events = jnp.copy(_core_of(states).events)
-                continue
-            if self.faults is not None:
-                self.faults.maybe_kill("post-segment", seg)
-            # commit
-            self._segments_run += 1
-            if window_report is not None:
-                inv_checks += window_report.n_checks
-            if ys and "obs" in ys:
-                obs_acc.append(ys["obs"])
-            start += L
-            if ev_totals is not None:
-                # segment-boundary EV drain (the probe/invariant verdict
-                # above already validated this segment): the segment's
-                # i32 counter growth folds into the host i64 totals and
-                # the device counters zero, so no device counter ever
-                # holds more than ONE segment's growth — the overflow
-                # horizon becomes per-segment, not per-run
-                ev_totals += (np.asarray(_core_of(states).events, np.int64)
-                              - np.asarray(prev_events, np.int64))
-                states = _with_events(
-                    states, jnp.zeros_like(_core_of(states).events))
-            if (self._segments_run % svc.checkpoint_every_segments == 0
-                    or start >= total):
-                meta = {"dispatch": start}
+            # the segment's clock: `seconds` of its report row
+            with self._span("serve.segment", segment=seg) as seg_span:
+                with self._span("serve.dispatch"):
+                    out, ys, retries, degraded = self._dispatch_retrying(
+                        seg, start, L, states, xs, due)
+                self._retries += retries
+                if degraded:
+                    # shape changed (or observers dropped): rebuild the
+                    # segment from an intact state on the new ladder rung
+                    states = (out if out is not None
+                              else self._state_at(start))
+                    xs_cache.clear()
+                    continue
+                states = out
+                # double-buffer: assemble the NEXT segment's xs while the
+                # device is still executing this one (dispatch is async)
+                nxt = start + L
+                if nxt < total:
+                    Ln = min(self._seg_len, total - nxt)
+                    with self._span("serve.stack_args"):
+                        xs_cache[nxt] = runner.stack_args(self.make_args, nxt,
+                                                          nxt + Ln)
+                # injected silent corruption lands before the probe reads
+                if (self.faults is not None
+                        and self.faults.wants_corruption(seg)):
+                    states = self.faults.corrupt_state(
+                        states, seg,
+                        self.faults.resolved_dispatch(L), L)
+                # the segment's one host sync: probe + verdict readback
+                probe_fail = []
+                if self._probe is not None:
+                    with self._span("serve.probe_readback"):
+                        pm = np.asarray(self._probe(states, prev_events))
+                    flat = pm.reshape(-1, pm.shape[-1])
+                    probe_fail = [self._probe_names[k]
+                                  for k in np.nonzero(~flat.all(axis=0))[0]]
+                window_report = None
+                if self.invariants is not None and ys and "ok" in ys:
+                    window_report = self.invariants.report(ys["ok"],
+                                                           ticks=ticks)
+                inv_bad = (window_report is not None
+                           and not window_report.all_ok)
+                if probe_fail or inv_bad:
+                    self._recoveries += 1
+                    n = recov_per_segment.get(start, 0) + 1
+                    recov_per_segment[start] = n
+                    bundle = self._rollback_replay(
+                        seg, start, L, states, probe_fail, window_report)
+                    _log.warning(
+                        "segment %d unhealthy (%s) — rolled back; replay "
+                        "localized first violating dispatch %s (bundle %s)",
+                        seg, probe_fail or "invariants",
+                        bundle["first_bad_dispatch"], bundle["path"])
+                    if n > svc.max_recoveries_per_segment:
+                        self._heartbeat(start, "halted")
+                        what = bundle["replay_failures"] or probe_fail
+                        raise ServiceHalted(
+                            f"segment {seg}: {n} recoveries exceeded the "
+                            f"budget ({svc.max_recoveries_per_segment}) — "
+                            f"persistent violation ({what}); forensic "
+                            f"bundle at {bundle['path']}", bundle)
+                    states = self._state_at(start)
+                    prev_events = jnp.copy(_core_of(states).events)
+                    continue
+                if self.faults is not None:
+                    self.faults.maybe_kill("post-segment", seg)
+                # commit
+                self._segments_run += 1
+                if window_report is not None:
+                    inv_checks += window_report.n_checks
+                if ys and "obs" in ys:
+                    obs_acc.append(ys["obs"])
+                start += L
                 if ev_totals is not None:
-                    meta["ev_totals"] = ev_totals.tolist()
-                self.store.save(states, tick=start * rps, meta=meta)
-            prev_events = jnp.copy(_core_of(states).events)
-            dt = time.perf_counter() - t_seg
+                    # segment-boundary EV drain (the probe/invariant verdict
+                    # above already validated this segment): the segment's
+                    # i32 counter growth folds into the host i64 totals and
+                    # the device counters zero, so no device counter ever
+                    # holds more than ONE segment's growth — the overflow
+                    # horizon becomes per-segment, not per-run
+                    with self._span("serve.ev_drain"):
+                        ev_totals += (
+                            np.asarray(_core_of(states).events, np.int64)
+                            - np.asarray(prev_events, np.int64))
+                        states = _with_events(
+                            states, jnp.zeros_like(_core_of(states).events))
+                if (self._segments_run % svc.checkpoint_every_segments == 0
+                        or start >= total):
+                    meta = {"dispatch": start}
+                    if ev_totals is not None:
+                        meta["ev_totals"] = ev_totals.tolist()
+                    with self._span("serve.checkpoint_save"):
+                        self.store.save(states, tick=start * rps, meta=meta)
+                prev_events = jnp.copy(_core_of(states).events)
+            dt = seg_span.seconds
             self._heartbeat(start, "running")
             self._report_row({
                 "segment": seg,

@@ -23,7 +23,7 @@ from flax import struct
 
 from . import graph as graphlib
 from .ops import bitset, csr, edges
-from .perf import stages
+from .perf import spans, stages
 from .trace.events import zero_counters
 
 
@@ -223,6 +223,7 @@ class Net:
         return got
 
     @classmethod
+    @spans.span("setup.net_build")
     def build(
         cls,
         topo: graphlib.Topology,
@@ -339,31 +340,36 @@ class Net:
         else:
             band = (None if dynamic
                     else edges.detect_banded(topo.nbr, topo.rev, topo.nbr_ok))
-        edge_perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
-        # the tiers are static like the band: a dense net that is neither
-        # banded (rolls) nor dynamic (``edge_perm`` is traced there)
-        tiers = (edges.plan_tiers(edge_perm, topo.nbr_ok)
-                 if edge_layout == "dense" and band is None and not dynamic
-                 else None)
-        return cls(
-            edge_layout=edge_layout,
-            fused=bool(fused),
-            tiers=tiers,
-            **csr_kw,
-            band_off=band[0] if band else None,
-            band_rev=band[1] if band else None,
-            nbr=jnp.asarray(topo.nbr),
-            nbr_ok=jnp.asarray(topo.nbr_ok),
-            rev=jnp.asarray(topo.rev),
-            outbound=jnp.asarray(topo.outbound),
-            subscribed=jnp.asarray(subs.subscribed),
-            my_topics=jnp.asarray(subs.my_topics),
-            slot_of=jnp.asarray(subs.slot_of),
-            ip_group=jnp.asarray(ip_group),
-            direct=jnp.asarray(direct),
-            edge_perm=jnp.asarray(edge_perm),
-            protocol=jnp.asarray(protocol, jnp.int8),
-        )
+        # the host's numpy over the index planes (``plan_tiers`` ends in the
+        # plan's three device puts)
+        with spans.span("setup.net_build.plan"):
+            edge_perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
+            # the tiers are static like the band: a dense net that is
+            # neither banded (rolls) nor dynamic (``edge_perm`` is traced
+            # there)
+            tiers = (edges.plan_tiers(edge_perm, topo.nbr_ok)
+                     if edge_layout == "dense" and band is None
+                     and not dynamic else None)
+        with spans.span("setup.net_build.planes"):
+            return cls(
+                edge_layout=edge_layout,
+                fused=bool(fused),
+                tiers=tiers,
+                **csr_kw,
+                band_off=band[0] if band else None,
+                band_rev=band[1] if band else None,
+                nbr=jnp.asarray(topo.nbr),
+                nbr_ok=jnp.asarray(topo.nbr_ok),
+                rev=jnp.asarray(topo.rev),
+                outbound=jnp.asarray(topo.outbound),
+                subscribed=jnp.asarray(subs.subscribed),
+                my_topics=jnp.asarray(subs.my_topics),
+                slot_of=jnp.asarray(subs.slot_of),
+                ip_group=jnp.asarray(ip_group),
+                direct=jnp.asarray(direct),
+                edge_perm=jnp.asarray(edge_perm),
+                protocol=jnp.asarray(protocol, jnp.int8),
+            )
 
     @property
     def n_peers(self) -> int:
