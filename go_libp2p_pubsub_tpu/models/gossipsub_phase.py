@@ -52,10 +52,14 @@ launch-overhead, not bytes:
     ops, replacing r allocate_publishes calls' tiny-kernel swarm
     ([M]-table scatters, cursor scalar chains — the round-6 profile's
     dominant launch pool);
-  * the ATTRIBUTION ACCUMULATORS fold as one leading-axis-stacked
-    tensor (_AccStack) — one OR + one keep-AND per sub-round for every
-    live plane — and the shared keep-clears go through
-    bitset.masked_keep.
+  * the ATTRIBUTION ACCUMULATORS' [N, W] planes (new / recv /
+    accepted) fold as lanes of one stacked tensor (_AccStack) — one OR
+    + one keep-AND per sub-round for all of them — and the shared
+    keep-clears go through bitset.masked_keep. (Round 7 stacked the
+    [N, K, W] planes too; since PR 40 each of those folds in a buffer
+    of its own: on the chip at 50k peers the concatenated stacks cost
+    the sub-round gathers their fast-memory tables and launches
+    besides, see _AccStack.)
 Measured on this image's XLA:CPU at N=12.5k r=16: 410.9 -> 85.1
 executed kernels/round (docs/PERF.md round-7 table). The legacy
 per-plane path stays selectable (cfg.wire_coalesced=False) and
@@ -152,15 +156,25 @@ class PhaseAdmissionError(ValueError):
 
 
 class _AccStack:
-    """The phase's attribution accumulators as ONE edge-axis-stacked
-    ``[N, C, W]`` tensor (round-7 tentpole): every live plane — [N, W]
-    planes contribute one lane, [N, K, W] planes K lanes — shares the
-    same two word-algebra folds per sub-round (OR the sub-round's update
-    in, AND the recycled-slot keep mask), so the stacked form runs each
-    fold as one wide kernel instead of one small kernel per plane. At
-    the 12.5k shard the phase engine is fusion-count-bound (docs/PERF.md
-    round-6 table: 94% of device time in many small ``not_and``/
-    ``broadcast_and`` fusions), so lanes are cheaper than launches.
+    """The phase's attribution accumulators. Every live plane takes the
+    same two word-algebra folds per sub-round: OR the sub-round's update
+    in, AND the recycled-slot keep mask.
+
+    Stacked (``cfg.wire_coalesced``), the ``[N, W]`` planes of one part
+    (``new``, ``recv``, ``accepted``) ride as lanes of ONE ``[N, C, W]``
+    buffer and share one OR and one keep-AND a sub-round (the round-7
+    form: at the 12.5k shard the phase engine was fusion-count-bound,
+    docs/PERF.md round-6 table, and a lane is cheaper than a launch). An
+    ``[N, K, W]`` plane (``trans``, ``mcw``, ``dup``, ``rejw``, ``ignw``,
+    ``dupt``) folds in a buffer of its own. Until PR 40 the K-wide planes
+    of a part were concatenated into one ``[N, 2K, W]`` / ``[N, 3K, W]``
+    stack too: at ``sybil-50k`` those 70 and 105 MB temporaries, live
+    beside a 56 MB gather table, made the TPU compiler leave the table of
+    five of the cell's ten 1.2M-row edge gathers in HBM (2.86 x the time
+    of the five it prefetched into the fast memory space), and the
+    concatenates were ops of their own (711 -> 572 fusions in a
+    dispatch's text). With every table in the fast space that cell runs
+    53.10 -> 91.73 rounds/s (PERF.md §6, PR 40).
 
     ``stacked=False`` keeps every plane a separate array with separate
     folds — the legacy round-4..6 kernel structure — selected by
@@ -169,90 +183,82 @@ class _AccStack:
     construction (pinned by tests/test_phase_stacked.py)."""
 
     def __init__(self, specs, n: int, w: int, stacked: bool):
-        # specs: (name, lanes, keep_masked, part); lanes=1 packs an
-        # [N, W] plane, lanes=k an [N, k, W] plane. Planes of one
-        # ``part`` (perf/stages.PARTS; None: the honest planes every
-        # scored build folds) share one buffer and are folded under that
-        # part's scope, so the trace can tell what the P3 / P4 and the
-        # gater planes cost; a build with one group folds as it always did
+        # specs: (name, lanes, keep_masked, part); lanes=1 is an [N, W]
+        # plane, lanes=k an [N, k, W] plane. Stacked, the [N, W] planes of
+        # one ``part`` (perf/stages.PARTS; None: the honest planes every
+        # scored build folds) share one buffer (``bufs``); every other
+        # plane is an array of its own (``planes``). Each fold runs under
+        # its plane's part scope, so the trace can tell what the P3 / P4
+        # and the gater planes cost
         self.specs = tuple(specs)
-        self.stacked = stacked
-        self.offs = {}      # name -> (part, first lane in its group, lanes)
-        self.groups = {}    # part -> [(name, lanes, keep_masked)]
+        self.offs = {}      # stacked name -> (part, its lane in the group)
+        self.groups = {}    # part -> [(name, keep_masked)], stacked planes
         for name, lanes, masked, part in self.specs:
-            group = self.groups.setdefault(part, [])
-            self.offs[name] = (part, sum(ln for _, ln, _ in group), lanes)
-            group.append((name, lanes, masked))
-        if stacked:
-            self.bufs = {
-                part: jnp.zeros((n, sum(ln for _, ln, _ in group), w),
-                                jnp.uint32)
-                for part, group in self.groups.items()
-            }
-        else:
-            self.planes = {
-                name: jnp.zeros((n, w) if lanes == 1 else (n, lanes, w),
-                                jnp.uint32)
-                for name, lanes, _, _ in self.specs
-            }
+            if stacked and lanes == 1:
+                group = self.groups.setdefault(part, [])
+                self.offs[name] = (part, len(group))
+                group.append((name, masked))
+        self.bufs = {
+            part: jnp.zeros((n, len(group), w), jnp.uint32)
+            for part, group in self.groups.items()
+        }
+        self.planes = {
+            name: jnp.zeros((n, w) if lanes == 1 else (n, lanes, w),
+                            jnp.uint32)
+            for name, lanes, _, _ in self.specs if name not in self.offs
+        }
 
     @staticmethod
     def _scope(part):
         return stages.part(part) if part else contextlib.nullcontext()
 
     def __contains__(self, name: str) -> bool:
-        return name in self.offs
+        return name in self.offs or name in self.planes
 
     def or_(self, updates: dict) -> "_AccStack":
-        """OR the sub-round's updates in — one wide op a group when
-        stacked. Every live plane must have an update (all accumulation
-        sites run every sub-round)."""
-        if self.stacked:
-            for part, group in self.groups.items():
-                with self._scope(part):
-                    n, _, w = self.bufs[part].shape
-                    upd = jnp.concatenate(
-                        [updates[name].reshape(n, lanes, w)
-                         for name, lanes, _ in group], axis=1)
-                    self.bufs[part] = self.bufs[part] | upd
-        else:
-            for name, _, _, part in self.specs:
+        """OR the sub-round's updates in — one wide op for the stacked
+        lanes of a part, one a plane otherwise. Every live plane must
+        have an update (all accumulation sites run every sub-round)."""
+        for part, group in self.groups.items():
+            with self._scope(part):
+                n, _, w = self.bufs[part].shape
+                upd = jnp.concatenate(
+                    [updates[name].reshape(n, 1, w) for name, _ in group],
+                    axis=1)
+                self.bufs[part] = self.bufs[part] | upd
+        for name, _, _, part in self.specs:
+            if name in self.planes:
                 with self._scope(part):
                     self.planes[name] = self.planes[name] | updates[name]
         return self
 
     def keep(self, keep_w: jax.Array) -> "_AccStack":
         """AND the recycled-slot keep mask into every keep-masked plane —
-        one wide op a group when stacked (planes that must survive
-        recycling, e.g. the exact-trace dup plane, ride an all-ones lane
-        mask)."""
-        if self.stacked:
-            for part, group in self.groups.items():
+        one wide op for the stacked lanes of a part (a lane that must
+        survive recycling would ride an all-ones mask), one a plane
+        otherwise (the exact-trace dup plane, not keep-masked, takes
+        none)."""
+        for part, group in self.groups.items():
+            with self._scope(part):
+                lane_masked = jnp.asarray([m for _, m in group], bool)
+                mask = jnp.where(
+                    lane_masked[:, None], keep_w[None, :],
+                    jnp.uint32(0xFFFFFFFF))
+                self.bufs[part] = self.bufs[part] & mask[None]
+        for name, lanes, masked, part in self.specs:
+            if masked and name in self.planes:
+                km = keep_w[None, :] if lanes == 1 else keep_w[None, None, :]
                 with self._scope(part):
-                    lane_masked = jnp.asarray(
-                        [m for _, lanes, m in group for _ in range(lanes)],
-                        bool)
-                    mask = jnp.where(
-                        lane_masked[:, None], keep_w[None, :],
-                        jnp.uint32(0xFFFFFFFF))
-                    self.bufs[part] = self.bufs[part] & mask[None]
-        else:
-            for name, lanes, masked, part in self.specs:
-                if masked:
-                    km = keep_w[None, :] if lanes == 1 else keep_w[None, None, :]
-                    with self._scope(part):
-                        self.planes[name] = self.planes[name] & km
+                    self.planes[name] = self.planes[name] & km
         return self
 
     def get(self, name: str, default=None):
+        if name in self.planes:
+            return self.planes[name]
         if name not in self.offs:
             return default
-        if not self.stacked:
-            return self.planes[name]
-        part, off, lanes = self.offs[name]
-        if lanes == 1:
-            return self.bufs[part][:, off, :]
-        return self.bufs[part][:, off : off + lanes, :]
+        part, lane = self.offs[name]
+        return self.bufs[part][:, lane, :]
 
 
 @spans.span("setup.step_build")
@@ -639,11 +645,14 @@ def make_gossipsub_phase_step(
         # origin advertises and IWANT-serves its own invalid publishes
         # from mcache, so invalid arrivals repeat across rounds on the
         # same edge. The trans plane stays.)
-        # the live attribution planes, folded through _AccStack: one OR +
-        # one keep-AND per sub-round over the whole stack when
-        # cfg.wire_coalesced, per-plane folds (the legacy kernel
-        # structure) otherwise. The exact-trace dup plane is the one
-        # NON-keep-masked lane — see the dup_trace comment below.
+        # the live attribution planes, folded through _AccStack: when
+        # cfg.wire_coalesced one OR + one keep-AND per sub-round over the
+        # stacked [N, W] planes and one each a K-wide plane (a buffer of
+        # its own since PR 40: stacked too they kept the sub-round
+        # gathers' tables out of the chip's fast memory), per-plane folds
+        # throughout (the legacy kernel structure) otherwise. The
+        # exact-trace dup plane is the one NON-keep-masked plane — see the
+        # dup_trace comment below.
         acc_specs = []
         if plane_score:
             acc_specs += [("new", 1, True, None), ("recv", 1, True, None)]

@@ -15,13 +15,19 @@ round at 100k peers (PERF.md §6, PR 34). One JSON line: the ``while`` ops,
 the ops inside their bodies, and those whose tuple carries such a buffer,
 after the sha256 of the window's LOWERED text (StableHLO, constants and
 all: two commits whose windows lower to one text run one program).
-``--lower-only`` stops there (seconds, where the compile takes a minute).
+``--lower-only`` stops there (seconds, where the compile takes a minute;
+``lower_s`` / ``compile_s`` count from the builder's call on).
 ``edge_gathers`` counts the gathers under ``gs.edge_gather`` by the stage
 they serve, the rows they give, the rows of their table and the words of a
 row, with the memory space XLA gave table, indices and output (``S(1)`` is
 the fast one: PERF.md §5 item 5): a plane that crossed in column slices
 (``ops/edges.word_slices``) shows as one gather a slice, each of at most a
 tile of words, not as one of the whole width.
+``edge_tables_hbm_per_dispatch`` counts those of at least Np rows (N
+rounded up to 128: the head gathers, one a sub-round and one a slice of the
+control head) whose table is NOT in ``S(1)``: 0 where every big gather of
+the one-phase window reads its table from the fast space (5 of 10 at
+``sybil-50k.stepped`` until PR 40, PERF.md §6).
 """
 
 from __future__ import annotations
@@ -109,6 +115,40 @@ def edge_gathers(text: str) -> list[dict]:
             for key, n in sorted(found.items(), key=str)]
 
 
+def edge_tables_hbm(gathers: list[dict], n_peers: int) -> int:
+    """Of ``edge_gathers``' list: the gathers of at least Np rows (``n_peers``
+    rounded up to 128) whose table is not in the fast memory space."""
+    padded = -(-n_peers // 128) * 128
+    return sum(g["count"] for g in gathers
+               if g["rows"] >= padded and not g["table_fast"])
+
+
+def lower_window(workload: str, chip, n_peers: int | None = None):
+    """A cell's window as ``benchmark/run.py`` builds it, lowered for
+    ``chip`` (a sharding on a described device) from shapes alone: the
+    cell, what its builder built, and the lowered program."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.harness import manifest as mf
+
+    manifest = mf.load_manifest(ROOT)
+    cell = mf.find_cell(manifest, workload)
+    config = mf.load_config(manifest, cell["config"], ROOT)
+    mix = mf.load_traffic(cell["traffic"], ROOT)
+    built = mf.load_plugin("builders", config["builder"], ROOT).build(
+        config, 1, jax.devices()[:1], n_peers=n_peers)
+    window = built.make_window(int(mix["unroll_phases"]))
+    on = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    rounds = int(mix["segment_phases"]) * built.rounds_per_phase
+    pubs = (rounds, int(mix["pubs_per_round"]))
+    xs = (jax.ShapeDtypeStruct(pubs, jnp.int32),
+          jax.ShapeDtypeStruct(pubs, jnp.int32),
+          jax.ShapeDtypeStruct(pubs, bool))
+    return cell, built, window.lower(on(jax.eval_shape(built.fresh)), *on(xs))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -123,32 +163,15 @@ def main(argv=None) -> int:
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from benchmark.harness import manifest as mf
-
     jax.config.update("jax_enable_compilation_cache", False)
-    manifest = mf.load_manifest(ROOT)
-    cell = mf.find_cell(manifest, args.workload)
-    config = mf.load_config(manifest, cell["config"], ROOT)
-    mix = mf.load_traffic(cell["traffic"], ROOT)
-    built = mf.load_plugin("builders", config["builder"], ROOT).build(
-        config, 1, jax.devices()[:1], n_peers=args.n_peers)
-    window = built.make_window(int(mix["unroll_phases"]))
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    chip = SingleDeviceSharding(topo.devices[0])
-    on = lambda tree: jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
-    rounds = int(mix["segment_phases"]) * built.rounds_per_phase
-    pubs = (rounds, int(mix["pubs_per_round"]))
-    xs = (jax.ShapeDtypeStruct(pubs, jnp.int32),
-          jax.ShapeDtypeStruct(pubs, jnp.int32),
-          jax.ShapeDtypeStruct(pubs, bool))
     t0 = time.perf_counter()
-    lowered = window.lower(on(jax.eval_shape(built.fresh)), *on(xs))
+    cell, built, lowered = lower_window(
+        args.workload, SingleDeviceSharding(topo.devices[0]), args.n_peers)
     sha = hashlib.sha256(lowered.as_text().encode()).hexdigest()
     if args.lower_only:
         print(json.dumps({"workload": cell["name"], "n_peers": built.n_peers,
@@ -161,6 +184,7 @@ def main(argv=None) -> int:
         with open(args.text, "w") as f:
             f.write(text)
     flat = [w for w in found if w["flat_u32_words"]]
+    gathers = edge_gathers(text)
     print(json.dumps({
         "workload": cell["name"], "compiled_for": str(topo.devices[0]),
         "lowered_sha256": sha,
@@ -171,7 +195,9 @@ def main(argv=None) -> int:
         "flat_u32_words": sorted(
             (w for f in flat for w in f["flat_u32_words"]), reverse=True),
         "gathers": len(re.findall(r" gather\(", text)),
-        "edge_gathers": edge_gathers(text),
+        "edge_tables_hbm_per_dispatch": edge_tables_hbm(gathers,
+                                                        built.n_peers),
+        "edge_gathers": gathers,
     }))
     return 0
 

@@ -142,6 +142,38 @@ def test_tiered_gather_compiles_without_a_relayout_loop(one_chip, words):
     assert big == {5: [(5, 1, 1)], 13: [(5, 1, 1), (8, 1, 1)]}[words]
 
 
+def test_sybil_window_gathers_read_their_tables_from_fast_memory(
+        one_chip, bench_prng):
+    """``sybil-50k.stepped``'s window, built as ``scripts/window_whiles.py``
+    builds it and compiled for the described chip: each of the ten edge
+    gathers of 1,201,152 rows (one a sub-round, one a slice of the control
+    head) has its 1,751,680-row table in the fast memory space ``S(1)``.
+    With the K-wide attribution planes concatenated into ``[N, 2K, W]`` /
+    ``[N, 3K, W]`` stacks XLA's memory-space assignment left five of the
+    ten tables in HBM, at 2.86 x the time on the chip (PERF.md §6, PR 40):
+    the cell every gather PR watches, because its planes ride on the
+    gather's neighbours."""
+    import collections
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "scripts"))
+    import window_whiles
+
+    _, built, lowered = window_whiles.lower_window("sybil-50k.stepped",
+                                                   one_chip)
+    gathers = window_whiles.edge_gathers(lowered.compile().as_text())
+    big = [g for g in gathers if g["rows"] == 1_201_152]
+    assert all(g["table_rows"] == 1_751_680 for g in big), big
+    by_stage = collections.Counter()
+    for g in big:
+        by_stage[g["stage"]] += g["count"]
+    assert by_stage == {"data_round": 8, "control_head": 2}, gathers
+    assert all(g["table_fast"] for g in big), big
+    assert window_whiles.edge_tables_hbm(gathers, built.n_peers) == 0
+
+
 # ---------------------------------------------------------------------------
 # the PJRT C-API bridge (native/pjrt_bridge.cc) against the TPU library:
 # load a real PJRT plugin, compile StableHLO exported from jax, execute

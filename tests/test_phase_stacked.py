@@ -1,7 +1,8 @@
 """Stacked/coalesced vs legacy parity suite (round-7 tentpole).
 
 The round-7 data-plane restructuring — the coalesced wire exchange, the
-leading-axis-stacked attribution accumulators (_AccStack), the
+attribution accumulators of _AccStack (the [N, W] planes as lanes of one
+stacked tensor, each [N, K, W] plane in a buffer of its own), the
 phase-head publish plan (state.PhasePubPlan), and the stacked
 recycled-slot clears in allocate_publishes — claims BIT-IDENTICAL
 semantics to the legacy per-plane path. This suite is that claim's
@@ -30,7 +31,10 @@ from go_libp2p_pubsub_tpu import graph
 from go_libp2p_pubsub_tpu.config import PeerGaterParams
 from go_libp2p_pubsub_tpu.models.floodsub import floodsub_step
 from go_libp2p_pubsub_tpu.models.gossipsub import make_gossipsub_step
-from go_libp2p_pubsub_tpu.models.gossipsub_phase import make_gossipsub_phase_step
+from go_libp2p_pubsub_tpu.models.gossipsub_phase import (
+    _AccStack,
+    make_gossipsub_phase_step,
+)
 from go_libp2p_pubsub_tpu.models.randomsub import make_randomsub_step
 from go_libp2p_pubsub_tpu.state import Net, PhasePubPlan, SimState, allocate_publishes
 
@@ -105,6 +109,57 @@ def test_phase_stacked_vs_legacy_validation_delay_trace_exact():
         trace_exact=True,
     )
     assert_states_equal(sa, sb, "stacked-valdelay/")
+
+
+def test_phase_stacked_vs_legacy_every_k_wide_plane():
+    """All five K-wide attribution planes live at once (the suite's P3
+    and P4 weights: ``mcw`` / ``trans``; the gater: ``dup`` / ``rejw`` /
+    ``ignw``) plus the exact-trace ``dupt``, each folded in a buffer of
+    its own on the stacked side, at r = 8 with invalid and ignored
+    publishes in the schedule and 8 x 4 publishes a phase into M = 64
+    slots: from the third phase on every phase recycles slots."""
+    sa, sb = _ab_phase(8, rounds=48, seed=17,
+                       gater_params=PeerGaterParams(), trace_exact=True)
+    for name, plane in (("imd", sa.score.imd), ("reject", sa.gater.reject),
+                        ("ignore", sa.gater.ignore),
+                        ("duplicate", sa.gater.duplicate),
+                        ("dup_trans", sa.dup_trans)):
+        assert bool(jnp.any(plane != 0)), f"{name} is idle: nothing compared"
+    assert_states_equal(sa, sb, "stacked-kwide/")
+
+
+@pytest.mark.parametrize("wide", [False, True])
+def test_acc_stack_folds_as_the_legacy_planes(wide):
+    """_AccStack alone: after the same or_ / keep sequence, get(name) of
+    the stacked form (the [N, W] planes lanes of one buffer a part, each
+    [N, K, W] plane its own) equals the legacy per-plane arrays, for
+    masked and unmasked planes of both shapes; a window with no K-wide
+    plane (wide off) holds no array outside the stacks."""
+    n, k, w = 12, 5, 3
+    specs = [("a", 1, True, None), ("b", 1, False, None),
+             ("c", 1, True, "gater")]
+    if wide:
+        specs += [("t", k, True, "attrib"), ("d", k, True, "gater"),
+                  ("u", k, False, None)]
+    rng = np.random.default_rng(int(wide))
+    word = lambda *shape: jnp.asarray(
+        rng.integers(0, 2**32, size=shape, dtype=np.uint32))
+    both = [_AccStack(specs, n, w, stacked=s) for s in (True, False)]
+    assert set(both[0].planes) == {nm for nm, ln, _, _ in specs if ln > 1}
+    assert {nm for g in both[0].groups.values() for nm, _ in g} == {
+        nm for nm, ln, _, _ in specs if ln == 1}
+    assert not both[1].bufs
+    for _ in range(3):
+        upd = {nm: word(n, w) if ln == 1 else word(n, ln, w)
+               for nm, ln, _, _ in specs}
+        keep_w = word(w)
+        both = [acc.or_(upd).keep(keep_w) for acc in both]
+    for nm, ln, masked, _ in specs:
+        got, want = (np.asarray(acc.get(nm)) for acc in both)
+        assert got.shape == ((n, w) if ln == 1 else (n, ln, w))
+        np.testing.assert_array_equal(got, want, err_msg=nm)
+        assert nm in both[0] and nm in both[1]
+    assert "absent" not in both[0] and both[0].get("absent", 7) == 7
 
 
 @pytest.mark.slow
