@@ -82,6 +82,18 @@ def cell_metrics(manifest: dict, cell: str, group: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
+def four_chip_breaches(workloads: list) -> list:
+    """The driver's rule on four-chip cells (each costs four chips in every
+    run of every later check): of the cells at most half, rounded down,
+    ask for 4 chips, and one always may."""
+    four = [w["name"] for w in workloads if w["chips"] == 4]
+    allowed = max(1, len(workloads) // 2)
+    if len(four) <= allowed:
+        return []
+    return [f"{len(four)} of {len(workloads)} workloads ask for 4 chips "
+            f"({', '.join(four)}): at most {allowed} may"]
+
+
 def check_manifest(manifest: dict, root: str = ROOT) -> list:
     """Every breach of the naming and wiring rules, as strings (empty:
     none). What the driver refuses before any run, checked here first."""
@@ -119,6 +131,7 @@ def check_manifest(manifest: dict, root: str = ROOT) -> list:
         path = os.path.join(bench_dir(root), "traffic", w["traffic"] + ".json")
         if not os.path.exists(path):
             bad.append(f"workload {w['name']}: no traffic file {path}")
+    bad.extend(four_chip_breaches(manifest["workloads"]))
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
     if "setup_s" not in e2e:
         bad.append("end_to_end lacks setup_s")

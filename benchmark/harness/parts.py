@@ -5,14 +5,16 @@ stages, such as the fanout path. A part's ops stay booked to their stage
 too (``harness/stages.py``): parts do not add up to the window, and a
 fusion whose root lies outside the scope is not counted.
 
-Where the program gives no part map (a commit before the scopes, a window
-over more than one device, no one traced window in the trace), every
-function here returns ``None`` and raises nothing.
+A trace of several device planes reduces to the mean over them, as
+``harness/stages.py``'s does. Where the program gives no part map (a
+commit before the scopes, its answer for a sharded window today, no one
+traced window in the trace), every function here returns ``None`` and
+raises nothing.
 """
 
 from __future__ import annotations
 
-from benchmark.harness import stages, trace
+from benchmark.harness import stages
 
 MEMO_KEY = "part_trace"
 
@@ -22,23 +24,14 @@ def part_seconds(run: dict, windows=None) -> dict | None:
     over the traced window, worked out once per run."""
     if MEMO_KEY in run:
         return run[MEMO_KEY]
-    out = None
     tr = run.get("device_trace")
     if windows is None:
         windows = stages.traced_windows()
-    if tr and windows and len(tr["devices"]) == 1:
-        ran = {stages.module_base(m[0]) for dev in tr["devices"].values()
-               for m in dev["modules"]}
-        ours = [w for w in windows if w.module_name in ran]
-        part_of = (getattr(ours[0], "parts", lambda: None)()
-                   if len(ours) == 1 else None)
-        if part_of is not None:
-            (dev,) = tr["devices"].values()
-            inside = stages.ops_inside(dev, ours[0].module_name)
-            out = {}
-            for name, sec in trace.self_times(inside).items():
-                if name in part_of:
-                    out[part_of[name]] = out.get(part_of[name], 0.0) + sec
+    window = stages.the_window(tr, windows)
+    part_of = getattr(window, "parts", lambda: None)()
+    out = None
+    if part_of is not None:
+        out, _ = stages.self_seconds_by(tr, window.module_name, part_of.get)
     run[MEMO_KEY] = out
     return out
 

@@ -10,9 +10,12 @@ op than the window's. So only op events that begin inside a module event
 of the window's name (``jit_gs_window_v1(...)``) are looked up. Self
 times are ``trace.self_times``: a ``while`` spans its body's ops.
 
-Where the program has no such module (a commit before the scopes), or
-its window ran over more than one device (the program gives no map
-there yet), every function here returns ``None`` and raises nothing.
+A trace of several device planes (a window sharded over chips) reduces
+to the MEAN over the planes, as ``trace.reduce`` takes ``busy_s``; one
+plane reads its own sums. Where the program has no such module (a commit
+before the scopes), or gives no map for its window (its answer for a
+sharded window, today), every function here returns ``None`` and raises
+nothing.
 """
 
 from __future__ import annotations
@@ -56,47 +59,71 @@ def ops_inside(dev: dict, module_name: str) -> list:
     return [e for e, k in zip(dev["ops"], keep) if k]
 
 
+def self_seconds_by(device_trace: dict, module_name: str, key_of) -> tuple:
+    """``({key: self seconds}, op events)`` inside the modules of that name,
+    each summed over the device planes and divided by their number (the
+    mean ``trace.reduce`` takes for ``busy_s``); ``key_of(op name)`` gives
+    the key an op is booked to, ``None`` to leave it out. One plane reads
+    its own sums."""
+    n_dev = len(device_trace["devices"])
+    seconds: dict = {}
+    ops = 0
+    for dev in device_trace["devices"].values():
+        inside = ops_inside(dev, module_name)
+        ops += len(inside)
+        for name, sec in trace.self_times(inside).items():
+            key = key_of(name)
+            if key is not None:
+                seconds[key] = seconds.get(key, 0.0) + sec
+    return {k: v / n_dev for k, v in seconds.items()}, ops / n_dev
+
+
 def reduce(device_trace: dict, window) -> dict | None:
     """``{"seconds": {stage: self seconds}, "ops": op events, "unmapped":
     op names the map lacks}`` inside the modules of ``window`` (an entry of
-    the program's registry). An op the compiled text does not hold is
-    none of the program's: it is booked as ``unscoped``, and counted."""
-    if len(device_trace["devices"]) != 1:
-        return None
+    the program's registry), the MEAN over the device planes of the trace.
+    An op the compiled text does not hold is none of the program's: it is
+    booked as ``unscoped``, and counted. A window that gives no map (the
+    program's answer for a sharded one, today) reduces to ``None``."""
     stage_of = window.stages()
     if stage_of is None:
         return None
-    (dev,) = device_trace["devices"].values()
-    inside = ops_inside(dev, window.module_name)
-    if not inside:
-        return None
-    seconds: dict = {}
     unmapped = []
-    for name, sec in trace.self_times(inside).items():
-        if name not in stage_of:
+
+    def key_of(name):
+        if name not in stage_of and name not in unmapped:
             unmapped.append(name)
-        stage = stage_of.get(name, UNSCOPED)
-        seconds[stage] = seconds.get(stage, 0.0) + sec
-    return {"seconds": seconds, "ops": len(inside), "unmapped": unmapped}
+        return stage_of.get(name, UNSCOPED)
+
+    seconds, ops = self_seconds_by(device_trace, window.module_name, key_of)
+    if not ops:
+        return None
+    return {"seconds": seconds, "ops": ops, "unmapped": unmapped}
+
+
+def the_window(tr: dict | None, windows):
+    """The one traced window whose module ran in the trace. Two windows of
+    one module name that both ran cannot be told apart by their ops'
+    names: ``None``."""
+    if not tr or not windows:
+        return None
+    ran = {module_base(m[0]) for dev in tr["devices"].values()
+           for m in dev["modules"]}
+    ours = [w for w in windows if w.module_name in ran]
+    return ours[0] if len(ours) == 1 else None
 
 
 def stage_trace(run: dict, windows=None) -> dict | None:
     """``reduce`` of the run's trace over the one traced window whose
-    module ran in it, worked out once per run (ten readers, one
-    reduction). Two windows of one module name that both ran cannot be
-    told apart by their ops' names: ``None``."""
+    module ran in it (``the_window``), worked out once per run (ten
+    readers, one reduction)."""
     if MEMO_KEY in run:
         return run[MEMO_KEY]
-    out = None
     tr = run.get("device_trace")
     if windows is None:
         windows = traced_windows()
-    if tr and windows:
-        ran = {module_base(m[0]) for dev in tr["devices"].values()
-               for m in dev["modules"]}
-        ours = [w for w in windows if w.module_name in ran]
-        if len(ours) == 1:
-            out = reduce(tr, ours[0])
+    window = the_window(tr, windows)
+    out = None if window is None else reduce(tr, window)
     run[MEMO_KEY] = out
     return out
 

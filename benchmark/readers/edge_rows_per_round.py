@@ -11,14 +11,9 @@ from benchmark.harness import stages
 
 
 def read(run: dict):
-    windows = stages.traced_windows()
-    tr = run.get("device_trace")
-    if not windows or not tr or not run.get("rounds_per_phase"):
+    if not run.get("rounds_per_phase"):
         return None
-    ran = {stages.module_base(m[0]) for dev in tr["devices"].values()
-           for m in dev["modules"]}
-    ours = [w for w in windows if w.module_name in ran]
-    if len(ours) != 1:
-        return None
-    rows = getattr(ours[0], "edge_rows_per_dispatch", None)
+    window = stages.the_window(run.get("device_trace"),
+                               stages.traced_windows())
+    rows = getattr(window, "edge_rows_per_dispatch", None)
     return None if rows is None else rows / run["rounds_per_phase"]

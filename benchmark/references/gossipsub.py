@@ -23,7 +23,8 @@ fixes, whatever the draws, is checked answer by answer:
                   over mesh edges both ends agree on)
   mesh_off_graph  mesh membership only over real edges
   mesh_degree_out (peer, topic) pairs whose mesh degree after the window's
-                  last heartbeat is over D_hi, or under D_lo while a
+                  last heartbeat is over D_hi but by the heartbeat's own
+                  outbound top-up (``over_d_hi``), or under D_lo while a
                   neighbour could still be grafted (on the graph, not in
                   the mesh, no backoff entry, score not negative)
   backoff_in_mesh mesh edges still under a prune backoff
@@ -108,8 +109,16 @@ def scores_from_counters(ans: dict, graph: dict, subs: dict, sc: dict,
     and sum in ``dtype``."""
     f = lambda x: np.asarray(x, dtype=dtype)
     # membership as the heartbeat's refresh saw it: the scores are taken
-    # at the top of the heartbeat (gossipsub.go:1303 ff.), before it prunes
-    in_mesh = ans["mesh"] | ans["prune_out"]                # [N,S,K]
+    # at the top of the heartbeat (gossipsub.go:1303 ff.), before it prunes.
+    # The PRUNE outbox also holds the answers to GRAFTs refused at the
+    # phase's head (mesh full, backoff: gossipsub.go:753-792), edges that
+    # were in no mesh at the refresh: those keep the time-in-mesh of an
+    # earlier membership, which the refresh did not touch, while an edge
+    # the heartbeat itself pruned has the time the refresh just gave it
+    last = int(ans["tick"]) - 1
+    graft = ans["graft_tick"].astype(np.int64)
+    fresh = (graft >= 0) & (ans["mesh_time"] == last - graft)
+    in_mesh = ans["mesh"] | (ans["prune_out"] & fresh)      # [N,S,K]
     quantum = f(max(1.0, np.ceil(sc["time_in_mesh_quantum_s"])))
     p1 = np.minimum(f(ans["mesh_time"]) / quantum, f(sc["time_in_mesh_cap"]))
     topic = np.where(in_mesh, p1 * f(sc["time_in_mesh_weight"]), f(0.0))
@@ -127,6 +136,22 @@ def score_gap(program: np.ndarray, reference: np.ndarray) -> float:
     ref = reference.astype(np.float64)
     scale = max(1.0, float(np.abs(ref).max()))
     return float(np.abs(program.astype(np.float64) - ref).max()) / scale
+
+
+def over_d_hi(mesh: np.ndarray, outbound: np.ndarray, mp: dict) -> np.ndarray:
+    """``[N, S]``: meshes over D_hi after a heartbeat that the protocol
+    does not allow. The heartbeat prunes a mesh over D_hi down to D and
+    THEN tops up the outbound quota of every mesh of D_lo or more, D_hi
+    included (gossipsub.go:1451-1476): it grafts D_out less the outbound
+    members the mesh holds, each a peer this one dialled (``outbound``
+    ``[N, K]``, the graph's own plane). So a mesh may stand over D_hi only
+    by outbound members, no more than it holds, and holds no more than
+    D_out of them (hence at most D_hi + D_out members); the next
+    heartbeat prunes it."""
+    deg = mesh.sum(axis=2)
+    out = (mesh & outbound[:, None, :]).sum(axis=2)
+    over = deg - int(mp["D_hi"])
+    return (over > 0) & ((out > int(mp["D_out"])) | (over > out))
 
 
 def check(ans: dict, graph: dict, subs: dict, config: dict, tail: dict,
@@ -247,7 +272,7 @@ def check(ans: dict, graph: dict, subs: dict, config: dict, tail: dict,
         graftable &= (ans["scores"] >= 0)[:, None, :]
     joined = subs["my_topics"] >= 0
     number("mesh_degree_out", int(np.sum(joined & (
-        (deg > int(mp["D_hi"]))
+        over_d_hi(mesh, graph["outbound"], mp)
         | ((deg < int(mp["D_lo"])) & graftable.any(axis=2))))))
     number("backoff_in_mesh", int(np.sum(mesh & backoff)))
     number("ihave_mismatch", ihave_mismatch(

@@ -57,7 +57,8 @@ round's phase.
                   latest first receipt among such peers, in rounds after
                   the birth
   mesh_degree_out after the last heartbeat no up (peer, topic) is over
-                  D_hi, or under D_lo while an UP neighbour could be
+                  D_hi but by the heartbeat's own outbound top-up
+                  (``over_d_hi``), or under D_lo while an UP neighbour could be
                   grafted (live edge, not in the mesh, no backoff entry,
                   score not negative)
   ihave_mismatch  gossip emission as ``references/gossipsub.py``, the
@@ -100,6 +101,7 @@ dtype_of = _subnets.dtype_of
 allocate = _subnets.allocate
 scores_from_counters = _subnets.scores_from_counters
 score_gap = _subnets.score_gap
+over_d_hi = _subnets.over_d_hi
 
 WORD = 32
 #: a counter that is a sum of decayed credits is compared one-sidedly
@@ -282,7 +284,7 @@ def check(ans: dict, graph: dict, subs: dict, config: dict, tail: dict,
                  & ~ans["backoff_present"])
     joined = (subs["my_topics"] >= 0) & up_end[:, None]
     number("mesh_degree_out", int(np.sum(joined & (
-        (deg > int(mp["D_hi"]))
+        over_d_hi(mesh, graph["outbound"], mp)
         | ((deg < int(mp["D_lo"])) & graftable.any(axis=2))))))
     number("backoff_in_mesh", int(np.sum(mesh & backoff)))
     # a backoff entry is stamped expire = its round + the prune backoff: a

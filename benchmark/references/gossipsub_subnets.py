@@ -142,6 +142,22 @@ def score_gap(program: np.ndarray, reference: np.ndarray) -> float:
     return float(np.abs(program.astype(np.float64) - ref).max()) / scale
 
 
+def over_d_hi(mesh: np.ndarray, outbound: np.ndarray, mp: dict) -> np.ndarray:
+    """``[N, S]``: meshes over D_hi after a heartbeat that the protocol
+    does not allow. The heartbeat prunes a mesh over D_hi down to D and
+    THEN tops up the outbound quota of every mesh of D_lo or more, D_hi
+    included (gossipsub.go:1451-1476): it grafts D_out less the outbound
+    members the mesh holds, each a peer this one dialled (``outbound``
+    ``[N, K]``, the graph's own plane). So a mesh may stand over D_hi only
+    by outbound members, no more than it holds, and holds no more than
+    D_out of them (hence at most D_hi + D_out members); the next
+    heartbeat prunes it."""
+    deg = mesh.sum(axis=2)
+    out = (mesh & outbound[:, None, :]).sum(axis=2)
+    over = deg - int(mp["D_hi"])
+    return (over > 0) & ((out > int(mp["D_out"])) | (over > out))
+
+
 def neighbour_subscribes(graph: dict, subs: dict, topic: np.ndarray) -> np.ndarray:
     """``[N, K]``: neighbour k of peer n is a real edge to a subscriber of
     ``topic[n]`` (nobody where ``topic[n]`` < 0)."""
@@ -364,7 +380,7 @@ def check(ans: dict, graph: dict, subs: dict, config: dict, tail: dict,
     if scored:
         graftable &= (ans["scores"] >= 0)[:, None, :]
     number("mesh_degree_out", int(np.sum(joined & (
-        (deg > int(mp["D_hi"]))
+        over_d_hi(mesh, graph["outbound"], mp)
         | ((deg < int(mp["D_lo"])) & graftable.any(axis=2))))))
     number("backoff_in_mesh", int(np.sum(mesh & backoff)))
 

@@ -184,6 +184,22 @@ def score_gap(program: np.ndarray, planes: tuple) -> float:
     return float(gap.max()) / scale
 
 
+def over_d_hi(mesh: np.ndarray, outbound: np.ndarray, mp: dict) -> np.ndarray:
+    """``[N, S]``: meshes over D_hi after a heartbeat that the protocol
+    does not allow. The heartbeat prunes a mesh over D_hi down to D and
+    THEN tops up the outbound quota of every mesh of D_lo or more, D_hi
+    included (gossipsub.go:1451-1476): it grafts D_out less the outbound
+    members the mesh holds, each a peer this one dialled (``outbound``
+    ``[N, K]``, the graph's own plane). So a mesh may stand over D_hi only
+    by outbound members, no more than it holds, and holds no more than
+    D_out of them (hence at most D_hi + D_out members); the next
+    heartbeat prunes it."""
+    deg = mesh.sum(axis=2)
+    out = (mesh & outbound[:, None, :]).sum(axis=2)
+    over = deg - int(mp["D_hi"])
+    return (over > 0) & ((out > int(mp["D_out"])) | (over > out))
+
+
 def check(ans: dict, graph: dict, subs: dict, config: dict, tail: dict,
           rounds_run: int, summaries: list) -> list:
     """Every number compared, as ``{"name", "value", "limit"}``; the run is
@@ -338,7 +354,7 @@ def check(ans: dict, graph: dict, subs: dict, config: dict, tail: dict,
         & ~ans["backoff_present"]
     joined = subs["my_topics"] >= 0
     number("mesh_degree_out", int(np.sum(joined & (
-        (deg > int(mp["D_hi"]))
+        over_d_hi(mesh, graph["outbound"], mp)
         | ((deg < int(mp["D_lo"])) & graftable.any(axis=2))))))
     number("backoff_in_mesh", int(np.sum(mesh & backoff)))
     number("mesh_negative", int(np.sum(mesh & (ans["scores"] < 0)[:, None, :])))

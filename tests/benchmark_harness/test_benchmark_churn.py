@@ -64,10 +64,11 @@ def sound():
 
 def test_the_manifest_holds_the_new_entries_and_breaks_no_rule():
     assert mf.check_manifest(MANIFEST) == []
-    entry = MANIFEST["configs"][-1]
-    assert entry["name"] == "churn-100k" and entry["reduced"] == []
+    # found by NAME: a later PR appends its own configuration and cell
+    (entry,) = [c for c in MANIFEST["configs"] if c["name"] == "churn-100k"]
+    assert entry["reduced"] == [] and CELL["config"] == entry["name"]
     assert entry["source"] == CONFIG["source"] and len(entry["source"]) <= 200
-    assert MANIFEST["workloads"][-1] == CELL
+    assert CELL["name"] == "churn-100k.stepped"
     assert CELL["chips"] == 1 and CELL["traffic"] == "stepped"
     assert CONFIG["reduced"] == [] and CONFIG["architecture"] is None
     for key in ("assumed", "guarantees", "churn", "timers", "catchup"):
@@ -239,6 +240,44 @@ def test_each_new_number_fails_under_its_planted_fault(sound, fault, number):
     assert number in failed(numbers), (fault, failed(numbers))
 
 
+def _mesh_of(ans, built, run, n_out, n_in):
+    """The answers with peer ``p``'s mesh made of ``n_out`` neighbours it
+    dialled and ``n_in`` that dialled it, all up."""
+    a = copy.deepcopy(ans)
+    up = _history(run)[-1]
+    ok, outb = built.graph["nbr_ok"], built.graph["outbound"]
+    live = ok & up[:, None] & up[np.clip(built.graph["nbr"], 0, None)]
+    fits = ((live & outb).sum(axis=1) >= n_out) & ((live & ~outb).sum(axis=1)
+                                                   >= n_in)
+    p = int(np.flatnonzero(fits)[0])
+    a["mesh"][p, 0] = False
+    a["mesh"][p, 0, np.flatnonzero(live[p] & outb[p])[:n_out]] = True
+    a["mesh"][p, 0, np.flatnonzero(live[p] & ~outb[p])[:n_in]] = True
+    return a
+
+
+@pytest.mark.parametrize("members,breach", [
+    # (D_hi, D_out) -> (members the peer dialled, members that dialled it)
+    (lambda hi, out: (1, hi), False),       # D_hi + 1: the outbound top-up
+    (lambda hi, out: (out, hi), False),     # D_hi + D_out: the most it leaves
+    (lambda hi, out: (out + 1, hi - out), True),   # D_hi + 1 with D_out
+                                            # outbound in it and one more
+    (lambda hi, out: (0, hi + 1), True),    # over D_hi by a peer that dialled IN
+], ids=["topped_up_by_one", "topped_up_by_D_out", "outbound_over_D_out",
+        "inbound_over_D_hi"])
+def test_mesh_degree_out_says_what_the_heartbeat_does(sound, members, breach):
+    """Upstream tops up the outbound quota AFTER it pruned to D, on any mesh
+    of D_lo or more (gossipsub.go:1451-1476): such a mesh over D_hi is the
+    protocol's own (seed 3800000214 read 1 for it on the chip, PR 38);
+    another over D_hi is a breach, counted once."""
+    built, run = sound
+    mp = CONFIG["mesh_params"]
+    assert judge(built, run)["mesh_degree_out"]["value"] == 0
+    planted = _mesh_of(run["answers"], built, run,
+                       *members(mp["D_hi"], mp["D_out"]))
+    assert judge(built, run, planted)["mesh_degree_out"]["value"] == int(breach)
+
+
 @pytest.mark.parametrize("control,caught_by", [
     ({"program_static_peers": True}, {"up_mismatch", "down_holders"}),
     ({"program_mesh_params": {"D_lazy": 0, "gossip_factor": 0.0}},
@@ -303,10 +342,13 @@ def test_the_dynamic_window_has_the_churn_part_and_the_reader_reads_it(
     window = ours[-1]
     part_of, stage_of = window.parts(), window.stages()
     assert set(part_of.values()) == {"churn"}
-    # the part lies inside the stages its callers are: the head (the
-    # transitions, the views, the publish gate) and the two liveness peer
-    # gathers, which are booked to edge_gather
-    assert {stage_of[i] for i in part_of} >= {"control_head", "edge_gather"}
+    # the part lies inside the stage its callers are: the head (the
+    # transitions, the views, the publish gate). Since PR 38 the liveness
+    # code crosses once a phase as a 2-bit code through the planned edge
+    # gather, booked to edge_gather today; a build that carries the bits
+    # inside the head's own wire exchange books no op of the part there,
+    # so only the head is held
+    assert {stage_of[i] for i in part_of} >= {"control_head"}
     monkeypatch.setattr(program, "traced_windows", lambda: [window])
     ops = sorted(part_of)[:10]
     fake = dict(run, device_trace={"devices": {"/device:TPU:0": {

@@ -15,9 +15,11 @@ from go_libp2p_pubsub_tpu.perf import stages as program
 
 MANIFEST = mf.load_manifest()
 READER = mf.load_plugin("readers", "edge_rows_per_round")
+#: the live window's module, by the program's own name for it
+W = "jit_" + program.window_name()
 TRACE = {"devices": {"/device:TPU:0": {
     "ops": [["fusion.1", 100, 20]],
-    "modules": [["jit_gs_window_v1(1)", 100, 60]]}}, "spans": []}
+    "modules": [[W + "(1)", 100, 60]]}}, "spans": []}
 
 
 def toy_window(monkeypatch, cell_name):
@@ -63,14 +65,14 @@ class Window:
 @pytest.mark.parametrize("windows,want,why", [
     (None, None, "a commit without the registry"),
     ([], None, "no window traced"),
-    ([Window("jit_gs_window_v1")], None, "a commit without the counter"),
-    ([Window("jit_gs_window_v1", edge_rows_per_dispatch=None)], None,
+    ([Window(W)], None, "a commit without the counter"),
+    ([Window(W, edge_rows_per_dispatch=None)], None,
      "the trace replayed a step traced before it"),
     ([Window("jit_run", edge_rows_per_dispatch=8.0)], None,
      "its module did not run in the trace"),
-    ([Window("jit_gs_window_v1", edge_rows_per_dispatch=8.0)] * 2, None,
+    ([Window(W, edge_rows_per_dispatch=8.0)] * 2, None,
      "two windows of one name"),
-    ([Window("jit_gs_window_v1", edge_rows_per_dispatch=36_900_000.0)],
+    ([Window(W, edge_rows_per_dispatch=36_900_000.0)],
      4_612_500.0, "nine gathers of 4.1 M rows a phase of 8 rounds"),
 ])
 def test_edge_rows_reader_by_hand(monkeypatch, windows, want, why):

@@ -1,11 +1,11 @@
-"""The seven ``setup_*`` per-layer metrics: their entries (waiting in
-``benchmark/data/per_layer_waiting.json`` with the five older ones until a
-``benchmark`` PR lifts the pin on the manifest's last ten names), the one
-reduction ``benchmark/harness/setup.py`` on spans written out by hand, and
-the readers on a toy run's recorder."""
+"""The seven ``setup_*`` per-layer metrics and the five older ones that
+waited with them (``edge_rows_per_round``, the four ``part_us_*``): their
+entries in ``BENCHMARK.json`` as their PRs gave them (PR 41 moved them in
+from ``benchmark/data/per_layer_waiting.json``), the one reduction
+``benchmark/harness/setup.py`` on spans written out by hand, and the
+readers on a toy run's recorder."""
 
 import json
-import os
 import sys
 import time
 
@@ -18,8 +18,8 @@ from benchmark.harness import setup
 from go_libp2p_pubsub_tpu.perf import spans, stages
 
 MANIFEST = mf.load_manifest()
-WAITING = mf.load_json(os.path.join(mf.BENCH_DIR, "data",
-                                    "per_layer_waiting.json"))
+#: found by NAME, wherever they stand: later PRs append their own
+PER_LAYER = {m["name"]: m for m in MANIFEST["per_layer"]}
 TABLE = {
     "setup_net_build_s": ("s", "program_span", "engine"),
     "setup_state_init_s": ("s", "program_span", "engine"),
@@ -37,68 +37,44 @@ OLDER = {"part_us_attrib": "sybil-50k.stepped",
 W = stages.window_name()
 
 
-def merged() -> dict:
-    """The manifest with the waiting entries where the file says they go
-    (those it already holds left where they are)."""
-    man = json.loads(json.dumps(MANIFEST))
-    held = {m["name"] for m in man["per_layer"]}
-    new = [e for e in WAITING["entries"] if e["name"] not in held]
-    at = [m["name"] for m in man["per_layer"]].index(WAITING["after"]) + 1
-    man["per_layer"][at:at] = new
-    return man
-
-
 def test_the_names_are_the_reduction_s():
     assert tuple(TABLE) == setup.NAMES
-    assert [e["name"] for e in WAITING["entries"]] == [*TABLE, *OLDER]
+    assert set(TABLE) | set(OLDER) <= set(PER_LAYER)
 
 
 @pytest.mark.parametrize("name", TABLE)
 def test_a_setup_entry_has_the_fields_of_the_table(name):
-    (entry,) = [e for e in WAITING["entries"] if e["name"] == name]
     unit, source, layer = TABLE[name]
-    assert entry == {"name": name, "unit": unit, "better": "lower",
-                     "source": source, "layer": layer, "moves": "setup_s"}
+    assert PER_LAYER[name] == {
+        "name": name, "unit": unit, "better": "lower", "source": source,
+        "layer": layer, "moves": "setup_s"}
     assert callable(mf.load_plugin("readers", name).read)
 
 
 @pytest.mark.parametrize("name", OLDER)
-def test_an_older_entry_waits_as_its_pr_gave_it(name):
-    (entry,) = [e for e in WAITING["entries"] if e["name"] == name]
+def test_an_older_entry_stands_as_its_pr_gave_it(name):
     want = {"name": name, "unit": "us", "better": "lower",
             "source": "device_trace", "layer": "engine",
             "moves": "rounds_per_s", "workloads": [OLDER[name]]}
     if OLDER[name] is None:
         want.update(unit="count", source="program_counter")
         del want["workloads"]
-    assert entry == want
+    assert PER_LAYER[name] == want
     assert callable(mf.load_plugin("readers", name).read)
 
 
-def test_the_manifest_with_them_keeps_every_rule_and_every_pin():
-    man = merged()
-    assert mf.check_manifest(man) == []
-    names = [m["name"] for m in man["per_layer"]]
-    assert len(names) == len(set(names))
-    assert set(TABLE) | set(OLDER) <= set(names)
-    # what the other test files pin: the last ten names
-    # (test_benchmark_stages.py) and what sits at 0-2
-    # (test_benchmark_manifest.py)
-    was = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-10:] == was[-10:] and names[:3] == was[:3]
-    # every object that is there keeps every byte, and their order
-    kept = [m for m in man["per_layer"] if m["name"] in was]
-    assert kept == MANIFEST["per_layer"]
-    for group in ("command", "paths", "run_seconds", "configs", "workloads",
-                  "end_to_end"):
-        assert man[group] == MANIFEST[group]
-    # all seven in every cell; the older ones where their lists say
-    for cell in (w["name"] for w in man["workloads"]):
-        mine = {m["name"] for m in mf.cell_metrics(man, cell, "per_layer")}
+def test_the_manifest_with_them_keeps_every_rule():
+    assert mf.check_manifest(MANIFEST) == []
+    assert len(PER_LAYER) == len(MANIFEST["per_layer"])
+    assert "setup_s" in {m["name"] for m in MANIFEST["end_to_end"]}
+    # all seven in every cell, whatever cells there are; the older ones
+    # where their lists say, and in no other cell
+    for cell in (w["name"] for w in MANIFEST["workloads"]):
+        mine = {m["name"] for m in mf.cell_metrics(MANIFEST, cell, "per_layer")}
         assert set(TABLE) <= mine and "edge_rows_per_round" in mine
         for name, where in OLDER.items():
             assert (name in mine) == (where in (None, cell)), (name, cell)
-    assert len(json.dumps(man, indent=1)) < 64 * 1024
+    assert len(json.dumps(MANIFEST, indent=1)) < 64 * 1024
 
 
 def S(name, start_s, end_s, **attrs):

@@ -22,6 +22,10 @@ RECORDED = os.path.join(mf.BENCH_DIR, "data",
                         "trace_v5e_random-100k_stepped3.json")
 STAGE_READERS = ["stage_us_" + s for s in (*program.STAGES, program.UNSCOPED)]
 READERS = STAGE_READERS + ["kernels_per_round"]
+#: the LIVE window's module, by the program's own name for it (a bump of
+#: ``perf.stages.VERSION`` moves it); the recorded run keeps the name its
+#: file holds
+W = "jit_" + program.window_name()
 
 
 #: the recorded run's reduction (my chip run, PR 29)
@@ -56,8 +60,10 @@ def recorded_run():
 
 
 def test_every_new_metric_is_in_the_manifest_with_a_reader():
+    # by NAME, wherever they stand: later PRs append their own metrics
     by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert [m["name"] for m in MANIFEST["per_layer"]][-10:] == READERS
+    assert len(by_name) == len(MANIFEST["per_layer"])
+    assert set(READERS) <= set(by_name)
     for name in READERS:
         m = by_name[name]
         assert (m["source"], m["layer"], m["moves"], m["better"]) == (
@@ -75,11 +81,11 @@ def test_stage_seconds_by_hand():
                 ["fusion.2", 130, 30], ["copy.9", 160, 10],
                 ["fusion.1", 200, 10],
                 ["fusion.1", 300, 25], ["late.3", 330, 5]],
-        "modules": [["jit_gs_window_v1(11)", 100, 75],
+        "modules": [[W + "(11)", 100, 75],
                     ["jit_summary(2)", 200, 10],
-                    ["jit_gs_window_v1(11)", 300, 40]],
+                    [W + "(11)", 300, 40]],
     }}, "spans": []}
-    win = Window("jit_gs_window_v1", {
+    win = Window(W, {
         "while.1": "unscoped", "fusion.1": "edge_gather",
         "fusion.2": "deliver", "copy.9": "unscoped"})
     red = stages.reduce(tr, win)
@@ -92,7 +98,7 @@ def test_stage_seconds_by_hand():
     (dev,) = tr["devices"].values()
     assert [e[1] for e in stages.ops_inside(dev, "jit_summary")] == [200]
     assert stages.ops_inside(dev, "jit_other") == []
-    assert stages.module_base("jit_gs_window_v1(685704595)") == "jit_gs_window_v1"
+    assert stages.module_base(W + "(685704595)") == W
 
     run = {"device_trace": tr, "rounds": 4}
     assert stages.stage_seconds(run, [win]) == red["seconds"]
@@ -105,24 +111,63 @@ def test_stage_seconds_by_hand():
 @pytest.mark.parametrize("windows,why", [
     ([], "no window traced"),
     ([Window("jit_run", {})], "a commit before the scopes"),
-    ([Window("jit_gs_window_v1", None)], "sharded: the program gives no map"),
-    ([Window("jit_gs_window_v1", {}), Window("jit_gs_window_v1", {})],
+    ([Window(W, None)], "sharded: the program gives no map"),
+    ([Window(W, {}), Window(W, {})],
      "two windows of one name"),
 ])
 def test_no_stage_seconds_where_there_is_no_one_map(windows, why):
     tr = {"devices": {"/device:TPU:0": {
         "ops": [["fusion.1", 100, 20]],
-        "modules": [["jit_gs_window_v1(1)", 100, 60]]}}, "spans": []}
+        "modules": [[W + "(1)", 100, 60]]}}, "spans": []}
     run = {"device_trace": tr, "rounds": 8}
     assert stages.stage_seconds(run, windows) is None, why
     assert stages.stage_us_per_round(run, "deliver") is None
+
+
+def test_two_device_planes_reduce_to_their_mean():
+    """A window sharded over chips: each plane's ops inside the window's
+    modules by stage, then the MEAN over the planes (``trace.reduce``'s
+    rule for ``busy_s``); the same plane twice reads what it reads once;
+    with no map, nothing."""
+    a = {"ops": [["while.1", 100, 60], ["fusion.1", 100, 20],
+                 ["fusion.2", 130, 30], ["fusion.1", 200, 10]],
+         "modules": [[W + "(11)", 100, 75], ["jit_summary(2)", 200, 10]]}
+    # the second chip: the same program, a slower gather, one op more (a
+    # collective the text does not hold), its clock 1,000 ns on
+    b = {"ops": [["while.1", 1100, 80], ["fusion.1", 1100, 40],
+                 ["fusion.2", 1150, 20], ["all-reduce.7", 1170, 6]],
+         "modules": [[W + "(11)", 1100, 90]]}
+    stage_of = {"while.1": "unscoped", "fusion.1": "edge_gather",
+                "fusion.2": "deliver"}
+    one = stages.reduce({"devices": {"/device:TPU:0": a}, "spans": []},
+                        Window(W, stage_of))
+    assert one["seconds"] == pytest.approx({
+        "edge_gather": 20e-9, "deliver": 30e-9, "unscoped": 10e-9})
+    assert one["ops"] == 3 and one["unmapped"] == []
+    two = {"devices": {"/device:TPU:0": a, "/device:TPU:1": b}, "spans": []}
+    red = stages.reduce(two, Window(W, stage_of))
+    assert red["seconds"] == pytest.approx({
+        "edge_gather": (20e-9 + 40e-9) / 2, "deliver": (30e-9 + 20e-9) / 2,
+        "unscoped": (10e-9 + (80 - 40 - 20 - 6 + 6) * 1e-9) / 2})
+    assert red["ops"] == (3 + 4) / 2 and red["unmapped"] == ["all-reduce.7"]
+    twice = stages.reduce({"devices": {"x": a, "y": a}, "spans": []},
+                          Window(W, stage_of))
+    assert twice["seconds"] == one["seconds"] and twice["ops"] == one["ops"]
+    # the readers on it, and the program's answer for a sharded window today
+    run = {"device_trace": two, "rounds": 4}
+    assert stages.stage_seconds(run, [Window(W, stage_of)]) == red["seconds"]
+    assert stages.stage_us_per_round(run, "edge_gather") == pytest.approx(
+        1e6 * 30e-9 / 4)
+    run = {"device_trace": two, "rounds": 4}
+    assert stages.stage_seconds(run, [Window(W, None)]) is None
 
 
 def test_recorded_chip_run_reduces_to_pinned_numbers():
     rec, run, win = recorded_run()
     assert rec["device_kind"] == "TPU v5 lite" and rec["rounds"] == 24
     assert rec["workload"] == "random-100k.stepped" and rec["segments"] == 3
-    assert win.module_name == "jit_gs_window_v1"
+    # the name the recorded file holds (PR 29's program: VERSION 1)
+    assert win.module_name == rec["module_name"] == "jit_gs_window_v1"
     (dev,) = rec["devices"].values()
     inside = stages.ops_inside(dev, win.module_name)
     red = stages.reduce(run["device_trace"], win)
@@ -133,7 +178,7 @@ def test_recorded_chip_run_reduces_to_pinned_numbers():
     # the harness's own programs ran too, and are left out
     assert len(dev["ops"]) > len(inside)
     assert {stages.module_base(m[0]) for m in dev["modules"]} == {
-        "jit_gs_window_v1", "jit_summary"}
+        rec["module_name"], "jit_summary"}
     # the stages sum to the busy time inside the window's modules (to
     # 5 ns of 2.48 s: a few events overlap their neighbour by a ns)
     arr = np.asarray([[e[1], e[1] + e[2]] for e in inside], np.float64)
@@ -200,7 +245,7 @@ def test_an_untraced_run_lowers_nothing_and_a_traced_one_once(monkeypatch):
     assert out["result"]["correct"] and lowered == []
     assert not set(out["result"]["metrics"]) & set(READERS)
     (window,) = [w for w in program.traced_windows() if id(w) not in before]
-    assert window.module_name == "jit_gs_window_v1"
+    assert window.module_name == W
 
     # the same run with a device trace (made up: the CPU has no device
     # plane), through the readers as `--trace 1` calls them
@@ -208,7 +253,7 @@ def test_an_untraced_run_lowers_nothing_and_a_traced_one_once(monkeypatch):
     lowered.clear()
     run = dict(out["run"], device_trace={"devices": {"/device:TPU:0": {
         "ops": [[n, 100 + 10 * i, 10] for i, n in enumerate(names)],
-        "modules": [["jit_gs_window_v1(7)", 100, 1000]]}}, "spans": []})
+        "modules": [[W + "(7)", 100, 1000]]}}, "spans": []})
     monkeypatch.setattr(window, "_stages", None)
     # a test worker has traced other windows of the same name before this
     # one; the command's process makes one

@@ -12,6 +12,7 @@ import pytest
 from benchmark import run as bench_run
 from benchmark.harness import faults, parts, subnets
 from benchmark.harness import manifest as mf
+from go_libp2p_pubsub_tpu.perf import stages as program
 
 MANIFEST = mf.load_manifest()
 CELL = "eth2-100k.stepped"
@@ -281,6 +282,10 @@ def test_a_builder_refuses_a_program_with_another_fanout_ttl():
 # the part reader
 
 
+#: the live window's module, by the program's own name for it
+W = "jit_" + program.window_name()
+
+
 class Window:
     def __init__(self, module_name, part_of):
         self.module_name, self.part_of = module_name, part_of
@@ -292,27 +297,34 @@ class Window:
 TRACE = {"devices": {"/device:TPU:0": {
     "ops": [["while.1", 100, 60], ["fusion.1", 100, 20], ["fusion.2", 130, 30],
             ["fusion.1", 200, 10]],
-    "modules": [["jit_gs_window_v1(11)", 100, 75], ["jit_summary(2)", 200, 10]],
+    "modules": [[W + "(11)", 100, 75], ["jit_summary(2)", 200, 10]],
 }}, "spans": []}
 
 
 def test_part_seconds_by_hand():
     run = {"device_trace": TRACE, "rounds": 4}
-    win = Window("jit_gs_window_v1", {"fusion.2": "fanout"})
+    win = Window(W, {"fusion.2": "fanout"})
     assert parts.part_seconds(run, [win]) == pytest.approx({"fanout": 30e-9})
     assert parts.part_us_per_round(run, "fanout") == pytest.approx(1e6 * 30e-9 / 4)
     # a window that traced no fanout: the part reads 0, not nothing
     run = {"device_trace": TRACE, "rounds": 4}
-    assert parts.part_seconds(run, [Window("jit_gs_window_v1", {})]) == {}
+    assert parts.part_seconds(run, [Window(W, {})]) == {}
     assert parts.part_us_per_round(run, "fanout") == 0.0
+    # two device planes (a window sharded over chips): the mean over them
+    (dev,) = TRACE["devices"].values()
+    slower = dict(dev, ops=[[n, t, 2 * d] for n, t, d in dev["ops"]])
+    run = {"device_trace": {"devices": {"a": dev, "b": slower}, "spans": []},
+           "rounds": 4}
+    assert parts.part_seconds(run, [win]) == pytest.approx(
+        {"fanout": (30e-9 + 60e-9) / 2})
 
 
 @pytest.mark.parametrize("windows,why", [
     ([], "no window traced"),
-    ([object.__new__(type("Old", (), {"module_name": "jit_gs_window_v1"}))],
+    ([object.__new__(type("Old", (), {"module_name": W}))],
      "a commit before the parts"),
-    ([Window("jit_gs_window_v1", None)], "sharded: the program gives no map"),
-    ([Window("jit_gs_window_v1", {}), Window("jit_gs_window_v1", {})],
+    ([Window(W, None)], "sharded: the program gives no map"),
+    ([Window(W, {}), Window(W, {})],
      "two windows of one name"),
     ([Window("jit_run", {})], "no module of that name ran"),
 ])
@@ -326,8 +338,6 @@ def test_no_part_seconds_where_there_is_no_one_map(windows, why):
 
 
 def test_the_part_reader_on_a_toy_window(monkeypatch):
-    from go_libp2p_pubsub_tpu.perf import stages as program
-
     before = set(map(id, program.traced_windows()))
     cell = mf.find_cell(MANIFEST, CELL)
     out = bench_run.measure(
