@@ -192,8 +192,8 @@ def _jit_window(run, donate: bool):
     same trace counts the rows its edge gathers address by index
     (``ops/edges.tally_index_rows``), per step call, the rows and the
     tile-rows of the table they read, the calls that crossed in column
-    slices, the rows its peer gathers address, and the step calls one
-    call of it runs."""
+    slices or by the explicit halo exchange, the rows its peer gathers
+    address, and the step calls one call of it runs."""
     def window(*args, **kwargs):
         rows: list = []
         with edges.tally_index_rows(rows):
@@ -205,6 +205,8 @@ def _jit_window(run, donate: bool):
             edge_table_tile_rows=edges.edge_table_rows(rows, "tile_rows"),
             edge_sliced_calls_per_dispatch=edges.edge_rows_per_dispatch(
                 rows, "sliced"),
+            halo_exchanges_per_dispatch=edges.edge_rows_per_dispatch(
+                rows, "halo"),
             peer_rows_per_dispatch=edges.edge_rows_per_dispatch(rows, "peer"),
             dispatches=sum(n for kind, n in rows if kind == "window"))
         return out

@@ -50,8 +50,9 @@ WORD = 32
 
 # ---------------------------------------------------------------------------
 # halo-gather tally: each cross-peer gather below is ONE "gather set" — on a
-# banded topology it lowers to len(offsets) rolled halo collective-permutes
-# under GSPMD (parallel/sharding.py), so counting gather calls at trace time
+# banded topology whose peer axis is split it is len(offsets) halo
+# collective-permutes (the explicit exchange, ``edge_permute_banded``),
+# so counting gather calls at trace time
 # IS measuring the per-phase permute budget the v5e-8 projection charges
 # (perf/projection.py). The counter is None outside `tally_halo_gathers`,
 # keeping the hot path untouched.
@@ -77,14 +78,17 @@ class TallyCacheHit(RuntimeError):
 
 
 def _tally(kind: str, moved=None, rows: int = 0, table_rows: int = 0,
-           tile_rows: int = 0, sliced: bool = False) -> None:
+           tile_rows: int = 0, sliced: bool = False,
+           halo: bool = False) -> None:
     """One cross-peer gather SET: ``moved`` is the tensor it moves,
     ``rows`` the rows it addresses by index (a gather's output rows plus
     a scatter's rows; 0 for rolls, which address none), ``table_rows``
     the rows of the table its largest gather reads and ``tile_rows`` the
     same in the unit the chip charges (``tile_rows``; entries of their
     own, ``("table", n)`` and ``("tile_rows", n)``, and none for rolls),
-    ``sliced`` whether ``word_slices`` split the call (``("sliced", 1)``)."""
+    ``sliced`` whether ``word_slices`` split the call (``("sliced", 1)``),
+    ``halo`` whether it crossed by the explicit exchange of a split peer
+    ring (``("halo", 1)``)."""
     if _TALLY is not None:
         _TALLY.append(kind)
     if _ROWS_TALLY is not None:
@@ -94,6 +98,8 @@ def _tally(kind: str, moved=None, rows: int = 0, table_rows: int = 0,
             _ROWS_TALLY.append(("tile_rows", int(tile_rows)))
         if sliced:
             _ROWS_TALLY.append(("sliced", 1))
+        if halo:
+            _ROWS_TALLY.append(("halo", 1))
     if _BYTES_TALLY is not None:
         nbytes = None
         if moved is not None and hasattr(moved, "size"):
@@ -594,51 +600,76 @@ def detect_banded(
     return tuple(int(o) for o in off[0]), tuple(int(r) for r in rev[0])
 
 
+#: The mesh axis ``parallel.make_mesh`` splits the peer axis over.
+PEER_AXIS = "peers"
+
+
+def _peer_ring(x: jax.Array, off: tuple[int, ...]):
+    """The abstract mesh to exchange ``x``'s halo over explicitly, else
+    ``None``: where ``x``'s aval lies on a 1-D ``peers`` mesh of more than
+    one device that splits its N rows evenly into shards at least as tall
+    as the widest ring offset. Read off the aval, so a lowering from
+    ``ShapeDtypeStruct``s takes the branch its call took."""
+    mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
+    if (mesh is None or tuple(mesh.axis_names) != (PEER_AXIS,)
+            or mesh.size < 2):
+        return None
+    n = x.shape[0]
+    if n % mesh.size or n // mesh.size < max(min(o % n, -o % n)
+                                             for o in off):
+        return None
+    return mesh
+
+
+def _ring_shifted(v: jax.Array, o: int, n: int, s: int) -> jax.Array:
+    """Inside a ``shard_map`` over the ``s`` shards of the peer ring:
+    the local rows of ``roll(v, -o)``, out[j] = v[(j+o) % n]. The shard's
+    own rows shifted by ``o``, joined with the ``|o|`` boundary rows the
+    neighbouring shard sends in ONE ``ppermute`` (shard i -> i-1 for
+    ``o > 0``, i -> i+1 for ``o < 0``)."""
+    o = o % n
+    if o > n // 2:
+        o -= n
+    step = -1 if o > 0 else 1
+    pairs = [(i, (i + step) % s) for i in range(s)]
+    if o > 0:
+        return jnp.concatenate(
+            [v[o:], jax.lax.ppermute(v[:o], PEER_AXIS, pairs)])
+    return jnp.concatenate(
+        [jax.lax.ppermute(v[o:], PEER_AXIS, pairs), v[:o]])
+
+
+def _banded(x: jax.Array, off: tuple[int, ...], cols) -> tuple:
+    """``(out, exchanged)``: the ``[N, K, ...]`` plane whose column k is
+    ``cols(x)[k]`` read ``off[k]`` rows on, out[j, k] = cols(x)[k][(j +
+    off[k]) % N]. One roll a ring offset; where the peer axis is split over
+    a ``peers`` ring (``_peer_ring``), a ``shard_map`` over it in which
+    each offset's boundary rows cross in one permute (``exchanged``)."""
+    mesh = _peer_ring(x, off)
+    if mesh is None:
+        return jnp.stack([jnp.roll(c, -o, axis=0)
+                          for c, o in zip(cols(x), off)], axis=1), False
+    n, s = x.shape[0], mesh.size
+    spec = jax.sharding.PartitionSpec(PEER_AXIS)
+    body = lambda xl: jnp.stack([_ring_shifted(c, o, n, s)
+                                 for c, o in zip(cols(xl), off)], axis=1)
+    return jax.shard_map(body, mesh=mesh, in_specs=spec,
+                         out_specs=spec)(x), True
+
+
 def edge_permute_banded(
     x: jax.Array, off: tuple[int, ...], rev: tuple[int, ...]
 ) -> jax.Array:
     """Banded-regular edge_permute: out[j,k] = x[(j+off[k]) % N, rev[k]]."""
-    _tally("edge", x)
-    cols = [jnp.roll(x[:, r], -o, axis=0) for o, r in zip(off, rev)]
-    return jnp.stack(cols, axis=1)
-
-
-def edge_permute_banded_flat(
-    x: jax.Array, off: tuple[int, ...], rev: tuple[int, ...]
-) -> jax.Array:
-    """edge_permute_banded for [N,K,C] payloads via 8-aligned flat pieces.
-
-    The stack-of-[N,1,C] formulation gives every rolled piece a degenerate
-    T(1,128) sublane tile on the TPU's preferred N-minor layout; padding C
-    to a multiple of 8 and concatenating [N,Cp] pieces keeps every piece an
-    aligned sublane group of the N-minor [N,K*Cp] result.
-
-    Status: NOT the default. Measured end-to-end on the bench this wins
-    ~5x on the gather itself (2.1ms -> 0.4ms of device time) but loses
-    globally (322 -> 293 ticks/s): the flat result's layout propagates
-    into every downstream consumer of the [N,K,W] word planes, degrading
-    their tiles (T(2,128) on the W=2 slices). Kept for a future pass that
-    migrates the consumers to flat [N,K*W] planes wholesale."""
-    n, k, c = x.shape
-    pad = -c % 8
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros((n, k, pad), x.dtype)], axis=-1
-        )
-    cp = c + pad
-    flat = x.reshape(n, k * cp)
-    pieces = [
-        jnp.roll(flat[:, r * cp : (r + 1) * cp], -o, axis=0)
-        for o, r in zip(off, rev)
-    ]
-    out = jnp.concatenate(pieces, axis=1).reshape(n, k, cp)
-    return out[..., :c] if pad else out
+    out, exchanged = _banded(x, off, lambda a: [a[:, r] for r in rev])
+    _tally("edge", x, halo=exchanged)
+    return out
 
 
 def peer_gather_banded(v: jax.Array, off: tuple[int, ...]) -> jax.Array:
     """Banded-regular v[nbr]: out[j,k] = v[(j+off[k]) % N]."""
-    out = jnp.stack([jnp.roll(v, -o, axis=0) for o in off], axis=1)
-    _tally("peer", out)
+    out, exchanged = _banded(v, off, lambda a: [a] * len(off))
+    _tally("peer", out, halo=exchanged)
     return out
 
 

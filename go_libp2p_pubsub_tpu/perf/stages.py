@@ -84,8 +84,9 @@ plane of a running trace: spelled here with the other names, called from
 there alone.
 
 Collectives. A window traced over a mesh of more than one device is
-``sharded``: GSPMD puts the cross-peer traffic into collectives the
-compiled text names. A tracer carries the mesh's size but neither its
+``sharded``: the cross-peer traffic crosses in collectives the compiled
+text names (GSPMD's, and the banded gathers' explicit halo exchange,
+``ops/edges._banded``). A tracer carries the mesh's size but neither its
 devices nor the specs, so the registry takes the concrete shardings of
 the state argument from the call being traced (``CALLED_STATE``, set by
 ``driver``'s window; its output shardings are its input's, so they hold
@@ -269,6 +270,10 @@ class TracedWindow:
     #: edge gathers of ONE step call that crossed in column slices
     #: (``ops/edges.word_slices``); 0 for rolls, ``None`` for replays
     edge_sliced_calls_per_dispatch: float | None = None
+    #: gather sets (edge and peer) of ONE step call that crossed a split
+    #: peer ring by the explicit halo exchange, one permute a ring offset
+    #: (``ops/edges._peer_ring``); 0 on one device, ``None`` for replays
+    halo_exchanges_per_dispatch: float | None = None
     #: rows the PEER gathers of ONE step call address by index
     #: (``Net.peer_gather``: all N*K slots of ``v[nbr]`` a call; 0 for
     #: rolls, ``None`` for replays): 0 where every neighbour view of the

@@ -96,8 +96,8 @@ class Net:
     # pack/unpack are pure RESHAPES (GSPMD splits the sharded edge axis
     # without collectives); csr_band_* — the banded-regular roll structure
     # detected on the underlying topology, so the FLAT cross-peer gathers
-    # lower to the same static rolls (= halo collective-permutes under
-    # GSPMD) the dense involution compiles to
+    # lower to the same banded form (= halo collective-permutes on a split
+    # peer axis, ops/edges.edge_permute_banded) the dense involution takes
     csr_identity: bool = struct.field(pytree_node=False, default=False)
     csr_band_off: tuple = struct.field(pytree_node=False, default=None)
     csr_band_rev: tuple = struct.field(pytree_node=False, default=None)
@@ -186,7 +186,7 @@ class Net:
         """The involution on a flat edge plane: out[e] = x_e[eperm[e]]
         — E-sized cross-peer movement. On a banded-regular full-density
         build the gather lowers as the dense banded ROLLS (the same
-        halo collective-permute structure under GSPMD)."""
+        halo collective-permute structure on a split peer axis)."""
         if self.csr_band_off is not None:
             n, k = self.csr_e_of_nk.shape
             out = edges.edge_permute_banded(

@@ -1,9 +1,10 @@
 """``benchmark/tools/traced.py`` (same arguments), then what the program
 counted while it traced the window: the rows its edge gathers address a
 step call, the rows and tile-rows of the table they read, the calls a
-step that crossed in column slices and the rows its peer gathers address
-(``perf.stages``'s ``edge_rows_per_dispatch`` / ``edge_table_rows`` /
-``edge_table_tile_rows`` / ``edge_sliced_calls_per_dispatch`` /
+step that crossed in column slices or by the explicit halo exchange and the
+rows its peer gathers address (``perf.stages``'s ``edge_rows_per_dispatch``
+/ ``edge_table_rows`` / ``edge_table_tile_rows`` /
+``edge_sliced_calls_per_dispatch`` / ``halo_exchanges_per_dispatch`` /
 ``peer_rows_per_dispatch``; ``null`` on a commit without the counter), and
 the edge gathers of the window as this machine's compiler built it, with
 the memory space of each one's table, indices and output
@@ -51,6 +52,7 @@ def main(argv=None) -> int:
             for name in ("edge_rows_per_dispatch", "edge_table_rows",
                          "edge_table_tile_rows",
                          "edge_sliced_calls_per_dispatch",
+                         "halo_exchanges_per_dispatch",
                          "peer_rows_per_dispatch")},
             "edge_tables_hbm_per_dispatch":
                 window_whiles.edge_tables_hbm(gathers, n_peers),

@@ -28,7 +28,10 @@ devices, its state placed as ``parallel.shard_state`` places it: the line
 adds the collective profile of the partitioned window (``collectives``)
 and what one step call runs and sends (``collectives_per_dispatch``,
 ``collective_bytes_per_dispatch``; 0 on one chip), so a PR sees its
-permutes before any chip run.
+permutes before any chip run; ``halo_exchanges_per_dispatch`` (this line
+and ``--lower-only``'s) is the gather sets of a step call that crossed the
+split peer ring by the explicit exchange (``ops/edges._peer_ring``; 0 on
+one chip).
 ``edge_tables_hbm_per_dispatch`` counts those of at least Np rows (N
 rounded up to 128: the head gathers, one a sub-round and one a slice of the
 control head) whose table is NOT in ``S(1)``: 0 where every big gather of
@@ -198,9 +201,12 @@ def main(argv=None) -> int:
         args.workload, SingleDeviceSharding(topo.devices[0]), args.n_peers,
         mesh)
     sha = hashlib.sha256(lowered.as_text().encode()).hexdigest()
+    # the window the lowering just traced, with what its trace counted
+    halo = stages.traced_windows()[-1].halo_exchanges_per_dispatch
     if args.lower_only:
         print(json.dumps({"workload": cell["name"], "n_peers": built.n_peers,
                           "lowered_sha256": sha,
+                          "halo_exchanges_per_dispatch": halo,
                           "lower_s": time.perf_counter() - t0}))
         return 0
     text = lowered.compile().as_text()
@@ -224,6 +230,7 @@ def main(argv=None) -> int:
                                                         built.n_peers),
         "edge_gathers": gathers,
         "collectives": sharding.collective_profile(text),
+        "halo_exchanges_per_dispatch": halo,
         **dict(zip(("collectives_per_dispatch",
                     "collective_bytes_per_dispatch"),
                    stages.collectives_per_dispatch(
