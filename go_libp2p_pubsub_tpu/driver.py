@@ -193,7 +193,8 @@ def _jit_window(run, donate: bool):
     (``ops/edges.tally_index_rows``), per step call, the rows and the
     tile-rows of the table they read, the calls that crossed in column
     slices or by the explicit halo exchange, the rows its peer gathers
-    address, and the step calls one call of it runs."""
+    address, the rows a shard received in the per-shard plan's exchange,
+    and the step calls one call of it runs."""
     def window(*args, **kwargs):
         rows: list = []
         with edges.tally_index_rows(rows):
@@ -208,6 +209,8 @@ def _jit_window(run, donate: bool):
             halo_exchanges_per_dispatch=edges.edge_rows_per_dispatch(
                 rows, "halo"),
             peer_rows_per_dispatch=edges.edge_rows_per_dispatch(rows, "peer"),
+            remote_rows_per_dispatch=edges.edge_rows_per_dispatch(
+                rows, "remote"),
             dispatches=sum(n for kind, n in rows if kind == "window"))
         return out
     window.__name__ = window.__qualname__ = stages.window_name()

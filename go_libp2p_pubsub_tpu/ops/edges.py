@@ -23,10 +23,15 @@ copy loop. Every slot comes out bit for bit as `flat[perm]` gives it.
 K0 = K is the one full gather. A plane wider than one sublane tile of
 words crosses in tile-wide column slices, one gather a slice, where only
 the slices' tables are small enough to be read cheaply (`word_slices`).
+Where the peer axis is split over a 1-D ``peers`` mesh the same gather is
+planned per shard (`plan_shards`): each shard reads its own table with the
+rows the other shards' slots hold appended, and those cross in one
+exchange a gather set (`edge_permute_sharded`).
 `_tally` counts the rows every gather set addresses, the rows and tile-rows
-of the table it reads and whether it was sliced, for the window's
-`edge_rows_per_dispatch`, `edge_table_rows`, `edge_table_tile_rows`,
-`edge_sliced_calls_per_dispatch` and `peer_rows_per_dispatch`.
+of the table it reads, whether it was sliced and the rows a shard received,
+for the window's `edge_rows_per_dispatch`, `edge_table_rows`,
+`edge_table_tile_rows`, `edge_sliced_calls_per_dispatch`,
+`peer_rows_per_dispatch` and `remote_rows_per_dispatch`.
 
 Topic-slot payloads ([N,S,K] per-slot bools) are moved across edges by
 packing the S axis into *topic-id bit positions* of uint32 words (T bits
@@ -45,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
+
+from ..perf import stages
 
 WORD = 32
 
@@ -79,7 +86,7 @@ class TallyCacheHit(RuntimeError):
 
 def _tally(kind: str, moved=None, rows: int = 0, table_rows: int = 0,
            tile_rows: int = 0, sliced: bool = False,
-           halo: bool = False) -> None:
+           halo: bool = False, remote: int = 0) -> None:
     """One cross-peer gather SET: ``moved`` is the tensor it moves,
     ``rows`` the rows it addresses by index (a gather's output rows plus
     a scatter's rows; 0 for rolls, which address none), ``table_rows``
@@ -88,7 +95,8 @@ def _tally(kind: str, moved=None, rows: int = 0, table_rows: int = 0,
     own, ``("table", n)`` and ``("tile_rows", n)``, and none for rolls),
     ``sliced`` whether ``word_slices`` split the call (``("sliced", 1)``),
     ``halo`` whether it crossed by the explicit exchange of a split peer
-    ring (``("halo", 1)``)."""
+    axis (``("halo", 1)``), ``remote`` the rows one shard of it received
+    from the others (``("remote", n)``: the per-shard plan's exchange)."""
     if _TALLY is not None:
         _TALLY.append(kind)
     if _ROWS_TALLY is not None:
@@ -100,6 +108,8 @@ def _tally(kind: str, moved=None, rows: int = 0, table_rows: int = 0,
             _ROWS_TALLY.append(("sliced", 1))
         if halo:
             _ROWS_TALLY.append(("halo", 1))
+        if remote:
+            _ROWS_TALLY.append(("remote", int(remote)))
     if _BYTES_TALLY is not None:
         nbytes = None
         if moved is not None and hasattr(moved, "size"):
@@ -493,6 +503,43 @@ def pick_k0(col_fill, n: int) -> int:
     return k if cost[k] <= cost[best] else best
 
 
+def _tier_planes(perm: np.ndarray, moved: np.ndarray, k0: int,
+                 compact: bool, n_extra: int = 0) -> tuple:
+    """``(head, tail_src, tail_dst)`` of a tiered gather, as numpy: ``perm``
+    ``[n, k]`` addresses each slot's source in the slot space ``[0, n*k)``
+    or, at ``n*k + j``, the j-th of ``n_extra`` rows appended to the table;
+    ``moved`` ``[n, k - k0]`` marks the tail slots that move (every present
+    one, and any absent one a plan adds, whose source is itself)."""
+    n, k = perm.shape
+    n_pad = lane_padded(n)
+    cols, rows = np.nonzero(moved.T)            # K-major: ascending
+    # every slot's row in the table, by its full-space index n*K + k
+    addr = (np.arange(k, dtype=np.int32)[None, :] * n_pad
+            + np.arange(n, dtype=np.int32)[:, None])
+    if compact:
+        # an absent tail slot has no row (-1) and nobody asks: a present
+        # slot's partner is present, an absent head slot points at itself
+        addr[:, k0:] = -1
+        addr[rows, cols + k0] = n_pad * k0 + np.arange(rows.size,
+                                                       dtype=np.int32)
+    addr = addr.reshape(-1)
+    if n_extra:
+        table = n_pad * k0 + rows.size if compact else n_pad * k
+        addr = np.concatenate(
+            [addr, table + np.arange(n_extra, dtype=np.int32)])
+    src = addr[perm]
+    # the pad slots point at row 0: a run of self-pointing rows is the
+    # dearest index pattern the law found
+    head = np.zeros((k0, n_pad), np.int32)
+    head[:, :n] = src[:, :k0].T
+    return head, src[rows, cols + k0], cols * n_pad + rows
+
+
+#: a host plane as the program's i32 constant: cast on the host, since a
+#: device-side convert is one more program to compile
+_i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
+
+
 def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
                compact: bool | None = None,
                cliff: int = TABLE_CLIFF_ROWS) -> Tiers | None:
@@ -507,28 +554,93 @@ def plan_tiers(perm: np.ndarray, nbr_ok: np.ndarray, k0: int | None = None,
     if k0 >= k:
         return None
     n_pad = lane_padded(n)
-    cols, rows = np.nonzero(nbr_ok[:, k0:].T)   # K-major: ascending
     if compact is None:
-        compact = compact_pays(n_pad * k, n_pad * k0 + rows.size, cliff)
-    # every slot's row in the table, by its full-space index n*K + k
-    addr = (np.arange(k, dtype=np.int32)[None, :] * n_pad
-            + np.arange(n, dtype=np.int32)[:, None])
-    if compact:
-        # an absent tail slot has no row (-1) and nobody asks: a present
-        # slot's partner is present, an absent head slot points at itself
-        addr[:, k0:] = -1
-        addr[rows, cols + k0] = n_pad * k0 + np.arange(rows.size,
-                                                       dtype=np.int32)
-    src = addr.reshape(-1)[perm]
-    # the pad slots point at row 0: a run of self-pointing rows is the
-    # dearest index pattern the law found
-    head = np.zeros((k0, n_pad), np.int32)
-    head[:, :n] = src[:, :k0].T
-    # cast on the host: a device-side convert is one more program to compile
-    i32 = lambda a: jnp.asarray(np.asarray(a, np.int32))
-    return Tiers(head=i32(head), tail_src=i32(src[rows, cols + k0]),
-                 tail_dst=i32(cols * n_pad + rows), compact=bool(compact),
+        compact = compact_pays(n_pad * k,
+                               n_pad * k0 + int(nbr_ok[:, k0:].sum()), cliff)
+    head, tail_src, tail_dst = _tier_planes(perm, nbr_ok[:, k0:], k0,
+                                            bool(compact))
+    return Tiers(head=_i32(head), tail_src=_i32(tail_src),
+                 tail_dst=_i32(tail_dst), compact=bool(compact),
                  cliff=int(cliff))
+
+
+def _cuts(tiers: Tiers, x: jax.Array, n_extra: int = 0) -> tuple:
+    """``(table rows, column slices)`` of the big gather of ``x`` through
+    ``tiers``, out of a table with ``n_extra`` rows appended."""
+    trail = x.shape[2:]
+    table_rows = tiers.table_rows(x.shape[1]) + n_extra
+    words = math.prod(trail)
+    # no plane of more than one trailing axis is a tile wide
+    return table_rows, (word_slices(table_rows, words, tiers.cliff)
+                        if len(trail) == 1 else [(0, words)])
+
+
+def _tally_tiered(x: jax.Array, tiers: Tiers, sent: int = 0) -> None:
+    """``_tally`` of one tiered gather set: every slice gathers (and
+    appends its rows); the scatter is one. ``sent``: the rows a shard of
+    the per-shard plan packed for the others and received from them,
+    appended to its table."""
+    table_rows, cuts = _cuts(tiers, x, sent)
+    _tally("edge", x, table_rows=table_rows, sliced=len(cuts) > 1,
+           rows=tiers.rows
+           + (len(cuts) - 1) * (tiers.rows - tiers.tail_dst.size) + sent,
+           tile_rows=tile_rows(table_rows, max(hi - lo for lo, hi in cuts)),
+           halo=sent > 0, remote=sent)
+
+
+def _k_major(x: jax.Array, n_pad: int) -> jax.Array:
+    """The ``[K, Np, ...]`` view of an ``[N, K, ...]`` plane: the planes
+    live N-minor, so XLA keeps it."""
+    k_major = (1, 0) + tuple(range(2, x.ndim))
+    return jnp.pad(x, ((0, n_pad - x.shape[0]),) + ((0, 0),) * (x.ndim - 1)
+                   ).transpose(k_major)
+
+
+def _tiered(x: jax.Array, tiers: Tiers,
+            extra: jax.Array | None = None) -> jax.Array:
+    """The tiered gather of ``x`` through ``tiers``; ``extra``: the rows
+    the per-shard plan's exchange received, which land after the big
+    gather's own table (under ``gsx.exchange``)."""
+    n, k = x.shape[:2]
+    k0, n_pad = tiers.head.shape
+    trail = x.shape[2:]
+    _, cuts = _cuts(tiers, x, 0 if extra is None else extra.shape[0])
+    whole = len(cuts) == 1
+    # a table a slice; and the slices' rows side by side again
+    cols = lambda a: [a] if whole else [a[..., lo:hi] for lo, hi in cuts]
+    join = lambda parts: (parts[0] if whole
+                          else jnp.concatenate(parts, axis=-1))
+
+    def landed(tables):
+        if extra is None:
+            return tables
+        with stages.part("exchange"):
+            return [jnp.concatenate([t, e])
+                    for t, e in zip(tables, cols(extra))]
+
+    xt = _k_major(x, n_pad)
+    tail = xt[k0:].reshape(((k - k0) * n_pad,) + trail)
+    onto_tail = lambda rows: tail.at[tiers.tail_dst].set(
+        rows, unique_indices=True, indices_are_sorted=True)
+    if tiers.compact:
+        tables = landed([jnp.concatenate([h, t[tiers.tail_dst]])
+                         for h, t in zip(cols(xt[:k0].reshape(
+                             (k0 * n_pad,) + trail)), cols(tail))])
+        # ONE gather a table: the head block, then the T rows the tail
+        # slots take
+        index = jnp.concatenate([tiers.head.reshape(-1), tiers.tail_src])
+        moved = join([table[index] for table in tables])
+        tail = onto_tail(moved[k0 * n_pad:])
+        head = moved[:k0 * n_pad]
+    else:
+        # the head's gather gives its block whole: no slice of a joined one
+        tables = landed(cols(xt.reshape((k * n_pad,) + trail)))
+        tail = onto_tail(join([table[tiers.tail_src] for table in tables]))
+        head = join([table[tiers.head.reshape(-1)] for table in tables])
+    out = jnp.concatenate(
+        [head.reshape((k0, n_pad) + trail),
+         tail.reshape((k - k0, n_pad) + trail)], axis=0)
+    return out.transpose((1, 0) + tuple(range(2, x.ndim)))[:n]
 
 
 def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
@@ -540,48 +652,8 @@ def edge_permute_tiered(x: jax.Array, tiers: Tiers) -> jax.Array:
     ``word_slices``, whether a ``[N, K, w]`` plane wider than a tile
     crosses in column slices, each out of a table of its own through the
     same index plane. The tail stays one scatter."""
-    n, k = x.shape[:2]
-    k0, n_pad = tiers.head.shape
-    trail = x.shape[2:]
-    table_rows, words = tiers.table_rows(k), math.prod(trail)
-    # no plane of more than one trailing axis is a tile wide
-    cuts = (word_slices(table_rows, words, tiers.cliff) if len(trail) == 1
-            else [(0, words)])
-    whole = len(cuts) == 1
-    # every slice gathers (and appends its rows); the scatter is one
-    _tally("edge", x, table_rows=table_rows, sliced=not whole,
-           rows=tiers.rows
-           + (len(cuts) - 1) * (tiers.rows - tiers.tail_dst.size),
-           tile_rows=tile_rows(table_rows, max(hi - lo for lo, hi in cuts)))
-    # a table a slice; and the slices' rows side by side again
-    cols = lambda a: [a] if whole else [a[..., lo:hi] for lo, hi in cuts]
-    join = lambda parts: (parts[0] if whole
-                          else jnp.concatenate(parts, axis=-1))
-    # the [K, Np, ...] view: the planes live N-minor, so XLA keeps it
-    k_major = (1, 0) + tuple(range(2, x.ndim))
-    xt = jnp.pad(x, ((0, n_pad - n),) + ((0, 0),) * (x.ndim - 1)
-                 ).transpose(k_major)
-    tail = xt[k0:].reshape(((k - k0) * n_pad,) + trail)
-    onto_tail = lambda rows: tail.at[tiers.tail_dst].set(
-        rows, unique_indices=True, indices_are_sorted=True)
-    if tiers.compact:
-        tables = [jnp.concatenate([h, t[tiers.tail_dst]]) for h, t in zip(
-            cols(xt[:k0].reshape((k0 * n_pad,) + trail)), cols(tail))]
-        # ONE gather a table: the head block, then the T rows the tail
-        # slots take
-        index = jnp.concatenate([tiers.head.reshape(-1), tiers.tail_src])
-        moved = join([table[index] for table in tables])
-        tail = onto_tail(moved[k0 * n_pad:])
-        head = moved[:k0 * n_pad]
-    else:
-        # the head's gather gives its block whole: no slice of a joined one
-        tables = cols(xt.reshape((k * n_pad,) + trail))
-        tail = onto_tail(join([table[tiers.tail_src] for table in tables]))
-        head = join([table[tiers.head.reshape(-1)] for table in tables])
-    out = jnp.concatenate(
-        [head.reshape((k0, n_pad) + trail),
-         tail.reshape((k - k0, n_pad) + trail)], axis=0)
-    return out.transpose(k_major)[:n]
+    _tally_tiered(x, tiers)
+    return _tiered(x, tiers)
 
 
 def detect_banded(
@@ -604,19 +676,26 @@ def detect_banded(
 PEER_AXIS = "peers"
 
 
-def _peer_ring(x: jax.Array, off: tuple[int, ...]):
-    """The abstract mesh to exchange ``x``'s halo over explicitly, else
-    ``None``: where ``x``'s aval lies on a 1-D ``peers`` mesh of more than
-    one device that splits its N rows evenly into shards at least as tall
-    as the widest ring offset. Read off the aval, so a lowering from
+def peer_mesh(x: jax.Array):
+    """The abstract mesh ``x``'s aval is split over, where it is a 1-D
+    ``peers`` mesh of more than one device that splits its N rows evenly,
+    else ``None``. Read off the aval, so a lowering from
     ``ShapeDtypeStruct``s takes the branch its call took."""
     mesh = getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
     if (mesh is None or tuple(mesh.axis_names) != (PEER_AXIS,)
-            or mesh.size < 2):
+            or mesh.size < 2 or x.shape[0] % mesh.size):
         return None
+    return mesh
+
+
+def _peer_ring(x: jax.Array, off: tuple[int, ...]):
+    """The abstract mesh to exchange ``x``'s halo over explicitly, else
+    ``None``: where ``x`` is split over a ``peer_mesh`` into shards at
+    least as tall as the widest ring offset."""
+    mesh = peer_mesh(x)
     n = x.shape[0]
-    if n % mesh.size or n // mesh.size < max(min(o % n, -o % n)
-                                             for o in off):
+    if mesh is None or n // mesh.size < max(min(o % n, -o % n)
+                                            for o in off):
         return None
     return mesh
 
@@ -671,6 +750,167 @@ def peer_gather_banded(v: jax.Array, off: tuple[int, ...]) -> jax.Array:
     out, exchanged = _banded(v, off, lambda a: [a] * len(off))
     _tally("peer", out, halo=exchanged)
     return out
+
+
+# ---------------------------------------------------------------------------
+# the tiered gather over a split peer axis. Where the ``[N, K]`` planes are
+# split into S contiguous blocks of peers, a gather through replicated
+# global indices leaves the partitioner one way out: every chip reads the
+# whole table. So the plan is made per shard (``plan_shards``): a shard's
+# slots whose partner lies on the same shard read its own K-major table as
+# ``plan_tiers`` plans it; the rows the other shards' slots read cross in
+# ONE exchange a gather set, S - 1 permutes by shard offset, each a list of
+# rows of the sender's table padded to the longest list; and the received
+# rows are appended to the shard's table, so the shard's tiered gather reads
+# them like any other row. On a graph without locality, such as upstream's
+# ``denseConnect``, (S - 1) / S of the present slots read a remote row.
+
+
+@struct.dataclass
+class ShardTiers:
+    """The plan of a tiered edge gather over ``shards`` contiguous blocks
+    of the peer axis (``plan_shards``). Every leaf holds the shards' planes
+    end to end, so a ``shard_map`` over ``peers`` hands each shard its
+    own: ``head``, ``tail_src`` and ``tail_dst`` are a ``Tiers`` a shard
+    (one K0, the tail padded to the longest with absent slots that read
+    themselves), addressing the shard's table with the received rows
+    appended; ``send`` the rows of the shard's ``[K*Np]`` table it sends
+    to the shard 1, 2, ..., S-1 after it, ``pad`` rows each, which land in
+    that order after the receiver's table."""
+
+    head: jax.Array       # [S*K0, Np] i32
+    tail_src: jax.Array   # [S*T] i32
+    tail_dst: jax.Array   # [S*T] i32
+    send: jax.Array       # [S*(S-1)*pad] i32
+    shards: int = struct.field(pytree_node=False, default=2)
+    compact: bool = struct.field(pytree_node=False, default=False)
+    cliff: int = struct.field(pytree_node=False, default=TABLE_CLIFF_ROWS)
+
+    @property
+    def pad(self) -> int:
+        """Rows a shard sends each other shard."""
+        return self.send.size // (self.shards * (self.shards - 1))
+
+    def shard(self, head, tail_src, tail_dst) -> Tiers:
+        """One shard's ``Tiers`` of its blocks of the planes."""
+        return Tiers(head=head, tail_src=tail_src, tail_dst=tail_dst,
+                     compact=self.compact, cliff=self.cliff)
+
+    def local(self) -> Tiers:
+        """A shard's ``Tiers`` in shapes alone, for the tally."""
+        block = lambda a: jax.ShapeDtypeStruct(
+            (a.shape[0] // self.shards,) + a.shape[1:], a.dtype)
+        return self.shard(block(self.head), block(self.tail_src),
+                          block(self.tail_dst))
+
+
+def plan_shards(perm: np.ndarray, nbr_ok: np.ndarray, shards: int,
+                k0: int | None = None, compact: bool | None = None,
+                cliff: int = TABLE_CLIFF_ROWS) -> Tiers | ShardTiers | None:
+    """Plan the tiered gather of one static graph over ``shards``
+    contiguous blocks of its peers, on the host. One shard is
+    ``plan_tiers``' plan. Else K0 is ``pick_k0``'s over the fullest
+    shard's column histogram and the table ``compact_pays``' choice for
+    the shard's table with its received rows, one for every shard, since
+    the shards run one program; ``k0``, ``compact`` and ``cliff`` as in
+    ``plan_tiers``. K0 = K is a full gather out of that table."""
+    if shards == 1:
+        return plan_tiers(perm, nbr_ok, k0, compact, cliff)
+    n, k = perm.shape
+    if n % shards:
+        raise ValueError(f"{n} peers do not split into {shards} shards")
+    ns = n // shards
+    n_pad = lane_padded(ns)
+    blocks = [slice(i * ns, (i + 1) * ns) for i in range(shards)]
+    home = np.arange(n)[:, None] // ns          # a slot's shard
+    there = perm // k // ns                     # its partner's
+    if k0 is None:
+        k0 = pick_k0(np.max([nbr_ok[b].sum(axis=0) for b in blocks], axis=0),
+                     ns)
+    # the send lists: a present slot whose partner lies on another shard,
+    # by the row of its shard's table, grouped by (sender, receiver)
+    sent_n, sent_k = np.nonzero(nbr_ok & (there != home))
+    src, dst = home[sent_n, 0], there[sent_n, sent_k]
+    row = sent_k * n_pad + sent_n % ns
+    order = np.lexsort((row, dst, src))
+    group = (src * shards + dst)[order]
+    counts = np.bincount(group, minlength=shards * shards)
+    pos = np.empty(order.size, np.int64)
+    pos[order] = np.arange(order.size) - (np.cumsum(counts) - counts)[group]
+    pad = max(1, int(counts.max(initial=0)))
+    offset = (dst - src) % shards               # 1 .. S-1
+    send = np.zeros((shards, shards - 1, pad), np.int32)
+    send[src, offset - 1, pos] = row
+    # where a receiver finds the sent slot: after its own table, in the
+    # block of the sender's offset
+    got_at = np.zeros(n * k, np.int64)
+    got_at[sent_n * k + sent_k] = (offset - 1) * pad + pos
+    n_extra = (shards - 1) * pad
+    tails = [int(nbr_ok[b, k0:].sum()) for b in blocks]
+    t_pad = max(tails)
+    if compact is None:
+        compact = compact_pays(n_pad * k + n_extra,
+                               n_pad * k0 + t_pad + n_extra, cliff)
+    planes = []
+    for i, b in enumerate(blocks):
+        g = perm[b]
+        local = ~nbr_ok[b] | (there[b] == i)
+        ext = np.where(local, g - b.start * k, ns * k + got_at[g])
+        # the tail padded to the longest with absent slots, K-major first
+        moved = nbr_ok[b, k0:].copy()
+        free_k, free_n = np.nonzero(~moved.T)
+        need = t_pad - tails[i]
+        moved[free_n[:need], free_k[:need]] = True
+        planes.append(_tier_planes(ext, moved, k0, bool(compact), n_extra))
+    head, tail_src, tail_dst = (np.concatenate(p) for p in zip(*planes))
+    return ShardTiers(head=_i32(head), tail_src=_i32(tail_src),
+                      tail_dst=_i32(tail_dst), send=_i32(send.reshape(-1)),
+                      shards=shards, compact=bool(compact), cliff=int(cliff))
+
+
+class ShardPlanner:
+    """A static graph's edge involution on the host (``Net.build``'s), and
+    its ``plan_shards`` plan for each shard count a gather's argument came
+    split into, made the first time (at trace time) and kept. Compared by
+    identity: a net's static aux data."""
+
+    def __init__(self, perm: np.ndarray, nbr_ok: np.ndarray):
+        self.perm, self.nbr_ok = perm, nbr_ok
+        self._plans: dict = {}
+
+    def __call__(self, shards: int) -> Tiers | ShardTiers | None:
+        if shards not in self._plans:
+            self._plans[shards] = plan_shards(self.perm, self.nbr_ok, shards)
+        return self._plans[shards]
+
+
+def edge_permute_sharded(x: jax.Array, plan: ShardTiers, mesh) -> jax.Array:
+    """``edge_permute(x, edge_perm)`` bit for bit on every slot, where
+    ``x``'s peer axis is split over ``mesh`` (``peer_mesh``) into
+    ``plan.shards`` blocks: a ``shard_map`` over ``peers`` in which each
+    shard packs the rows the others read (one gather), sends them in ONE
+    exchange (S - 1 permutes by shard offset), lands them after its own
+    table and gathers out of that (``_tiered``), under ``gsx.exchange``
+    from the pack to the landing."""
+    s, pad = plan.shards, plan.pad
+    _tally_tiered(x, plan.local(), (s - 1) * pad)
+    spec = jax.sharding.PartitionSpec(PEER_AXIS)
+
+    def body(xl, head, tail_src, tail_dst, send):
+        tiers = plan.shard(head, tail_src, tail_dst)
+        k, trail = xl.shape[1], xl.shape[2:]
+        with stages.part("exchange"):
+            table = _k_major(xl, head.shape[1]).reshape(
+                (k * head.shape[1],) + trail)
+            rows = table[send].reshape((s - 1, pad) + trail)
+            extra = jnp.concatenate([jax.lax.ppermute(
+                rows[d - 1], PEER_AXIS, [(i, (i + d) % s) for i in range(s)])
+                for d in range(1, s)])
+        return _tiered(xl, tiers, extra)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 5,
+                         out_specs=spec)(x, plan.head, plan.tail_src,
+                                         plan.tail_dst, plan.send)
 
 
 def topic_pack(x: jax.Array, my_topics: jax.Array, n_topics: int) -> jax.Array:

@@ -4,16 +4,23 @@ TPU-native distributed backend).
 The framework's parallelism is data-parallel-over-peers: every state array
 whose leading dimension is N is sharded along a 1-D 'peers' mesh axis;
 small global structures (the message table, event counters, RNG key) are
-replicated. Cross-peer traffic — the neighbor gathers x[nbr] in the
-delivery engine and control-plane handlers — lowers to XLA collectives
-over ICI (single host) / DCN (multi host) under GSPMD; the topology
-builders can be composed with a peer-id relabeling so that most mesh
-edges stay shard-local, keeping those collectives small.
+replicated.
+
+Cross-peer traffic, the edge gathers of the delivery engine and of the
+control-plane handlers, crosses a 1-D 'peers' mesh in the program's own
+exchanges: on a banded ring one ``ppermute`` a ring offset
+(``ops/edges._banded``), on every other static dense graph one exchange a
+gather set of the rows each shard's slots read from the others, S - 1
+permutes by shard offset (``ops/edges.edge_permute_sharded``, the
+per-shard plan). GSPMD chooses the collectives for the rest: the
+neighbour views ``v[nbr]``, the reductions into replicated structures,
+and every layout the explicit exchanges do not take (a 2-D or multi-host
+mesh, a traced dynamic graph, the flat CSR layout).
 
 This replaces the reference's libp2p stream layer + per-peer goroutines
-(comm.go) — the "NCCL analogue" named in the survey — with compiler-
-inserted collectives, per the scaling-book recipe: pick a mesh, annotate
-shardings, let XLA do the rest.
+(comm.go), the "NCCL analogue" named in the survey, with collectives over
+ICI (single host) / DCN (multi host): explicit ones where the program
+knows the graph, compiler-inserted ones elsewhere.
 """
 
 from __future__ import annotations

@@ -77,6 +77,11 @@ same compiled text.
                 ``live_step_views``' traced arm, and the publish gate on
                 ``up[origin]`` (``pub_holder``, which ``state.PhasePubPlan``
                 / ``allocate_publishes`` take). A static window carries none
+  exchange      the per-shard plan's crossing of a split peer axis
+                (``ops/edges.edge_permute_sharded``): the pack of the rows
+                the other shards read, the permutes, and their landing
+                after the shard's own table; under ``gs.edge_gather``. A
+                window on one device, or over a banded ring, carries none
 
 Host spans. ``host_scope(name)`` is the profiler annotation
 ``gs.host.<name>`` a host span of ``perf/spans.py`` lies under in the host
@@ -85,8 +90,9 @@ there alone.
 
 Collectives. A window traced over a mesh of more than one device is
 ``sharded``: the cross-peer traffic crosses in collectives the compiled
-text names (GSPMD's, and the banded gathers' explicit halo exchange,
-``ops/edges._banded``). A tracer carries the mesh's size but neither its
+text names (GSPMD's, the banded gathers' explicit halo exchange,
+``ops/edges._banded``, and the per-shard plan's,
+``ops/edges.edge_permute_sharded``). A tracer carries the mesh's size but neither its
 devices nor the specs, so the registry takes the concrete shardings of
 the state argument from the call being traced (``CALLED_STATE``, set by
 ``driver``'s window; its output shardings are its input's, so they hold
@@ -128,7 +134,9 @@ from typing import Any
 #: an executable cached before it simply lacks the name nobody reads.
 #: ``gsx.churn`` likewise: it is traced under ``dynamic_peers`` alone, and
 #: the PR that brought it put the publish gate into those programs, so no
-#: executable from before it has their key.)
+#: executable from before it has their key. ``gsx.exchange`` likewise: it
+#: is traced only where a planned net's gather is split over a 1-D
+#: ``peers`` mesh, a program that was a partitioner's gather before it.)
 VERSION = 1
 
 PREFIX = "gs."
@@ -136,7 +144,7 @@ STAGES = ("control_head", "pub_plan", "data_round", "edge_gather", "deliver",
           "score", "heartbeat", "phase_tail")
 UNSCOPED = "unscoped"
 PART_PREFIX = "gsx."
-PARTS = ("fanout", "attrib", "gater", "churn")
+PARTS = ("fanout", "attrib", "gater", "churn", "exchange")
 HOST_PREFIX = PREFIX + "host."
 
 _SCOPE_RE = re.compile(re.escape(PREFIX) + r"([a-z_]+)")
@@ -271,14 +279,21 @@ class TracedWindow:
     #: (``ops/edges.word_slices``); 0 for rolls, ``None`` for replays
     edge_sliced_calls_per_dispatch: float | None = None
     #: gather sets (edge and peer) of ONE step call that crossed a split
-    #: peer ring by the explicit halo exchange, one permute a ring offset
-    #: (``ops/edges._peer_ring``); 0 on one device, ``None`` for replays
+    #: peer axis by an explicit exchange: one permute a ring offset of a
+    #: banded ring (``ops/edges._peer_ring``), one a shard offset of the
+    #: per-shard plan (``ops/edges.edge_permute_sharded``); 0 on one
+    #: device, ``None`` for replays
     halo_exchanges_per_dispatch: float | None = None
     #: rows the PEER gathers of ONE step call address by index
     #: (``Net.peer_gather``: all N*K slots of ``v[nbr]`` a call; 0 for
     #: rolls, ``None`` for replays): 0 where every neighbour view of the
     #: step crosses as an edge gather
     peer_rows_per_dispatch: float | None = None
+    #: rows one shard of ONE step call's edge gathers received from the
+    #: other shards (``ops/edges.edge_permute_sharded``: the per-shard
+    #: plan's exchange, padded as it crosses); 0 on one device and over a
+    #: banded ring, ``None`` for replays
+    remote_rows_per_dispatch: float | None = None
     #: step calls ONE call of the window runs (``ops/edges.mark_window``)
     dispatches: int | None = None
     _stages: dict | None = None

@@ -43,8 +43,12 @@ class Net:
     tail's present rows and no other) where the degree histogram makes
     that the cheaper program, the one full gather where it does not (full
     or near-regular columns, toy nets) and on a ``dynamic`` net, whose
-    ``edge_perm`` is traced. Every form returns the same plane on every
-    slot, absent ones included."""
+    ``edge_perm`` is traced. Where the argument's peer axis is split over
+    a 1-D ``peers`` mesh, the same nets' gather is planned per shard
+    (``shard_plans``): each shard's tiered gather reads its own table with
+    the rows the other shards send it appended, all of them in one
+    exchange. Every form returns the same plane on every slot, absent ones
+    included."""
 
     nbr: jax.Array         # [N, K] i32
     nbr_ok: jax.Array      # [N, K] bool
@@ -114,6 +118,11 @@ class Net:
     # and the table its head reads both from the degree histogram; None is
     # K0 = K, the one full gather through ``edge_perm``
     tiers: edges.Tiers | None = None
+    # the same net's plan over a split peer axis (ops/edges.plan_shards):
+    # on the same nets as ``tiers``, made on the host for the shard count a
+    # gather's argument arrives split into, once (static aux data)
+    shard_plans: edges.ShardPlanner | None = struct.field(
+        pytree_node=False, default=None)
 
     @stages.scope("edge_gather")
     def edge_gather(self, x: jax.Array) -> jax.Array:
@@ -134,6 +143,10 @@ class Net:
             return jnp.where(present, got, x)
         if self.band_off is not None:
             return edges.edge_permute_banded(x, self.band_off, self.band_rev)
+        mesh = None if self.shard_plans is None else edges.peer_mesh(x)
+        if mesh is not None:
+            return edges.edge_permute_sharded(
+                x, self.shard_plans(mesh.size), mesh)
         if self.tiers is not None:
             return edges.edge_permute_tiered(x, self.tiers)
         return edges.edge_permute(x, self.edge_perm)
@@ -346,15 +359,19 @@ class Net:
             edge_perm = edges.build_edge_perm(topo.nbr, topo.rev, topo.nbr_ok)
             # the tiers are static like the band: a dense net that is
             # neither banded (rolls) nor dynamic (``edge_perm`` is traced
-            # there)
+            # there); its plan over a split peer axis waits for the shard
+            # count
+            planned = edge_layout == "dense" and band is None and not dynamic
             tiers = (edges.plan_tiers(edge_perm, topo.nbr_ok)
-                     if edge_layout == "dense" and band is None
-                     and not dynamic else None)
+                     if planned else None)
+            shard_plans = (edges.ShardPlanner(edge_perm, topo.nbr_ok)
+                           if planned else None)
         with spans.span("setup.net_build.planes"):
             return cls(
                 edge_layout=edge_layout,
                 fused=bool(fused),
                 tiers=tiers,
+                shard_plans=shard_plans,
                 **csr_kw,
                 band_off=band[0] if band else None,
                 band_rev=band[1] if band else None,
@@ -395,8 +412,9 @@ class Net:
                 "with_overlay: the tiered gather's plan is static — build "
                 "the net with Net.build(..., dynamic=True)"
             )
+        # the static net's plan over a split peer axis goes with its graph
         kw = dict(nbr=topo.nbr, nbr_ok=topo.nbr_ok, rev=topo.rev,
-                  edge_perm=topo.edge_perm)
+                  edge_perm=topo.edge_perm, shard_plans=None)
         if self.edge_layout == "csr":
             e = self.n_peers * self.max_degree
             if not self.csr_identity or self.n_edges != e:

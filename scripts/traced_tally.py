@@ -5,7 +5,8 @@ step that crossed in column slices or by the explicit halo exchange and the
 rows its peer gathers address (``perf.stages``'s ``edge_rows_per_dispatch``
 / ``edge_table_rows`` / ``edge_table_tile_rows`` /
 ``edge_sliced_calls_per_dispatch`` / ``halo_exchanges_per_dispatch`` /
-``peer_rows_per_dispatch``; ``null`` on a commit without the counter), and
+``peer_rows_per_dispatch`` / ``remote_rows_per_dispatch``; ``null`` on a
+commit without the counter), and
 the edge gathers of the window as this machine's compiler built it, with
 the memory space of each one's table, indices and output
 (``window_whiles.edge_gathers``), and before them how many of at least Np
@@ -53,7 +54,8 @@ def main(argv=None) -> int:
                          "edge_table_tile_rows",
                          "edge_sliced_calls_per_dispatch",
                          "halo_exchanges_per_dispatch",
-                         "peer_rows_per_dispatch")},
+                         "peer_rows_per_dispatch",
+                         "remote_rows_per_dispatch")},
             "edge_tables_hbm_per_dispatch":
                 window_whiles.edge_tables_hbm(gathers, n_peers),
             "edge_gathers": gathers,
