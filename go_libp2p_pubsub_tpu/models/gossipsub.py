@@ -37,6 +37,7 @@ CDF comparison):
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -1780,28 +1781,45 @@ def prepare_step_consts(
     )
 
 
+def liveness_code(st: GossipSubState, up_next: jax.Array):
+    """The sender side of a phase head's peer transitions, local arithmetic
+    alone: ``(eff_next, down_tr, code)``, ``eff_next`` who is up after the
+    head (a blacklisted peer stays down), ``down_tr`` who goes down, and
+    the ``[N]`` ``uint32`` code that carries both across the edges (bit 0
+    ``down_tr``, bit 1 the new ``up``)."""
+    eff_next = up_next & ~st.blacklist
+    down_tr = st.up & ~eff_next
+    code = down_tr.astype(jnp.uint32) | (eff_next.astype(jnp.uint32) << 1)
+    return eff_next, down_tr, code
+
+
 def apply_peer_transitions(cfg: GossipSubConfig, net: Net, st: GossipSubState,
-                           up_next: jax.Array, tp: dict):
+                           up_next: jax.Array, tp: dict,
+                           code_nbr: jax.Array | None = None):
     """Peer lifecycle transitions (dynamic_peers builds): disconnect
     down/blacklisted peers with full dead-peer cleanup (handleDeadPeers
     pubsub.go:648-689 + router RemovePeer gossipsub.go:545-562 + score
-    retention score.go:604-689). Returns (st, live-edge mask)."""
-    eff_next = up_next & ~st.blacklist
-    down_tr = st.up & ~eff_next
+    retention score.go:604-689). Returns (st, live-edge mask).
+
+    ``code_nbr`` is ``liveness_code``'s code already across the edges
+    (``[N, K]``, unmasked), where the caller's own wire exchange carried
+    it: the phase engine's coalesced control head does. Given it, the
+    function gathers nothing."""
+    eff_next, down_tr, code = liveness_code(st, up_next)
     up_tr = ~st.up & eff_next
-    # both neighbour views cross the edges ONCE, as a 2-bit code (bit 0
-    # ``down_tr``, bit 1 the new ``up``): a plane constant along K comes
-    # out of the edge involution as its neighbour view (x[n,k] = v[n] =>
-    # out[j,k] = v[nbr[j,k]], whatever ``rev`` is), so the crossing takes
-    # what the net's layout gives an edge gather (plan, rolls, flat). An
-    # absent slot reads its own junk where a peer gather reads v[0]: both
-    # views are under ``nbr_ok``. The plane is two words wide (the code
-    # twice): XLA drops a unit word axis and gathers element by element,
-    # which a v5e charges 6.6 ns a row where a row gather of two words
-    # costs 5.2 (PERF.md §6, PR 38)
-    code = down_tr.astype(jnp.uint32) | (eff_next.astype(jnp.uint32) << 1)
-    code_nbr = net.edge_gather(jnp.broadcast_to(
-        code[:, None, None], net.nbr.shape + (2,)))[..., 0]
+    if code_nbr is None:
+        # both neighbour views cross the edges ONCE, as the 2-bit code: a
+        # plane constant along K comes out of the edge involution as its
+        # neighbour view (x[n,k] = v[n] => out[j,k] = v[nbr[j,k]],
+        # whatever ``rev`` is), so the crossing takes what the net's
+        # layout gives an edge gather (plan, rolls, flat). An absent slot
+        # reads its own junk where a peer gather reads v[0]: both views
+        # are under ``nbr_ok``. The plane is two words wide (the code
+        # twice): XLA drops a unit word axis and gathers element by
+        # element, which a v5e charges 6.6 ns a row where a row gather of
+        # two words costs 5.2 (PERF.md §6)
+        code_nbr = net.edge_gather(jnp.broadcast_to(
+            code[:, None, None], net.nbr.shape + (2,)))[..., 0]
     down_nbr = ((code_nbr & 1) != 0) & net.nbr_ok
     # every edge touching a down peer dies (both directions; a
     # restarting node comes back with fresh soft state)
@@ -2094,37 +2112,41 @@ def control_exchange(cfg: GossipSubConfig, net: Net, net_l: Net,
             nbr_score_of_me)
 
 
-def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
-                               st: GossipSubState, include_app: bool = False):
-    """ONE stacked wire exchange for the whole phase control head (round-7
-    tentpole): every control outbox, the score plane, the IWANT-service
-    mcache window — and, when ``include_app``, the P5 app-score plane the
-    heartbeat tail consumes — cross the edge involution in a single
-    gather, so the sharded lowering emits ONE halo-permute set for the
-    entire control head instead of three-plus-one (16·(r+4) →
-    16·(r+1) permutes per phase; perf/projection.py charges 1–5 µs
-    launch latency per permute).
+class ControlWire(NamedTuple):
+    """The phase control head's stacked wire: the sender side's parts by
+    name, in plane order, and — once ``gather_wire`` ran — their stacked
+    plane across the edges, unmasked."""
+    names: list
+    parts: list
+    raw: jax.Array | None = None
 
-    The [N]-shaped planes (mcache window, app score) broadcast over the
-    edge axis before the concat, turning their peer gather into the same
-    edge involution (x[n,k] = v[n] ⇒ gathered[j,k] = v[nbr[j,k]]) —
+
+def control_wire(cfg: GossipSubConfig, net: Net, st: GossipSubState,
+                 include_app: bool = False,
+                 code: jax.Array | None = None) -> ControlWire:
+    """The sender side of ONE stacked wire exchange for the whole phase
+    control head (round-7 tentpole): every control outbox, the score plane,
+    the IWANT-service mcache window — and, when ``include_app``, the P5
+    app-score plane the heartbeat tail consumes; with ``code``, the ``[N]``
+    liveness code of a dynamic build (``liveness_code``), last. They cross
+    the edge involution in a single gather, so the sharded lowering emits
+    ONE halo-permute set for the entire control head instead of
+    three-plus-one (16·(r+4) → 16·(r+1) permutes per phase;
+    perf/projection.py charges 1–5 µs launch latency per permute).
+
+    The [N]-shaped planes (mcache window, app score, code) broadcast over
+    the edge axis before the concat, turning their peer gather into the
+    same edge involution (x[n,k] = v[n] ⇒ gathered[j,k] = v[nbr[j,k]]) —
     byte-wasteful per direction but launch-free, the right trade in the
-    launch-dominated halo regime the projection models.
-
-    The round-3 measured merge policy (control_exchange above) deliberately
-    kept the score column and the ihave words on separate gathers: their
-    consumers' layouts forced a relayout copy per ROUND on the real chip.
-    The phase engine pays the control head once per PHASE, so a once-per-
-    phase relayout buys r rounds of avoided launches — the opposite
-    tradeoff; the per-round step keeps the round-3 policy, and the legacy
-    phase path stays selectable (cfg.wire_coalesced=False) for A/B.
-
-    Returns (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
-    nbr_score_of_me, window_g, app_g)."""
+    launch-dominated halo regime the projection models."""
     named_parts = control_parts(cfg, net, st)
     names = [nm for nm, _ in named_parts]
     parts = [p for _, p in named_parts]
+    # control parts lead the concat in control_parts order (score is
+    # always appended last by control_parts), so the plain index view
+    # feeds control_unpack directly
     n_ctrl = len([nm for nm in names if nm != "score"])
+    assert "score" not in names[:n_ctrl]
     n_peers, k_dim = net.nbr.shape
     sender_window = bitset.word_or_reduce(st.mcache, axis=1)       # [N,W]
     w = sender_window.shape[-1]
@@ -2136,12 +2158,44 @@ def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
         parts.append(jnp.broadcast_to(
             jax.lax.bitcast_convert_type(st.app_score, jnp.uint32)[:, None, None],
             (n_peers, k_dim, 1)))
-    sizes = np.cumsum([0] + [p.shape[-1] for p in parts])
-    gg = jnp.where(
-        net_l.nbr_ok[:, :, None],
-        net_l.edge_gather(jnp.concatenate(parts, axis=-1)),
-        jnp.uint32(0),
-    )
+    if code is not None:
+        names.append("code")
+        parts.append(jnp.broadcast_to(code[:, None, None], (n_peers, k_dim, 1)))
+    return ControlWire(names, parts)
+
+
+def gather_wire(net: Net, wire: ControlWire) -> ControlWire:
+    """The stacked plane across the edges: the static net's gather,
+    UNMASKED (``Net.edge_gather`` reads no ``nbr_ok``), so it may run
+    before a head's peer transitions have said which edges live."""
+    return wire._replace(
+        raw=net.edge_gather(jnp.concatenate(wire.parts, axis=-1)))
+
+
+def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
+                               wire: ControlWire):
+    """The receiver side of ``control_wire``: the stacked plane across the
+    edges (gathered here unless ``wire`` holds it already) under ``net_l``'s
+    edge mask — the live edges, and under chaos the head round's links —
+    split into what the control head consumes.
+
+    The round-3 measured merge policy (control_exchange above) deliberately
+    kept the score column and the ihave words on separate gathers: their
+    consumers' layouts forced a relayout copy per ROUND on the real chip.
+    The phase engine pays the control head once per PHASE, so a once-per-
+    phase relayout buys r rounds of avoided launches — the opposite
+    tradeoff; the per-round step keeps the round-3 policy, and the legacy
+    phase path stays selectable (cfg.wire_coalesced=False) for A/B.
+
+    Returns (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
+    nbr_score_of_me, window_g, app_g)."""
+    names = wire.names
+    sizes = np.cumsum([0] + [p.shape[-1] for p in wire.parts])
+    # ``ok`` is traced before the gather: a build without the code keeps
+    # the order of its lowered text, which PERF.md §6 pins by sha256
+    ok = net_l.nbr_ok[:, :, None]
+    raw = gather_wire(net, wire).raw if wire.raw is None else wire.raw
+    gg = jnp.where(ok, raw, jnp.uint32(0))
 
     def seg(i):
         return gg[..., int(sizes[i]) : int(sizes[i + 1])]
@@ -2149,10 +2203,6 @@ def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
     def seg_named(nm):
         return seg(names.index(nm))
 
-    # control parts lead the concat in control_parts order (score is
-    # always appended last by control_parts), so the plain index view
-    # feeds control_unpack directly
-    assert "score" not in names[:n_ctrl]
     graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw = control_unpack(
         cfg, net, net_l, seg
     )
@@ -2167,10 +2217,32 @@ def control_exchange_coalesced(cfg: GossipSubConfig, net: Net, net_l: Net,
     window_g = seg_named("window")
     app_g = (
         jax.lax.bitcast_convert_type(seg_named("app")[..., 0], jnp.float32)
-        if include_app else None
+        if "app" in names else None
     )
     return (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
             nbr_score_of_me, window_g, app_g)
+
+
+def transitions_on_wire(cfg: GossipSubConfig, net: Net, st: GossipSubState,
+                        up_next: jax.Array, tp: dict,
+                        include_app: bool = False):
+    """A dynamic build's phase head with a coalesced wire exchange: the
+    liveness code rides the stacked wire as its last word, so the peer
+    transitions make no gather of their own. The wire is built and
+    gathered from the state BEFORE the transitions, which is exact: every
+    outbox they clear (graft, prune and IHAVE on a dead edge, the mcache
+    window of a peer that goes down) lies on an edge with a down end, which
+    the receiver's ``live`` mask in ``control_exchange_coalesced`` drops.
+    The code is read off the gather before any mask: liveness is no wire
+    message, and a chaos link outage must not drop it.
+    Returns ``(st, live, wire)``, the wire gathered."""
+    with stages.part("churn"):
+        code = liveness_code(st, up_next)[2]
+    wire = gather_wire(net, control_wire(cfg, net, st, include_app, code))
+    with stages.part("churn"):
+        st, live = apply_peer_transitions(cfg, net, st, up_next, tp,
+                                          code_nbr=wire.raw[..., -1])
+    return st, live, wire
 
 
 def px_connect(cfg: GossipSubConfig, net: Net, net_l: Net, st: GossipSubState,

@@ -43,6 +43,7 @@ launch-overhead, not bytes:
   * the CONTROL HEAD coalesces into one stacked wire exchange
     (gossipsub.control_exchange_coalesced): control outboxes + score
     plane + IWANT mcache window + (when weighted) the P5 app plane
+    (+ a dynamic build's liveness code, gossipsub.transitions_on_wire)
     cross the edge involution in ONE gather — the phase's halo budget
     drops from 16·(r+4) to 16·(r+1) permutes (the number the v5e-8
     projection charges; tests/test_collectives.py pins it exactly);
@@ -127,6 +128,7 @@ from .gossipsub import (
     apply_validation_throttle,
     control_exchange,
     control_exchange_coalesced,
+    control_wire,
     fanout_carry_words_packed,
     handle_graft_prune,
     pack_fanout_peers,
@@ -141,6 +143,7 @@ from .gossipsub import (
     prepare_step_consts,
     px_connect,
     sender_carry_words,
+    transitions_on_wire,
     update_fanout_on_publish,
 )
 
@@ -440,11 +443,23 @@ def make_gossipsub_phase_step(
         ev_prev = st.core.events if telemetry is not None else None
         # ---- control head (once per phase) ------------------------------
         stage("control_head")
+        include_app = (
+            cfg.score_enabled
+            and consts.score_params.app_specific_weight != 0.0
+        )
+        wire = None
         if dynamic_peers:
             # the churn part: what a dynamic build pays beyond a static
-            # one at the head (the transitions, the traced liveness views)
+            # one at the head (the transitions, the traced liveness views).
+            # With the stacked wire, the liveness code crosses inside it
+            if cfg.wire_coalesced:
+                st, live, wire = transitions_on_wire(cfg, net, st, up_next,
+                                                     tp_r, include_app)
+            else:
+                with stages.part("churn"):
+                    st, live = apply_peer_transitions(cfg, net, st, up_next,
+                                                      tp_r)
             with stages.part("churn"):
-                st, live = apply_peer_transitions(cfg, net, st, up_next, tp_r)
                 net_l, nbr_sub_l, flood_from_l, nbr_sub_words_l = (
                     live_step_views(cfg, net, st, live, consts))
                 # the publish gate: a publish whose origin is down (as
@@ -527,15 +542,14 @@ def make_gossipsub_phase_step(
         if cfg.wire_coalesced:
             # ONE stacked gather for the whole control head: control
             # outboxes + score plane + IWANT window (+ the P5 app plane
-            # when its weight is live) — the phase's halo budget drops
-            # from 16·(r+4) to 16·(r+1) permutes (perf/projection.py)
-            include_app = (
-                cfg.score_enabled
-                and consts.score_params.app_specific_weight != 0.0
-            )
+            # when its weight is live, + a dynamic build's liveness code,
+            # gathered above) — the phase's halo budget drops from
+            # 16·(r+4) to 16·(r+1) permutes (perf/projection.py)
+            if wire is None:
+                wire = control_wire(cfg, net, st, include_app)
             (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,
              nbr_score_of_me, window_g, app_g) = control_exchange_coalesced(
-                cfg, net, net_w, st, include_app=include_app
+                cfg, net, net_w, wire
             )
         else:
             (graft_in_raw, prune_in_raw, ihave_in_raw, px_in_raw,

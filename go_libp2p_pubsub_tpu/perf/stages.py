@@ -71,9 +71,11 @@ same compiled text.
                 composition and folds, ``score.gater.gater_on_round`` with
                 its popcounts, ``gater_accept`` and ``gater_decay``
   churn         what a ``dynamic_peers`` build pays at the head beyond a
-                static one: ``gossipsub.apply_peer_transitions`` (the
-                liveness code's one edge gather, which stands under
-                ``gs.edge_gather`` too, and the dead-edge clears),
+                static one: the liveness code's packing and
+                ``gossipsub.apply_peer_transitions`` (the dead-edge clears;
+                with the coalesced wire the code crosses inside the head's
+                own wire gather, outside the part, else in an edge gather
+                of its own, under ``gs.edge_gather`` too),
                 ``live_step_views``' traced arm, and the publish gate on
                 ``up[origin]`` (``pub_holder``, which ``state.PhasePubPlan``
                 / ``allocate_publishes`` take). A static window carries none

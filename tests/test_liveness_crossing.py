@@ -1,13 +1,20 @@
-"""The liveness plane crosses the edges once a phase (PR 38).
+"""The liveness plane crosses the edges once a phase, and in the phase
+engine's coalesced control head it crosses inside the wire exchange.
 
-``apply_peer_transitions`` builds one ``[N]`` code (bit 0 ``down_tr``, bit 1
-the new ``up``), broadcasts it along K and sends it through
-``Net.edge_gather``: for a plane constant along K the edge involution IS the
-neighbour view, so both views the function needs come out of ONE gather,
-through whatever the net's layout gives an edge gather (the tiered plan,
-rolls, the flat involution). The parent's form, two ``Net.peer_gather``
-calls on ``[N]`` bool planes, lives on here alone, as the plain reference:
-the state tree and ``live`` must come out bit for bit on every layout.
+``liveness_code`` packs one ``[N]`` code (bit 0 ``down_tr``, bit 1 the new
+``up``). For a plane constant along K the edge involution IS the neighbour
+view, so both views ``apply_peer_transitions`` needs come out of ONE edge
+gather, through whatever the net's layout gives one (the tiered plan, rolls,
+the flat involution). A caller without a wire exchange lets the function
+make that gather; the phase engine's coalesced head (``transitions_on_wire``)
+appends the code to the stacked plane it gathers anyway, from the state
+before the transitions, and hands the function the gathered code.
+
+Two plain references live on here alone: two ``Net.peer_gather`` calls on
+``[N]`` bool planes, the crossing's older form, and ``transitions_first``,
+the head's older order (the transitions with their own crossing, then the
+wire from the outboxes they left). The state tree and ``live`` must come out
+bit for bit against both.
 """
 
 import dataclasses
@@ -19,6 +26,7 @@ import numpy as np
 import pytest
 
 from go_libp2p_pubsub_tpu import driver, graph
+from go_libp2p_pubsub_tpu.chaos import ChaosConfig
 from go_libp2p_pubsub_tpu.config import (
     GossipSubParams,
     PeerScoreParams,
@@ -29,12 +37,17 @@ from go_libp2p_pubsub_tpu.models.gossipsub import (
     GossipSubConfig,
     GossipSubState,
     apply_peer_transitions,
+    control_exchange_coalesced,
+    control_wire,
+    gather_wire,
     live_step_views,
+    liveness_code,
     make_gossipsub_step,
     prepare_step_consts,
     px_connect,
     set_blacklist,
 )
+from go_libp2p_pubsub_tpu.models import gossipsub_phase
 from go_libp2p_pubsub_tpu.models.gossipsub_phase import (
     make_gossipsub_phase_step,
 )
@@ -194,18 +207,30 @@ def test_one_code_crossing_equals_the_two_peer_gathers(layout):
     assert np.asarray(st.score.fmd).any() and np.asarray(st.scores).any()
     assert np.asarray(st.mesh)[was & ~eff].any()
 
-    sets: list = []
-    with edges.tally_halo_gathers(sets):
-        got_st, got_live = jax.jit(
-            lambda s, u: apply_peer_transitions(cfg, net, s, u, tp))(
-                st, up_next)
-    assert sets == ["edge"]                 # ONE crossing, and no peer gather
     ref = TwoPeerGathers(net)
-    want_st, want_live = jax.jit(
-        lambda s, u: apply_peer_transitions(cfg, ref, s, u, tp))(st, up_next)
+    sets, rode = [], []
+
+    def three_forms(s, u):
+        # one program for the three, so the layout compiles once
+        with edges.tally_halo_gathers(sets):
+            own = apply_peer_transitions(cfg, net, s, u, tp)
+        # the code as the coalesced head's wire carries it: its last word
+        code_nbr = gather_wire(net, control_wire(
+            cfg, net, s, code=liveness_code(s, u)[2])).raw
+        with edges.tally_halo_gathers(rode):
+            given = apply_peer_transitions(cfg, net, s, u, tp,
+                                           code_nbr=code_nbr[..., -1])
+        return own, given, apply_peer_transitions(cfg, ref, s, u, tp)
+
+    (got_st, got_live), (rode_st, rode_live), (want_st, want_live) = (
+        jax.jit(three_forms)(st, up_next))
+    assert sets == ["edge"]                 # ONE crossing, and no peer gather
+    assert rode == []                       # given the code, no gather at all
     assert ref.calls == 1
     assert_trees_equal(got_st, want_st)
     np.testing.assert_array_equal(np.asarray(got_live), np.asarray(want_live))
+    assert_trees_equal(rode_st, got_st)
+    np.testing.assert_array_equal(np.asarray(rode_live), np.asarray(got_live))
     # the views themselves, as the parent wrote them
     down_nbr = net.peer_gather(jnp.asarray(was & ~eff)) & net.nbr_ok
     live = net.nbr_ok & eff[:, None] & net.peer_gather(jnp.asarray(eff))
@@ -250,11 +275,11 @@ def test_px_connect_takes_the_returned_plane_for_the_third_gather():
     assert (asleep & ~np.asarray(got)).any()
 
 
-def _phase_pair(net, r=4):
+def _phase_pair(net, r=4, **kw):
     # no fanout, as the churn cell: its maintenance peer-gathers a constant
     cfg, sp = dataclasses.replace(
         config(heartbeat_every=r), edge_layout=net.edge_layout,
-        fanout_slots=0), score_params()
+        fanout_slots=0, **kw), score_params()
     st = GossipSubState.init(net, M, cfg, score_params=sp, seed=5)
     xs = (jnp.full((r, P), -1, jnp.int32), jnp.zeros((r, P), jnp.int32),
           jnp.ones((r, P), bool))
@@ -263,48 +288,70 @@ def _phase_pair(net, r=4):
     return st, xs, build(False), build(True)
 
 
-@pytest.mark.parametrize("layout", ["tiered-compact", "banded", "csr"])
-def test_a_dynamic_phase_has_one_more_edge_set_and_no_more_peer_sets(layout):
+def _phase_sets(layout, **kw):
+    """The gather sets of a static and a dynamic phase of one layout."""
     topo = topology("banded" if layout == "banded" else "random")
     net = build_net(layout, topo)
-    st, xs, static, dynamic = _phase_pair(net)
+    st, xs, static, dynamic = _phase_pair(net, **kw)
     up = jnp.ones(topo.n_peers, bool)
     fold = lambda step, args: edges.fold_tally(edges.tally_step(
         step, st, args, {"do_heartbeat": True}))
-    a, b = fold(static, xs), fold(dynamic, xs + (up,))
+    return fold(static, xs), fold(dynamic, xs + (up,))
+
+
+@pytest.mark.parametrize("layout", ["tiered-compact", "banded", "csr"])
+def test_a_dynamic_phase_has_one_more_edge_set_and_no_more_peer_sets(layout):
+    """Without the stacked wire (``wire_coalesced=False``) the transitions
+    keep their own crossing: one edge set more than the static phase."""
+    a, b = _phase_sets(layout, wire_coalesced=False)
     assert b["edge"] == a["edge"] + 1
     assert b.get("peer", 0) == a.get("peer", 0)
     assert b["total"] == a["total"] + 1
 
 
+@pytest.mark.parametrize("layout", ["tiered-compact", "banded", "csr"])
+def test_a_coalesced_dynamic_phase_has_the_same_edge_and_peer_sets_as_the_static_one(
+        layout):
+    """The code rides the control head's wire: no set of its own."""
+    a, b = _phase_sets(layout)
+    assert b == a
+
+
 def test_a_dynamic_window_counts_no_peer_rows_and_one_more_planned_call():
-    """The counter that says the crossing engaged:
-    ``TracedWindow.peer_rows_per_dispatch`` is 0 for a dynamic phase window
-    (2 * N * K a phase at the parent) and its ``edge_rows_per_dispatch`` is
-    the static window's plus the plan's rows."""
+    """The counters that say the ride engaged: a coalesced dynamic phase
+    window's ``TracedWindow.edge_rows_per_dispatch`` is the static window's
+    and its ``peer_rows_per_dispatch`` 0; without the stacked wire the
+    dynamic window makes one planned call more, the plan's rows."""
     topo = topology("random")
     net = build_net("tiered-compact", topo)
-    st, xs, static, dynamic = _phase_pair(net)
     segs = 2
-    window = lambda step: driver.make_scan(
-        step, heartbeat_every=4, rounds_per_phase=4, static_heartbeat=True)
     rows = lambda a: jnp.stack([a] * segs).reshape((segs * 4,) + a.shape[1:])
-    entries = []
-    for step, more in ((static, ()),
-                       (dynamic, (jnp.ones((segs * 4, N), bool),))):
-        scan = window(step)
-        jax.eval_shape(scan, st, *(tuple(rows(a) for a in xs) + more))
-        (entry,) = [w for w in stages.traced_windows() if w.jitted is scan]
-        entries.append(entry)
-    fixed, moving = entries
-    assert fixed.peer_rows_per_dispatch == moving.peer_rows_per_dispatch == 0.0
+
+    def traced(coalesced):
+        st, xs, static, dynamic = _phase_pair(net, wire_coalesced=coalesced)
+        entries = []
+        for step, more in ((static, ()),
+                           (dynamic, (jnp.ones((segs * 4, N), bool),))):
+            scan = driver.make_scan(step, heartbeat_every=4,
+                                    rounds_per_phase=4, static_heartbeat=True)
+            jax.eval_shape(scan, st, *(tuple(rows(a) for a in xs) + more))
+            (entry,) = [w for w in stages.traced_windows()
+                        if w.jitted is scan]
+            entries.append(entry)
+        return entries
+
+    on, off = traced(True), traced(False)
+    assert on[0].peer_rows_per_dispatch == on[1].peer_rows_per_dispatch == 0.0
+    # without the stacked wire the IWANT window is a peer gather, in both
+    assert off[1].peer_rows_per_dispatch == off[0].peer_rows_per_dispatch > 0
+    for (fixed, moving), more_rows in ((on, 0), (off, net.tiers.rows)):
+        assert (moving.edge_rows_per_dispatch
+                == fixed.edge_rows_per_dispatch + more_rows)
+        assert (moving.edge_sliced_calls_per_dispatch
+                == fixed.edge_sliced_calls_per_dispatch)
+        assert moving.edge_table_rows == fixed.edge_table_rows
     # (a toy plan's rows are no fewer than the flat gather's, only other)
     assert net.tiers.rows != N * topo.max_degree
-    assert (moving.edge_rows_per_dispatch
-            == fixed.edge_rows_per_dispatch + net.tiers.rows)
-    assert (moving.edge_sliced_calls_per_dispatch
-            == fixed.edge_sliced_calls_per_dispatch)
-    assert moving.edge_table_rows == fixed.edge_table_rows
     # a peer gather in a window is counted: N * K rows a call
     win = driver.make_window(lambda s, x: net.peer_gather(s[:, 0]) ^ x,
                              donate=False)
@@ -313,3 +360,84 @@ def test_a_dynamic_window_counts_no_peer_rows_and_one_more_planned_call():
     (entry,) = [w for w in stages.traced_windows() if w.jitted is win]
     assert entry.peer_rows_per_dispatch == x.size
     assert entry.edge_rows_per_dispatch == 0.0
+
+
+def transitions_first(cfg, net, st, up_next, tp, include_app=False):
+    """The coalesced head's older order, the plain reference: the
+    transitions first, with the code's own crossing, then the wire from the
+    outboxes they left."""
+    st, live = apply_peer_transitions(cfg, net, st, up_next, tp)
+    return st, live, control_wire(cfg, net, st, include_app)
+
+
+PHASES, R = 4, 2
+
+
+@pytest.mark.parametrize("layout,chaos", [
+    ("tiered-compact", None), ("banded", None), ("csr", None),
+    ("tiered-compact", ChaosConfig(loss_rate=0.35))],
+    ids=["tiered-compact", "banded", "csr", "tiered-compact-chaos"])
+def test_the_code_on_the_wire_equals_the_transitions_first_over_a_window(
+        layout, chaos, monkeypatch):
+    """A dynamic phase window whose rows do all four things (down, back up,
+    stay down, blacklisted and marked up), P3 / P3b live, against the same
+    window built with ``transitions_first``: every phase's state tree bit
+    for bit, ``up`` (which ``live`` is read from) among them. Under chaos a
+    link outage at a head must not eat the code: it is read off the gather
+    before any mask."""
+    topo = topology("banded" if layout == "banded" else "random")
+    net = build_net(layout, topo)
+    n = topo.n_peers
+    cfg, sp = dataclasses.replace(
+        config(heartbeat_every=R), edge_layout=net.edge_layout,
+        fanout_slots=0, chaos=chaos), score_params()
+    rng = np.random.default_rng(11)
+    gone = rng.choice(n, 11, replace=False)
+    black, first, later = gone[0], gone[1:7], gone[7:]
+    st0 = set_blacklist(
+        GossipSubState.init(net, M, cfg, score_params=sp, seed=5),
+        np.arange(n) == black)
+    ups = np.ones((PHASES, n), bool)
+    ups[1:, first] = False
+    ups[2:, first[:3]] = True
+    ups[2:, later] = False
+    xs = (jnp.asarray(rng.integers(0, n, (PHASES, R, P)).astype(np.int32)),
+          jnp.zeros((PHASES, R, P), jnp.int32),
+          jnp.ones((PHASES, R, P), bool), jnp.asarray(ups))
+    phase = make_gossipsub_phase_step(cfg, net, R, score_params=sp,
+                                      dynamic_peers=True).__wrapped__
+
+    def phases(st):
+        def one(s, x):
+            s = phase(s, *x, do_heartbeat=True)
+            return s, s
+        return jax.lax.scan(one, st, xs)[1]      # the state after each phase
+
+    def both(st):
+        got = phases(st)
+        # the same body traced again, with the head's older order
+        with monkeypatch.context() as mp:
+            mp.setattr(gossipsub_phase, "transitions_on_wire",
+                       transitions_first)
+            want = phases(st)
+        return got, want
+
+    got, want = jax.jit(both)(st0)              # one program for the two
+    assert_trees_equal(got, want)
+    at = lambda tree, p: jax.tree_util.tree_map(lambda x: x[p], tree)
+    dropped = 0
+    for p in range(PHASES):
+        # what the wire carried that the receiver's mask had to drop: the
+        # outboxes and mcache the head's transitions clear on dying edges
+        before = at(got, p - 1) if p else st0
+        was = np.asarray(before.up)
+        down = was & ~(ups[p] & ~np.asarray(before.blacklist))
+        ok = np.asarray(net.nbr_ok)
+        dying = ok & (down[:, None] | down[np.clip(np.asarray(net.nbr), 0, None)])
+        dropped += int((np.asarray(before.graft_out).any(1) & dying).sum()
+                       + (np.asarray(before.ihave_out).any(-1) & dying).sum()
+                       + np.asarray(before.mcache)[down].any(-1).sum())
+    assert dropped > 0
+    up_end = np.asarray(got.up[-1])
+    assert not up_end[black] and not up_end[first[3:]].any()
+    assert up_end[first[:3]].all() and not up_end[later].any()
